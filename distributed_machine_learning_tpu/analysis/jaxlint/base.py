@@ -133,9 +133,11 @@ def iter_eqns(jaxpr, _stack: Tuple[str, ...] = ()) -> Iterator[Tuple[Any, Tuple[
 
 
 def _sub_jaxprs(value, jax) -> Iterator[Any]:
-    if isinstance(value, jax.core.ClosedJaxpr):
+    from jax.extend import core as jcore
+
+    if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jcore.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

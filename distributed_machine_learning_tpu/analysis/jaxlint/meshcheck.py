@@ -202,9 +202,11 @@ def _walk_with_bound_axes(
 
 
 def _subs(value, jax):
-    if isinstance(value, jax.core.ClosedJaxpr):
+    from jax.extend import core as jcore
+
+    if isinstance(value, jcore.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jcore.Jaxpr):
         yield value
     elif isinstance(value, (tuple, list)):
         for v in value:

@@ -94,24 +94,48 @@ def lowered_alias_info(lowered) -> Dict[str, Any]:
             "buffer_donors": donors}
 
 
+def _execution_devices(args, jit_kwargs):
+    """The devices the program for ``args`` runs on, in assignment order.
+
+    ``deserialize_and_load`` loads for EVERY device of the backend unless
+    told otherwise, and an executable loaded that way rejects a
+    one-device program's arguments on any multi-device host."""
+    import jax
+    from jax.sharding import NamedSharding, Sharding
+
+    shardings = [
+        s for s in jax.tree.leaves(
+            (jit_kwargs or {}).get("in_shardings"),
+            is_leaf=lambda s: isinstance(s, Sharding),
+        ) if isinstance(s, Sharding)
+    ] + [a.sharding for a in jax.tree.leaves(args)
+         if isinstance(a, jax.Array)]
+    for s in shardings:
+        if isinstance(s, NamedSharding):
+            return list(s.mesh.devices.flat)
+    for s in shardings:
+        return sorted(s.device_set, key=lambda d: d.id)
+    return [jax.devices()[0]]
+
+
 def default_aot_dir() -> str:
-    """``$DML_TPU_AOT_CACHE``, else ``<persistent cache dir>/aot``."""
-    env = os.environ.get("DML_TPU_AOT_CACHE")
-    if env:
-        return os.path.expanduser(env)
-    base = _tracker.cache_dir() or os.path.join(
-        os.path.expanduser("~"), ".cache", "dml_tpu", "xla_cache"
-    )
+    """``<persistent cache dir>/aot`` — follows the same rule as the XLA
+    cache (``tracker.resolve_cache_dir``)."""
+    base = _tracker.cache_dir() or _tracker.resolve_cache_dir()
     return os.path.join(base, "aot")
 
 
 class _Entry:
-    __slots__ = ("compiled", "fallback", "make_fallback")
+    __slots__ = ("compiled", "fallback", "make_fallback", "unproven")
 
-    def __init__(self, compiled):
+    def __init__(self, compiled, unproven=False):
         self.compiled = compiled
         self.fallback = None
         self.make_fallback = None
+        # Imported from disk and not yet run to completion once: XLA:CPU
+        # can load a serialized program whose fusion symbols it then fails
+        # to find, and says so only when the (async) result is awaited.
+        self.unproven = unproven
 
 
 class ExecutableCache:
@@ -128,7 +152,9 @@ class ExecutableCache:
        (``aot_unsupported``) and rely on the persistent XLA cache for the
        cross-process story.
 
-    The returned callable accepts the same concrete arguments as ``fn``.
+    Programs with ``donate_argnums`` skip tier 2 in both directions (see
+    ``get_or_compile``).  The returned callable accepts the same concrete
+    arguments as ``fn``.
     """
 
     def __init__(self, directory: Optional[str] = None,
@@ -148,7 +174,7 @@ class ExecutableCache:
     def _path(self, key: str) -> str:
         return os.path.join(self._dir, f"{key}.aotexec")
 
-    def _load_from_disk(self, key: str):
+    def _load_from_disk(self, key: str, execution_devices):
         path = self._path(key)
         if not self._persist or not os.path.exists(path):
             return None
@@ -160,7 +186,10 @@ class ExecutableCache:
                 payload, in_tree, out_tree = pickle.load(f)
             from jax.experimental import serialize_executable as se
 
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=execution_devices,
+            )
         except Exception:  # noqa: BLE001 - stale/cross-version payloads
             # A damaged entry must cost a recompile, never an error; drop
             # it so the fresh export below replaces it.
@@ -224,22 +253,31 @@ class ExecutableCache:
             counters.add("program_hits")
             return self._wrap(key, entry)
 
-        compiled = self._load_from_disk(key)
+        # A donating program never goes through the disk tier: an import is
+        # only proven by its first call, and a refusal that comes after the
+        # inputs were donated cannot be recovered.  The persistent XLA
+        # cache still spares it the backend compile in the next process.
+        on_disk = not donate_argnums
+        compiled = self._load_from_disk(
+            key, _execution_devices(args, jit_kwargs)
+        ) if on_disk else None
         if compiled is not None:
             counters.add("program_hits")
             counters.add("aot_imports")
             self._capture_cost(key, compiled, from_disk=True)
             entry = self._remember(key, compiled, fn, static_argnums,
-                                   donate_argnums, jit_kwargs)
+                                   donate_argnums, jit_kwargs,
+                                   unproven=True)
             return self._wrap(key, entry)
 
         counters.add("program_misses")
         jitted = self._jit(fn, static_argnums, donate_argnums, jit_kwargs)
         compiled = jitted.lower(*args).compile()
-        if self._export_to_disk(key, compiled):
-            counters.add("aot_exports")
-        else:
-            counters.add("aot_unsupported")
+        if on_disk:
+            if self._export_to_disk(key, compiled):
+                counters.add("aot_exports")
+            else:
+                counters.add("aot_unsupported")
         self._capture_cost(key, compiled, from_disk=False)
         entry = self._remember(key, compiled, fn, static_argnums,
                                donate_argnums, jit_kwargs)
@@ -278,11 +316,11 @@ class ExecutableCache:
         return jax.jit(fn, **kwargs)
 
     def _remember(self, key, compiled, fn, static_argnums, donate_argnums,
-                  jit_kwargs=None):
+                  jit_kwargs=None, unproven=False):
         # The fallback is built lazily: a plain jit of the original fn, used
         # only if the AOT executable ever rejects its arguments (dtype /
         # weak-type drift between the exporting and importing process).
-        entry = _Entry(compiled)
+        entry = _Entry(compiled, unproven)
 
         def fallback(*call_args):
             if entry.fallback is None:
@@ -297,11 +335,20 @@ class ExecutableCache:
 
     def _wrap(self, key: str, entry: _Entry) -> Callable:
         def call(*args):
+            import jax
+
             try:
-                return entry.compiled(*args)
-            except (TypeError, ValueError):
-                # Strict AOT signature mismatch: drop the entry and serve
-                # through ordinary jit (persistent cache still applies).
+                out = entry.compiled(*args)
+                if entry.unproven:
+                    jax.block_until_ready(out)
+                    entry.unproven = False
+                return out
+            except (TypeError, ValueError, jax.errors.JaxRuntimeError):
+                # Strict AOT signature mismatch, or an imported executable
+                # the runtime refuses at its first call (XLA:CPU reports
+                # fusion symbols of a deserialized program "not found"):
+                # drop the entry and serve through ordinary jit (persistent
+                # cache still applies).
                 get_counters().add("aot_unsupported")
                 with self._lock:
                     self._mem.pop(key, None)

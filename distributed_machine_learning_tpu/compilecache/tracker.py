@@ -26,9 +26,15 @@ import threading
 from typing import Dict, Optional
 from distributed_machine_learning_tpu.analysis.locks import named_lock
 
-_DEFAULT_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "dml_tpu", "xla_cache"
+# Everything this package builds at run time (XLA cache, AOT executables,
+# the native .so) lives under ONE fixed, git-ignored directory inside the
+# checkout: the path is part of a cache entry's key, so a directory that
+# moves (a home or temp dir that is new on every machine) never hits.
+_CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".dml_cache",
 )
+_DEFAULT_DIR = os.path.join(_CACHE_ROOT, "xla")
 
 _lock = named_lock("compilecache.tracker.registry")
 _enabled_dir: Optional[str] = None
@@ -43,27 +49,39 @@ _DURATION_EVENTS = (
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created if
-    missing) and drop the min-size/min-time thresholds so even small HPO
-    programs are cached.  Idempotent; returns the resolved directory.
+def cache_root() -> str:
+    """The in-checkout directory run-time build products go under."""
+    return _CACHE_ROOT
 
-    Default: ``$DML_TPU_COMPILE_CACHE`` or ``~/.cache/dml_tpu/xla_cache``.
+
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Where the persistent cache goes: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (nothing outranks it), else ``cache_dir``, else the fixed
+    in-checkout default."""
+    return os.path.expanduser(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir or _DEFAULT_DIR
+    )
+
+
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache at
+    :func:`resolve_cache_dir` and drop the min-size/min-time thresholds so
+    even small HPO programs are cached.  Idempotent; returns the directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+    sets no directory in code.
     """
     global _enabled_dir
-    resolved = os.path.expanduser(
-        cache_dir
-        or os.environ.get("DML_TPU_COMPILE_CACHE")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or _DEFAULT_DIR
-    )
+    from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    resolved = resolve_cache_dir(cache_dir)
     with _lock:
         if _enabled_dir == resolved:
             return resolved
         os.makedirs(resolved, exist_ok=True)
         import jax
 
-        jax.config.update("jax_compilation_cache_dir", resolved)
+        if not from_env:
+            jax.config.update("jax_compilation_cache_dir", resolved)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         # By default jax also turns on XLA's GPU autotune cache, whose
@@ -73,10 +91,7 @@ def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
         # between them (cluster origin, bench children) can never hit.
         # Disable it: key stability across hosts is the whole point, and
         # the knob only affects a GPU autotuning sidecar cache.
-        try:
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "")
-        except AttributeError:  # pragma: no cover - knob absent on old jax
-            pass
+        jax.config.update("jax_persistent_cache_enable_xla_caches", "")
         if _enabled_dir is not None and _enabled_dir != resolved:
             # JAX instantiates the cache object lazily ONCE; re-pointing the
             # config after that is silently ignored without a reset.

@@ -4,8 +4,8 @@ The hot host-side data-prep ops (windowing, shuffled batch gather,
 standardization — the work the reference does in Python loops / delegates to
 torch DataLoaders, `ray-tune-hpo-regression.py:403-411,452-457`) live in
 ``native/window_ops.cpp`` as a C-ABI shared library with OpenMP. This module
-compiles it with the system ``g++`` on first use (cached by source hash under
-``~/.cache/dml_tpu/``), binds it with ctypes, and exposes numpy-signature
+compiles it with the system ``g++`` on first use (cached by source hash under the
+in-checkout cache root, ``compilecache.tracker.cache_root()``), binds it with ctypes, and exposes numpy-signature
 wrappers. Every wrapper has a pure-numpy fallback, so the package works
 identically (slower) where no C++ toolchain exists; ``native_available()``
 reports which path is active.
@@ -23,11 +23,10 @@ from typing import Optional, Tuple
 
 import numpy as np
 from distributed_machine_learning_tpu.analysis.locks import named_lock
+from distributed_machine_learning_tpu.compilecache.tracker import cache_root
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native", "window_ops.cpp")
-_CACHE_DIR = os.environ.get(
-    "DML_TPU_NATIVE_CACHE", os.path.join(os.path.expanduser("~"), ".cache", "dml_tpu")
-)
+_CACHE_DIR = os.path.join(cache_root(), "native")
 
 _lock = named_lock("data.native")
 _lib: Optional[ctypes.CDLL] = None

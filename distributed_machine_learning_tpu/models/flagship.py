@@ -26,10 +26,8 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, Optional
 
-# Per-chip HBM when the runtime exposes no memory_stats: v2/v3 8/16 GiB
-# cores, v4 32 GiB, v5e 16 GiB — 16 GiB is the safe middle.  The CPU test
-# platform gets a deliberately tiny VIRTUAL budget (see module docstring).
-_TPU_HBM_FALLBACK_BYTES = 16 << 30
+# The CPU test platform gets a deliberately tiny VIRTUAL budget (see module
+# docstring); an accelerator's budget is what its runtime reports.
 _CPU_VIRTUAL_BUDGET_BYTES = 8 << 20
 
 
@@ -46,14 +44,13 @@ def single_chip_hbm_bytes(device=None) -> int:
                 "DML_CPU_DEVICE_BUDGET_BYTES", _CPU_VIRTUAL_BUDGET_BYTES
             )
         )
-    try:
-        stats = device.memory_stats()
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return limit
-    except Exception:  # noqa: BLE001 - not every runtime exposes stats
-        pass
-    return _TPU_HBM_FALLBACK_BYTES
+    limit = int((device.memory_stats() or {}).get("bytes_limit", 0))
+    if limit <= 0:
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']: refusing "
+            f"to assume a device-memory budget"
+        )
+    return limit
 
 
 def param_opt_bytes(config: Dict[str, Any], features: int = 16,

@@ -105,22 +105,35 @@ def constrain_activation(x: jnp.ndarray, mesh: Optional[Mesh], *axes):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend probing never fatal
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _partitioned(mesh: Optional[Mesh]) -> bool:
+    """A mesh of more than one device makes the program a partitioned
+    (GSPMD) one, and the compiler refuses a bare Mosaic kernel there
+    ("cannot be automatically partitioned"): only the seq_axis paths,
+    which wrap the kernel in shard_map, may run it under such a mesh."""
+    return mesh is not None and mesh.size > 1
 
 
 def _route_softmax_to_flash(seq_len: int, head_dim: int) -> bool:
     """Whether a plain softmax attention call should run the Pallas flash
-    kernel instead: same exact math (online softmax), measured faster on
-    chip from ~1k sequence length at head_dim <= 64 (benchmarks/RESULTS.md:
-    fwd ~20%, fwd+bwd 2.0x at seq 4096, full train step 1.48x at seq
-    2048). Gated to that measured-win regime: at D=128 the flash FORWARD
-    measured 2x slower than XLA (only the grad path won), and this route
-    also serves eval — configs wanting flash at bigger head dims select
+    kernel instead: same exact math (online softmax), recorded faster on
+    chip from ~1k sequence length at head_dim <= 64 (fwd ~20%, fwd+bwd
+    2.0x at seq 4096, full train step 1.48x at seq 2048; at D=128 the
+    flash FORWARD 2x slower than XLA, only the grad path ahead — all
+    figures from an earlier round, not measured on today's code). Gated
+    to that regime and to lengths the kernel can tile; this route also
+    serves eval — configs wanting flash at bigger head dims select
     attention_type='flash' explicitly."""
-    return _on_tpu() and seq_len >= 1024 and head_dim <= 64
+    from distributed_machine_learning_tpu.ops.pallas_attention import (
+        flash_can_tile,
+    )
+
+    return (
+        _on_tpu() and seq_len >= 1024 and head_dim <= 64
+        and flash_can_tile(seq_len, head_dim)
+    )
 
 
 def sincos_position_table(max_len: int, d_model: int) -> np.ndarray:
@@ -401,6 +414,14 @@ class MultiHeadAttention(nn.Module):
             # compile for TPU backends).
             scale = float(head_dim) ** (-self.key_dim_scaling)
             if _on_tpu():
+                if _partitioned(self.mesh):
+                    raise ValueError(
+                        f"attention_type='flash' under a {dict(self.mesh.shape)} "
+                        f"mesh: a Mosaic kernel cannot be partitioned "
+                        f"automatically; set seq_axis (ring/Ulysses run the "
+                        f"kernel inside shard_map) or use another "
+                        f"attention_type"
+                    )
                 from distributed_machine_learning_tpu.ops.pallas_attention import (
                     flash_attention,
                 )
@@ -424,7 +445,9 @@ class MultiHeadAttention(nn.Module):
             out = blockwise_attention(q, k, v, block_size=bs, causal=self.causal)
         else:
             scale = float(head_dim) ** (-self.key_dim_scaling)
-            if _route_softmax_to_flash(S, head_dim):
+            if _route_softmax_to_flash(S, head_dim) and not _partitioned(
+                self.mesh
+            ):
                 # Exact same softmax math through the measured-faster
                 # Pallas kernel (long sequences on TPU only). Blocks stay
                 # None — the kernel's measured-fastest tiles; block_size

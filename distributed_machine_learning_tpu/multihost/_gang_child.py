@@ -71,14 +71,8 @@ def main() -> None:
         # initialize the backend, which must not happen before
         # jax.distributed.initialize below.
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-            try:
-                # Cross-process CPU collectives need a backend; gloo ships
-                # in jaxlib.
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            except Exception:  # noqa: BLE001 - knob renamed on newer jax
-                pass
+            # Cross-process CPU collectives need a backend; gloo ships in jaxlib.
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
         from distributed_machine_learning_tpu import obs
         from distributed_machine_learning_tpu.compilecache import (

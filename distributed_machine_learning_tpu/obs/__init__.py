@@ -20,7 +20,7 @@ Three legs, one import:
 Everything is stdlib-only and safe to import anywhere (no jax at import
 time); the disabled tracing path is a single None-check.
 
-See docs/observability.md for the span taxonomy, flight-recorder
+See docs/observability.md for the span names, flight-recorder
 triggers, and the counter -> registry migration map.
 """
 

@@ -38,14 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports cleanly where libtpu/mosaic is available
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 
@@ -140,12 +133,56 @@ def _flash_kernel(
         lse_ref[0, 0] = lse[:, 0]
 
 
-def _adjust_blocks(S: int, block_q: int, block_k: int):
-    from distributed_machine_learning_tpu.ops.attention import (
-        largest_divisor_block,
-    )
+def _tileable_block(S: int, target: int, align: int) -> Optional[int]:
+    """Largest block <= ``target`` the TPU tiling accepts along an axis of
+    length S: the whole axis, or a divisor of S that is a multiple of
+    ``align``. None where S admits neither."""
+    if S <= target:
+        return S
+    b = (target // align) * align
+    while b >= align:
+        if S % b == 0:
+            return b
+        b -= align
+    return None
 
-    return largest_divisor_block(S, block_q), largest_divisor_block(S, block_k)
+
+def _tileable_blocks(S: int, block_q: int, block_k: int):
+    """(q block, kv block) for the compiled kernels, None where S admits
+    none: ``block_q`` is the LAST dim of the lse/delta blocks
+    ``(1, 1, block_q)`` so it must be a multiple of 128; ``block_k`` is only
+    ever second to last (``(1, block_k, D)``) so a multiple of 8 does."""
+    return _tileable_block(S, block_q, 128), _tileable_block(S, block_k, 8)
+
+
+def _adjust_blocks(S: int, block_q: int, block_k: int, interpret: bool):
+    """Fit the requested blocks to S.
+
+    Compiled (Mosaic) kernels only take blocks the chip's tiling accepts
+    (``_tileable_blocks``), or blocks spanning the whole axis. An S that
+    admits no such block under the caller's cap raises — nothing is padded
+    and nothing gives way to another kernel. The interpreter has no
+    tiling, so there any divisor goes."""
+    if interpret:
+        from distributed_machine_learning_tpu.ops.attention import (
+            largest_divisor_block,
+        )
+
+        return (
+            largest_divisor_block(S, block_q),
+            largest_divisor_block(S, block_k),
+        )
+    bq, bk = _tileable_blocks(S, block_q, block_k)
+    if bq is None or bk is None:
+        raise ValueError(
+            f"flash_attention cannot tile seq len {S} for the TPU: it needs "
+            f"a q block <= {block_q} that divides {S} and is a multiple of "
+            f"128 (got {bq}) and a kv block <= {block_k} that divides {S} "
+            f"and is a multiple of 8 (got {bk}), or blocks spanning the "
+            f"whole axis; use a sequence length with such a divisor or "
+            f"another attention_type"
+        )
+    return bq, bk
 
 
 def _to_bh(x):
@@ -196,7 +233,7 @@ def _flash_forward(
         raise ValueError(
             f"num_heads {H} must be a multiple of kv heads {Hkv}"
         )
-    block_q, block_k = _adjust_blocks(S, block_q, block_k)
+    block_q, block_k = _adjust_blocks(S, block_q, block_k, interpret)
     nq, nk = S // block_q, S // block_k
 
     # [B, S, H, D] -> [B*H, S, D]: one grid row per (batch, head).
@@ -211,11 +248,6 @@ def _flash_forward(
         causal=causal,
     )
 
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise RuntimeError(
-            "flash_attention requires jax.experimental.pallas.tpu; "
-            "use blockwise_attention on this backend"
-        )
     scratch_shapes = [
         pltpu.VMEM((block_q, 128), jnp.float32),  # running max
         pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
@@ -405,7 +437,7 @@ def _flash_backward(
     B, S, H, D = q.shape
     Hkv = k.shape[2]
     group = H // Hkv
-    block_q, block_k = _adjust_blocks(S, block_q, block_k)
+    block_q, block_k = _adjust_blocks(S, block_q, block_k, interpret)
     nq, nk = S // block_q, S // block_k
     kv_row = _kv_row_map(H, Hkv)
 
@@ -493,11 +525,11 @@ def _flash_backward(
 def _default_blocks(S: int, D: int, block_q, block_k, backward: bool = False):
     """Resolve block sizes: as large as VMEM comfortably allows.
 
-    Measured on a v5e chip (2026-07-30, benchmarks/RESULTS.md): 128x128
-    blocks ran 54ms forward vs XLA's fused attention at 24ms (seq 4096,
-    D=64) — grid overhead and tiny MXU matmuls dominated; 1024-tile
-    forwards run ~20% faster than XLA, and with the 512-tile backward the
-    fwd+bwd pair is 2.0x faster. The caps clamp by head dim to keep the
+    Recorded on a v5e chip in an earlier round (not measured on today's
+    code): 128x128 blocks ran 54ms forward vs XLA's fused attention at
+    24ms (seq 4096, D=64) — grid overhead and tiny MXU matmuls dominated;
+    1024-tile forwards ~20% faster than XLA, and with the 512-tile
+    backward the fwd+bwd pair 2.0x faster. The caps clamp by head dim to keep the
     per-step VMEM working set (f32 [bq, bk] intermediates + streamed
     blocks + Pallas double-buffering) inside the ~16MB scoped budget:
     1024-tile forwards fail Mosaic compilation at D=256 (measured), and
@@ -524,6 +556,18 @@ def _default_blocks(S: int, D: int, block_q, block_k, backward: bool = False):
     bq = min(cap, S) if block_q is None else min(block_q, cap, S)
     bk = min(cap, S) if block_k is None else min(block_k, cap, S)
     return bq, bk
+
+
+def flash_can_tile(S: int, D: int) -> bool:
+    """Whether the compiled forward AND backward kernels can tile seq len S
+    at their default blocks — what the automatic routes (softmax->flash,
+    ring, Ulysses) ask before selecting the kernel."""
+    return all(
+        None not in _tileable_blocks(
+            S, *_default_blocks(S, D, None, None, backward=backward)
+        )
+        for backward in (False, True)
+    )
 
 
 @functools.partial(
@@ -557,7 +601,13 @@ def flash_attention(
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     s = (q.shape[-1] ** -0.5) if scale is None else scale
-    bq, bk = _default_blocks(q.shape[1], q.shape[-1], block_q, block_k)
+    S, D = q.shape[1], q.shape[-1]
+    bq, bk = _default_blocks(S, D, block_q, block_k)
+    # An S the backward cannot tile is refused here, while the forward is
+    # traced, not halfway into the gradient's trace.
+    _adjust_blocks(
+        S, *_default_blocks(S, D, block_q, block_k, backward=True), interpret
+    )
     out, lse = _flash_forward(
         q, k, v, s, causal, bq, bk, interpret, with_lse=True
     )

@@ -3,12 +3,11 @@
 The reference's dropout randomness comes from cuDNN's hardware RNG
 (`torch.nn.Dropout` inside the encoder stack, `ray-tune-hpo-regression.py:
 148-177`) — fast, seeded, but not a counter-based stream.  JAX defaults to
-threefry2x32, whose key derivation is measurably expensive on TPU at HPO-sweep
-shapes: on the bench workload (d_model 64, batch 32, seq 96) switching dropout
-streams to the hardware RNG ("rbg") gave ~1.5x sweep throughput on a v5e chip
-in the clean same-dispatch-mode comparison (12.6k vs 8.3k trials/hour, f32
-whole-budget; the raw capture pair 15.3k-vs-8.1k also differs in dispatch
-mode — benchmarks/RESULTS.md "Headline sweeps", 2026-07-31).
+threefry2x32, whose key derivation is expensive on TPU at HPO-sweep shapes:
+on the bench workload (d_model 64, batch 32, seq 96) switching dropout
+streams to the hardware RNG ("rbg") was recorded at ~1.5x sweep throughput
+on a v5e chip (a figure from an earlier round, not measured on today's
+code).
 
 ``rng_impl`` semantics in a trial config:
 

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_machine_learning_tpu.parallel.ring_attention import _shard_map
 
 
 def _pipeline_local(
@@ -139,11 +138,12 @@ def pipeline_apply(
     param_specs = jax.tree_util.tree_map(
         lambda l: P(axis_name, *([None] * (l.ndim - 1))), stage_params
     )
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local, stage_fn=stage_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )
     y = fn(stage_params, x_mb)
     return y.reshape(B, *y.shape[2:])

@@ -31,19 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax API generations (>=0.8 keyword-only; older
-    experimental takes check_rep)."""
-    try:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as legacy
-
-        return legacy(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
 
 
 def _ring_local(
@@ -325,8 +312,9 @@ def _make_ring_flash(axis_name: str, causal: bool, scale: float,
 
 
 def _use_flash_inner(mode, Sq: int, Sk: int, D: int) -> bool:
-    """Resolve the use_flash knob: 'auto' = the measured-win regime on TPU
-    (same gate as the softmax->flash route: benchmarks/RESULTS.md).
+    """Resolve the use_flash knob: 'auto' = the same gate as the
+    softmax->flash route (models/layers.py) on TPU, for a chunk length the
+    kernel can tile.
 
     The flash chunk kernels assume equal q/kv chunk lengths (self-
     attention over one sharded sequence); cross-length rings stay on the
@@ -339,11 +327,15 @@ def _use_flash_inner(mode, Sq: int, Sk: int, D: int) -> bool:
             f"use_flash must be 'auto', True, or False; got {mode!r}"
         )
     if mode == "auto":
-        try:
-            on_tpu = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover
-            on_tpu = False
-        return on_tpu and Sq == Sk and Sq >= 1024 and D <= 64
+        from distributed_machine_learning_tpu.ops.pallas_attention import (
+            flash_can_tile,
+        )
+
+        return (
+            jax.default_backend() == "tpu"
+            and Sq == Sk and Sq >= 1024 and D <= 64
+            and flash_can_tile(Sq, D)
+        )
     if mode and Sq != Sk:
         raise ValueError(
             f"use_flash=True needs equal q/kv sequence lengths per shard "
@@ -411,14 +403,16 @@ def ring_attention(
                 axis_name, causal, s, flash_interpret
             )(q_, k_, v_)
 
-        fn = _shard_map(
-            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+        fn = jax.shard_map(
+            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )
         return fn(q, k, v)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_ring_local, axis_name=axis_name, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)

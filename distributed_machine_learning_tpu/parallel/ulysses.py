@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_machine_learning_tpu.parallel.ring_attention import _shard_map
 
 
 def _ulysses_local(
@@ -168,12 +167,13 @@ def ulysses_attention(
             f"full heads first (models/layers.py does this automatically)"
         )
     spec = P(baxis, axis_name, haxis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_local, axis_name=axis_name, causal=causal,
                 scale=scale, use_flash=use_flash,
                 flash_interpret=flash_interpret),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -2,7 +2,7 @@
 
 The obs plane records *where* time went; this module watches *whether any
 of it was abnormal* — the fail-slow shapes every postmortem in this repo
-shares (a wedged relay that doubles step time, a CPU-starved producer
+shares (a degraded link that doubles step time, a CPU-starved producer
 that starves one trial, one gang member 3x slower than its peers):
 
 * :class:`StepAnomalyDetector` — per-program-key sliding windows of step

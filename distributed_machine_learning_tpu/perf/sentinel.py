@@ -1,12 +1,9 @@
 """Cross-round bench regression sentinel: honest comparisons only.
 
-The checked-in ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` artifacts are
-the repo's performance memory — and the r03–r05 era demonstrated how
-they lie by juxtaposition: the TPU probe wedged, three rounds captured
-CPU-fallback numbers, and the headline sequence read "6329 → 722 →
-1372 trials/h, 0.8x torch" as if the framework had collapsed 0.8x when
-nothing chip-comparable was ever measured.  The sentinel parses the
-round artifacts, buckets them into **comparability classes** (backend +
+``BENCH_r*.json`` / ``MULTICHIP_r*.json`` round artifacts lie by
+juxtaposition when rounds ran on different backends: a chip capture
+followed by CPU captures reads as a collapse when nothing
+chip-comparable was measured.  The sentinel parses the round artifacts, buckets them into **comparability classes** (backend +
 compute dtype + metric), and only issues regression/improvement
 verdicts WITHIN a class and outside a noise band:
 
@@ -22,7 +19,7 @@ verdicts WITHIN a class and outside a noise band:
 
 ``dml-tpu perf compare --artifacts BENCH_r*.json`` renders the report
 and exits nonzero exactly when an in-class regression beyond the noise
-band exists — the CI smoke gate (``.github/workflows/lint.yml``).
+band exists.
 
 Stdlib-only; runs on hosts with no jax at all.
 """
@@ -103,11 +100,8 @@ def _same_class(a: Dict[str, Any], b: Dict[str, Any]) -> bool:
 
 def reference_backend(rounds: List[Dict[str, Any]]) -> Optional[str]:
     """The backend perf claims are judged on: the most recent parseable
-    non-CPU capture's backend — or, when every round is CPU but one
-    carries a banked ``last_tpu_capture`` block, ``tpu`` (the banked
-    chip evidence proves the product surface is the chip).  None when
-    nothing establishes a reference (all-CPU repo: CPU is then judged
-    as the reference by the caller)."""
+    non-CPU capture's backend.  None when nothing establishes a reference
+    (all-CPU repo: CPU is then judged as the reference by the caller)."""
     ref = None
     for rec in rounds:
         parsed = rec.get("parsed")
@@ -115,8 +109,6 @@ def reference_backend(rounds: List[Dict[str, Any]]) -> Optional[str]:
             continue
         if (parsed.get("backend") or "cpu") != "cpu":
             ref = parsed["backend"]
-        elif parsed.get("last_tpu_capture") and ref is None:
-            ref = "tpu"
     return ref
 
 

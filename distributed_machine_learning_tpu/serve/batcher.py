@@ -519,7 +519,7 @@ class ContinuousBatcher:
                 # The same per-bucket step measurement the adaptive cap
                 # EWMA runs on also feeds the step-stream anomaly
                 # detector (perf/anomaly.py): a sustained engine.step
-                # outlier — wedged relay, degraded replica — becomes a
+                # outlier — a hung call, a degraded replica — becomes a
                 # counter + flight dump naming this batcher instead of a
                 # silently drifting p99.
                 get_step_anomalies().observe(

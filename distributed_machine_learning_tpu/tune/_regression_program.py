@@ -587,7 +587,7 @@ def detect_call_convention(model, sample_x, init_rngs=None,
     """Init the model and learn (variables, train-flag kwarg name).
 
     The init is jitted: eager ``model.init`` dispatches hundreds of tiny ops
-    one by one, which is pathological on a remote/tunneled TPU backend; one
+    one by one, each paying a host->device dispatch; one
     compiled executable makes trial startup near-constant.  The rng dict is
     a traced ARGUMENT, so trials with different ``init_rngs`` (per-trial
     init diversity — the reference's torch trials each start from their own

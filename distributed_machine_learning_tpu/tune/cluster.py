@@ -519,7 +519,7 @@ def _worker_run_gang_member(state: _WorkerState, msg: Dict[str, Any],
     if _cc.cache_dir():
         # The child's compiles must land in THIS host's persistent cache
         # so the origin fetch/publish diff sees them.
-        child_env["DML_TPU_COMPILE_CACHE"] = _cc.cache_dir()
+        child_env["JAX_COMPILATION_CACHE_DIR"] = _cc.cache_dir()
 
     terminal: Dict[str, Any]
     handle = None
@@ -693,7 +693,7 @@ def serve_worker(
 
     # Workers own compile amortization the way tune.run does: the host's
     # persistent cache catches repeats across trials AND across sweeps
-    # ($DML_TPU_COMPILE_CACHE scopes it per host), and the artifact origin
+    # ($JAX_COMPILATION_CACHE_DIR scopes it per host), and the artifact origin
     # fetches/publishes entries for it by program key.
     _cc.enable_persistent_cache()
 

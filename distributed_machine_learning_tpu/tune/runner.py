@@ -148,8 +148,9 @@ def run(
     and async-overlap counters land in
     ``experiment_state.json["checkpoint"]`` and TensorBoard either way.
     ``compile_cache_dir``: persistent XLA compile-cache directory ("auto" =
-    ``$DML_TPU_COMPILE_CACHE`` or ``~/.cache/dml_tpu/xla_cache``; None
-    disables).  The framework owns compile-time amortization (SURVEY.md §7):
+    the fixed in-checkout ``.dml_cache/xla``; None disables;
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, wins over any directory given
+    here).  The framework owns compile-time amortization (SURVEY.md §7):
     identical-architecture trials skip XLA backend compilation, and every
     result record carries ``compile_time_s`` / ``compile_cache_hits``.
     ``time_limit_per_trial_s``: per-trial wall-clock budget.  Enforced softly
@@ -161,7 +162,8 @@ def run(
     way.
     ``trial_executor``: "thread" (default; lowest overhead, no preemption) or
     "process" (one OS process per trial with per-process device visibility;
-    requires picklable trainables).
+    requires picklable trainables; refused on a TPU, where this driver
+    holds the chips and a child that needs one cannot open it).
     ``prewarm_runners``: with ``trial_executor="process"``, keep this many
     PRE-WARMED runner children pooled: spawned before any trial is
     assigned, they front-load jax import + device enumeration + compile-

@@ -6,7 +6,7 @@ Run (CPU mesh):
         python examples/training_knobs.py
 
 On TPU drop the overrides; set compute_dtype="bfloat16" for MXU-bound
-model sizes (measured 1.4-1.6x at d_model >= 512 — benchmarks/RESULTS.md;
+model sizes (recorded 1.4-1.6x at d_model >= 512 in an earlier round, not measured on today's code;
 tiny models are faster in f32).
 """
 

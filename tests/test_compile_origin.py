@@ -20,7 +20,7 @@ REPO_ROOT = os.path.dirname(TESTS_DIR)
 
 def _worker_env(cache_dir, extra=None):
     env = {
-        "DML_TPU_COMPILE_CACHE": str(cache_dir),
+        "JAX_COMPILATION_CACHE_DIR": str(cache_dir),
         "PYTHONPATH": os.pathsep.join([REPO_ROOT, TESTS_DIR]),
     }
     if extra:

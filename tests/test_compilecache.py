@@ -267,6 +267,31 @@ def test_aot_corrupt_entry_recompiles(tmp_path):
     np.testing.assert_allclose(np.asarray(g(x)), 0.0)
 
 
+def test_aot_import_refused_at_first_call_recompiles(tmp_path, monkeypatch):
+    """An imported executable the runtime refuses when its (async) result
+    is awaited — XLA:CPU's "function not found" for a deserialized
+    program — costs a recompile and is dropped, never an error."""
+    import jax
+    import jax.numpy as jnp
+
+    store = cc.ExecutableCache(str(tmp_path))
+    fn = lambda x: x + 2  # noqa: E731
+    x = jnp.ones((3,), jnp.float32)
+    store.get_or_compile("pk_refused", fn, x)
+
+    def refused(*args):
+        raise jax.errors.JaxRuntimeError("NOT_FOUND: Function f not found")
+
+    fresh = cc.ExecutableCache(str(tmp_path))
+    monkeypatch.setattr(fresh, "_load_from_disk", lambda key, devs: refused)
+    base = cc.get_counters().snapshot()
+    g = fresh.get_or_compile("pk_refused", fn, x)
+    np.testing.assert_allclose(np.asarray(g(x)), 3.0)
+    d = cc.get_counters().delta_since(base)
+    assert d["aot_imports"] == 1 and d["aot_unsupported"] == 1
+    assert "pk_refused" not in fresh
+
+
 def test_aot_donated_program_roundtrip(tmp_path):
     import jax.numpy as jnp
 
@@ -361,7 +386,7 @@ def test_fresh_process_with_populated_cache_compiles_nothing(tmp_path):
     persistent-cache hit) — asserted from the experiment's own ``compile``
     counter block, not eyeballed."""
     env = dict(os.environ, PYTHONPATH=REPO_ROOT, JAX_PLATFORMS="cpu")
-    env.pop("DML_TPU_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     cache = str(tmp_path / "xla")
     blocks = []
     for i in range(2):
@@ -434,7 +459,7 @@ def test_child_precompile_frame(tmp_path):
 
     env = dict(os.environ, PYTHONPATH=REPO_ROOT, JAX_PLATFORMS="cpu")
     env[pc.PREWARM_ENV] = "1"
-    env["DML_TPU_COMPILE_CACHE"] = str(tmp_path / "xla")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
     proc = subprocess.Popen(
         [sys.executable, "-m",
          "distributed_machine_learning_tpu.tune._process_child"],

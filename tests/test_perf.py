@@ -12,8 +12,8 @@ The contracts under test:
   outlier increments registry counters NAMING the culprit
   (``perf_straggler[<who>]``) and triggers a flight dump; gang-skew
   naming by process id;
-* **regression sentinel** — goldens over the checked-in BENCH_r01–r05
-  artifacts: exactly one comparable chain (chip era), r03–r05 flagged
+* **regression sentinel** — goldens over five synthetic rounds: exactly
+  one comparable chain (the chip round), the CPU rounds flagged
   cpu-fallback/non-comparable, no false regression — and a synthetic
   in-class regression does exit the gate nonzero;
 * **straggler e2e** — a chaos-slowed producer on ONE trial of a
@@ -387,29 +387,54 @@ def test_skew_streak_resets_on_healthy_round():
 
 
 # ---------------------------------------------------------------------------
-# regression sentinel: goldens over the checked-in rounds
+# regression sentinel: goldens over synthetic rounds
 # ---------------------------------------------------------------------------
 
 
-def _repo_rounds():
-    return perf.load_rounds(
-        sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
-        + sorted(glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
-    )
+def _bench_round(tmp_path, n, parsed):
+    path = os.path.join(str(tmp_path), f"BENCH_r{n:02d}.json")
+    with open(path, "w") as f:
+        json.dump({"n": n, "parsed": parsed}, f)
+    return path
 
 
-def test_sentinel_golden_over_checked_in_rounds():
-    """ISSUE 15 acceptance: exactly ONE comparable chain (the chip era),
-    r03–r05 flagged cpu-fallback/non-comparable, NO false regression —
-    the honest verdict the r03–r05 headlines never had."""
-    rounds = _repo_rounds()
-    assert rounds, "checked-in BENCH_r*.json artifacts are gone?"
+def _multichip_round(tmp_path, n, ok=True, n_devices=8):
+    path = os.path.join(str(tmp_path), f"MULTICHIP_r{n:02d}.json")
+    with open(path, "w") as f:
+        json.dump({"ok": ok, "rc": 0 if ok else 1,
+                   "n_devices": n_devices}, f)
+    return path
+
+
+def _five_rounds(tmp_path):
+    """Five synthetic rounds in the shape the sentinel was built for: two
+    that printed nothing parseable, one chip capture, and two CPU
+    captures that must never read as chip regressions."""
+    cpu = {"metric": "trials_per_hour", "unit": "trials/h",
+           "backend": "cpu", "compute_dtype": "float32"}
+    return [
+        _bench_round(tmp_path, 1, None),
+        _bench_round(tmp_path, 2, {
+            "metric": "trials_per_hour", "value": 5000.0,
+            "unit": "trials/h", "backend": "tpu",
+        }),
+        _bench_round(tmp_path, 3, dict(cpu, value=722.64)),
+        _bench_round(tmp_path, 4, None),
+        _bench_round(tmp_path, 5, dict(cpu, value=1372.46)),
+    ] + [_multichip_round(tmp_path, n) for n in range(1, 6)]
+
+
+def test_sentinel_golden_over_five_rounds(tmp_path):
+    """ISSUE 15 acceptance: exactly ONE comparable chain (the chip round),
+    the CPU rounds flagged cpu-fallback/non-comparable, NO false
+    regression."""
+    rounds = perf.load_rounds(_five_rounds(tmp_path))
     report = perf.evaluate_rounds(rounds)
     assert report["reference_backend"] == "tpu"
     assert len(report["comparable_chains"]) == 1
     chain = report["comparable_chains"][0]
     assert chain["backend"] == "tpu"
-    assert chain["rounds"] == [2]  # the chip-era capture
+    assert chain["rounds"] == [2]  # the chip capture
     fallback = {fb["round"]: fb for fb in report["fallback_rounds"]}
     assert set(fallback) == {3, 5}  # r04 is unparsed, not mis-bucketed
     for fb in fallback.values():
@@ -425,13 +450,6 @@ def test_sentinel_golden_over_checked_in_rounds():
     # Render must not throw and must carry the verdict line.
     text = perf.render_report(report)
     assert "no in-class regression" in text
-
-
-def _bench_round(tmp_path, n, parsed):
-    path = os.path.join(str(tmp_path), f"BENCH_r{n:02d}.json")
-    with open(path, "w") as f:
-        json.dump({"n": n, "parsed": parsed}, f)
-    return path
 
 
 def test_sentinel_flags_in_class_regression(tmp_path):
@@ -479,14 +497,15 @@ def test_sentinel_dtype_change_is_non_comparable(tmp_path):
     assert len(report["comparable_chains"]) == 2
 
 
-def test_perf_compare_cli_gate():
-    """The CI smoke gate: exit 0 over the checked-in artifacts, human
+def test_perf_compare_cli_gate(tmp_path):
+    """The CI smoke gate: exit 0 over a set of round artifacts, human
     report on stdout."""
+    _five_rounds(tmp_path)
     proc = subprocess.run(
         [sys.executable, "-m", "distributed_machine_learning_tpu",
          "perf", "compare", "--artifacts",
-         os.path.join(REPO, "BENCH_r*.json"),
-         os.path.join(REPO, "MULTICHIP_r*.json")],
+         os.path.join(str(tmp_path), "BENCH_r*.json"),
+         os.path.join(str(tmp_path), "MULTICHIP_r*.json")],
         capture_output=True, text=True, timeout=120,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )

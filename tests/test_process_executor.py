@@ -171,3 +171,24 @@ def test_process_executor_real_jax_trial(tmp_path):
     assert trial.status == TrialStatus.TERMINATED
     assert trial.training_iteration == 2
     assert trial.last_result["validation_loss"] > 0
+
+
+def test_process_executor_refuses_a_tpu_driver(monkeypatch):
+    """On a TPU the driver that enumerated the devices holds the chip, and
+    a child that needs it dies at backend start-up (seen on a v5e chip):
+    the executor says so at once instead of failing every trial."""
+    import queue
+    import types
+
+    import jax
+    import pytest
+
+    from distributed_machine_learning_tpu.tune.executor import (
+        ProcessTrialExecutor,
+    )
+
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")]
+    )
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        ProcessTrialExecutor(store=None, event_queue=queue.Queue())

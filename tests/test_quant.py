@@ -278,7 +278,9 @@ def test_restarted_replica_imports_int8_programs_without_compiling(
         if f.endswith(".aotexec")
     )
     assert exported, "warmup exported no programs"
-    if aot_lib.ExecutableCache(str(tmp_path))._load_from_disk(exported[0]) is None:
+    if aot_lib.ExecutableCache(str(tmp_path))._load_from_disk(
+        exported[0], [jax.devices()[0]]
+    ) is None:
         pytest.skip("backend cannot deserialize its exported bucket programs")
 
     # "Restart": a brand-new engine, same bundle, same AOT directory.
