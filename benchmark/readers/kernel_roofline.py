@@ -1,0 +1,27 @@
+"""A kernel's share of its roofline from the device trace: the least time
+the chip could take for the calls seen (operations and bytes from
+``benchmark/flops.py`` by the call's shapes, the larger of the two bounds)
+over the summed device time of the kernel's events."""
+
+from benchmark import flops
+from benchmark import trace as trace_lib
+
+
+def read(spec: dict, run):
+    call = run.facts.get("attention_call")
+    if run.trace is None or not call or not run.peaks:
+        return None
+    seconds, events = 0.0, 0
+    for dev in run.trace.devices.values():
+        s, n = trace_lib.kernel_seconds(dev, run.trace_window, **spec["match"])
+        seconds += s
+        events += n
+    if not events or seconds <= 0.0:
+        return None
+    calls = events / float(spec.get("events_per_call", 1))
+    ops, nbytes = getattr(flops, spec["cost"])(**call)
+    floor_s, bound = flops.roofline_floor_s(ops, nbytes, run.peaks)
+    print(f"[bench] {spec['cost']}: {events} events, {seconds:.6f}s on the "
+          f"device, floor {floor_s * calls:.6f}s, bound by {bound}",
+          flush=True)
+    return 100.0 * floor_s * calls / seconds
