@@ -337,7 +337,25 @@ def write_snapshot(path: str, skeleton, leaves: List[Any]) -> Tuple[int, int]:
     """Write a snapshotted tree as one generation under ``path``; returns
     ``(bytes_written, chunks_written)``.  Order is the commit protocol:
     chunk payloads -> (CAS mode: manifest -> ref) -> index.json -> COMMIT
-    (multi-process: barriers between the phases, see :func:`_mh_barrier`)."""
+    (multi-process: barriers between the phases, see :func:`_mh_barrier`).
+
+    One ``ckpt.write`` span: chunks are made into bytes, hashed and written
+    one at a time, so the seconds of the first two are an attr of the span
+    (``serialize_s``) and not a span of their own."""
+    from distributed_machine_learning_tpu import obs
+
+    with obs.span("ckpt.write") as write_span:
+        nbytes, nchunks, serialize_s = _write_generation(
+            path, skeleton, leaves
+        )
+        write_span.set("bytes", nbytes).set("chunks", nchunks).set(
+            "serialize_s", round(serialize_s, 6)
+        )
+    return nbytes, nchunks
+
+
+def _write_generation(path: str, skeleton,
+                      leaves: List[Any]) -> Tuple[int, int, float]:
     backend, p = get_storage(path)
     # Re-saving over a previous attempt at the same step: drop its COMMIT
     # FIRST so no reader ever pairs the old marker with new bytes.
@@ -351,6 +369,7 @@ def write_snapshot(path: str, skeleton, leaves: List[Any]) -> Tuple[int, int]:
     gen_digests: List[str] = []
     total_bytes = 0
     total_chunks = 0
+    serialize_s = 0.0
     index_leaves: List[Dict[str, Any]] = []
     try:
         for n, leaf in enumerate(leaves):
@@ -359,6 +378,7 @@ def write_snapshot(path: str, skeleton, leaves: List[Any]) -> Tuple[int, int]:
                 continue
             chunk_recs = []
             for start, stop, arr in leaf.chunks:
+                t0 = time.perf_counter()
                 contiguous = np.ascontiguousarray(arr)
                 data = contiguous.tobytes()
                 fname = _chunk_file_name(n, start)
@@ -369,6 +389,7 @@ def write_snapshot(path: str, skeleton, leaves: List[Any]) -> Tuple[int, int]:
                     "nbytes": len(data),
                     "sha256": hashlib.sha256(data).hexdigest(),
                 }
+                serialize_s += time.perf_counter() - t0
                 if cas is not None:
                     blob_recs = []
                     for off, ln in store_lib.split_row_aligned(
@@ -457,13 +478,16 @@ def write_snapshot(path: str, skeleton, leaves: List[Any]) -> Tuple[int, int]:
     finally:
         if pin is not None:
             pin.release()
-    return total_bytes, total_chunks
+    return total_bytes, total_chunks, serialize_s
 
 
 def save_sharded(path: str, tree) -> str:
     """Snapshot + write ``tree`` as a committed generation at ``path``."""
+    from distributed_machine_learning_tpu import obs
+
     t0 = time.time()
-    skeleton, leaves = snapshot_tree(tree)
+    with obs.span("ckpt.device_get"):
+        skeleton, leaves = snapshot_tree(tree)
     nbytes, nchunks = write_snapshot(path, skeleton, leaves)
     get_metrics().record_save(time.time() - t0, nbytes, max(nchunks, 1))
     return path

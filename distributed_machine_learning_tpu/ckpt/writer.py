@@ -93,8 +93,11 @@ class AsyncCheckpointer:
         self._raise_pending_error()
         import time as _time
 
+        from distributed_machine_learning_tpu import obs
+
         t0 = _time.time()
-        skeleton, leaves = fmt.snapshot_tree(tree)
+        with obs.span("ckpt.device_get"):
+            skeleton, leaves = fmt.snapshot_tree(tree)
         metrics = get_metrics()
         metrics.add("save_block_s", _time.time() - t0)
         done = threading.Event()

@@ -8,7 +8,9 @@ Three legs, one import:
   process child (init frame), head -> cluster worker (dispatch frame),
   serve request -> replica -> batcher -> engine (pending entries).
   Per-process JSONL span files merge into Chrome-trace/Perfetto JSON
-  (``obs/export.py``, ``dml-tpu trace``).
+  (``obs/export.py``, ``dml-tpu trace``).  While a ``jax.profiler``
+  session runs, every span also lands in its host plane as
+  ``dml:<name>``, on the device trace's clock.
 * **Always-on flight recorder** (``obs/flight.py``): a bounded,
   preallocated, lock-free ring of recent events per process, dumped
   automatically on watchdog expiry, STALLED transitions, lease expiry,
@@ -18,7 +20,8 @@ Three legs, one import:
   cluster head aggregates worker snapshots into one place.
 
 Everything is stdlib-only and safe to import anywhere (no jax at import
-time); the disabled tracing path is a single None-check.
+time); the disabled tracing path is two None-checks and one
+``is_enabled()`` on the profiler.
 
 See docs/observability.md for the span names, flight-recorder
 triggers, and the counter -> registry migration map.
@@ -26,9 +29,7 @@ triggers, and the counter -> registry migration map.
 
 from __future__ import annotations
 
-import contextlib
 import os
-import threading
 from typing import Any, Dict, Optional, Tuple
 
 from distributed_machine_learning_tpu.obs.flight import (
@@ -74,7 +75,7 @@ __all__ = [
     "detached_span", "disabled_path_overhead", "dump_dir",
     "dump_flight_recorder", "event",
     "flush", "get_flight_recorder", "get_registry", "get_tracer",
-    "install_tracer", "maybe_profile_trial", "merge_trace_dir",
+    "install_tracer", "merge_trace_dir",
     "read_trace_files", "record_event", "set_dump_dir",
     "set_process_context", "shutdown", "span", "summarize_trace",
     "trace_context_frame", "tracing_enabled",
@@ -165,54 +166,3 @@ def configure_from_frame(ctx: Optional[Dict[str, Any]],
         parent_span_id=ctx.get("parent_span_id"),
         dump_dir=ctx.get("dump_dir"),
     )
-
-
-# -- opt-in jax profiler capture ----------------------------------------------
-
-_profile_lock = threading.Lock()
-_profile_active = [False]
-
-
-@contextlib.contextmanager
-def maybe_profile_trial(profile_dir: Optional[str], trial_id: str):
-    """Programmatic ``jax.profiler`` capture around one trial
-    (``tune.run(trace_profile_trials=N)``): traces into
-    ``profile_dir/<trial_id>/``.  The jax trace is process-global, so
-    only one capture runs at a time — a second concurrent trial simply
-    skips (counted), it never fails.  Any profiler error is absorbed:
-    profiling is forensics, not a dependency."""
-    if not profile_dir:
-        yield
-        return
-    with _profile_lock:
-        if _profile_active[0]:
-            get_registry().add("profile_skips")
-            claimed = False
-        else:
-            _profile_active[0] = claimed = True
-    if not claimed:
-        yield
-        return
-    started = False
-    try:
-        try:
-            import jax
-
-            target = os.path.join(profile_dir, str(trial_id))
-            os.makedirs(target, exist_ok=True)
-            jax.profiler.start_trace(target)
-            started = True
-            get_registry().add("profile_captures")
-        except Exception:  # noqa: BLE001 - profiling must not fail trials
-            get_registry().add("profile_errors")
-        yield
-    finally:
-        if started:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001
-                get_registry().add("profile_errors")
-        with _profile_lock:
-            _profile_active[0] = False

@@ -9,9 +9,18 @@ process's trace buffer AND to a per-process JSON-lines file under
 The driver merges the per-process files into one Chrome-trace JSON at
 experiment end (``obs/export.py``; ``dml-tpu trace export``).
 
-Disabled (the default), ``span`` costs ONE global read + None-check and
-returns a singleton no-op context manager — no allocation, a few hundred
-ns, cheap enough to leave at every epoch/request/chunk boundary
+Two sinks, one primitive.  A span also lands in the ``jax.profiler``
+trace's host plane as ``dml:<name>`` (a ``TraceAnnotation`` carrying the
+span's scalar attrs) whenever a profiler session is running — the device
+trace's clock, so an idle gap on the device can be put down to the span
+the host was in.  No switch: "on" means someone called
+``jax.profiler.start_trace``.  jax is never imported from here; the
+annotation class is picked up from ``sys.modules`` once jax is there.
+
+Disabled (no tracer, no profiler session: the default), ``span`` costs
+two global reads, an ``is_enabled()`` on the profiler and returns a
+singleton no-op context manager — no allocation, a few hundred ns, cheap
+enough to leave at every epoch/request/chunk boundary
 (tests/test_obs_plane.py pins this with an allocation + latency guard).
 
 Cross-boundary context: a span's identity is ``(trace_id, span_id)``.
@@ -29,6 +38,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -62,16 +72,41 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+# -- the profiler's host plane --------------------------------------------------
+
+PLANE_PREFIX = "dml:"
+_PLANE_SCALARS = (str, int, float)  # bool is an int
+# An annotation travels as ``name#k=v,k=v#``: these would end a value early.
+_PLANE_UNSAFE = str.maketrans({"#": "_", ",": "_"})
+
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _find_annotation():
+    """``jax.profiler.TraceAnnotation`` if the process has imported jax,
+    else None — looked up, never imported, so obs stays stdlib-only."""
+    global _annotation
+    profiler = sys.modules.get("jax.profiler")
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+def _plane_value(value):
+    return value.translate(_PLANE_UNSAFE) if isinstance(value, str) else value
+
 
 class Span:
-    """One live span.  Use as a context manager or call :meth:`end`."""
+    """One live span.  Use as a context manager or call :meth:`end`.
+
+    ``_tracer`` is None when only a profiler session is listening: the
+    span then has no ids and lands in the host plane alone."""
 
     __slots__ = (
         "name", "attrs", "trace_id", "span_id", "parent_id",
-        "_t0_mono", "_t0_wall", "_tracer", "_stacked", "_ended",
+        "_t0_mono", "_t0_wall", "_tracer", "_stacked", "_ended", "_ann",
     )
 
-    def __init__(self, tracer: "Tracer", name: str,
+    def __init__(self, tracer: Optional["Tracer"], name: str,
                  attrs: Optional[Dict[str, Any]],
                  trace_id: str, span_id: str, parent_id: Optional[str],
                  stacked: bool):
@@ -83,31 +118,52 @@ class Span:
         self._tracer = tracer
         self._stacked = stacked
         self._ended = False
+        self._ann = None
         self._t0_mono = time.monotonic()
         self._t0_wall = time.time()
 
+    def _annotate(self, annotation) -> "Span":
+        """Open this span in the running profiler session's host plane."""
+        meta = {
+            k: _plane_value(v) for k, v in self.attrs.items()
+            if isinstance(v, _PLANE_SCALARS)
+        }
+        if self.span_id:
+            meta["span_id"] = self.span_id
+            if self.parent_id:
+                meta["parent_id"] = self.parent_id
+        self._ann = annotation(PLANE_PREFIX + self.name, **meta)
+        return self
+
     def set(self, key: str, value) -> "Span":
         self.attrs[key] = value
+        if self._ann is not None and isinstance(value, _PLANE_SCALARS):
+            self._ann.set_metadata(**{key: _plane_value(value)})
         return self
 
     @property
-    def context(self) -> Tuple[str, str]:
+    def context(self) -> Optional[Tuple[str, str]]:
         """``(trace_id, span_id)`` — hand this across a queue/frame and
         open the far side's span with ``parent=context``."""
+        if self._tracer is None:
+            return None
         return (self.trace_id, self.span_id)
 
     def end(self) -> None:
         if self._ended:
             return
         self._ended = True
-        self._tracer._finish(self)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if self._tracer is not None:
+            self._tracer._finish(self)
 
     def __enter__(self) -> "Span":
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if exc_type is not None:
-            self.attrs.setdefault("error", exc_type.__name__)
+        if exc_type is not None and "error" not in self.attrs:
+            self.set("error", exc_type.__name__)
         self.end()
         return False
 
@@ -182,7 +238,9 @@ class Tracer:
                     stacked=False)
 
     def _new_id(self) -> str:
-        return f"{os.getpid():x}.{next(self._ids):x}"
+        # "-", not ".": the profiler's trace reads a stat that parses as a
+        # number as one, and "1673.10" would come back as 1673.1.
+        return f"{os.getpid():x}-{next(self._ids):x}"
 
     def _finish(self, span: Span) -> None:
         if span._stacked:
@@ -310,13 +368,21 @@ def tracing_enabled() -> bool:
 
 def span(name: str, attrs: Optional[Dict[str, Any]] = None,
          parent: Optional[Tuple[str, str]] = None):
-    """THE instrumentation call.  Disabled: one global read, a None-check,
-    and a shared no-op object back — nothing allocated (the perf guard
-    in tests/test_obs_plane.py holds this to a few hundred ns/call)."""
+    """THE instrumentation call.  With no tracer installed and no
+    ``jax.profiler`` session running: two global reads, one
+    ``is_enabled()``, and a shared no-op object back — nothing allocated
+    (the perf guard in tests/test_obs_plane.py holds this to a
+    microsecond or so a call)."""
     t = _tracer
+    annotation = _annotation or _find_annotation()
+    on_plane = annotation is not None and annotation.is_enabled()
     if t is None:
-        return _NOOP
-    return t.start(name, attrs, parent)
+        if not on_plane:
+            return _NOOP
+        return Span(None, name, attrs, None, None, None,
+                    stacked=False)._annotate(annotation)
+    live = t.start(name, attrs, parent)
+    return live._annotate(annotation) if on_plane else live
 
 
 def detached_span(name: str, attrs: Optional[Dict[str, Any]] = None,
@@ -367,9 +433,11 @@ def active_span_stacks() -> Dict[str, List[Dict[str, Any]]]:
 
 
 def disabled_path_overhead(iters: int = 100_000) -> Dict[str, float]:
-    """Measure the tracing-DISABLED ``span()`` path: ns per call and net
-    allocated blocks across ``iters`` spans (must be ~0 — the disabled
-    path returns a shared singleton and allocates nothing).
+    """Measure the tracing-DISABLED ``span()`` path (no tracer; and, where
+    the caller has imported jax, the profiler bridge with no session
+    running): ns per call and net allocated blocks across ``iters`` spans
+    (must be ~0 — the disabled path returns a shared singleton and
+    allocates nothing).
 
     This is the contract that makes always-on instrumentation acceptable
     in epoch/request/chunk hot paths.  Shared by the tier-1 perf guard
@@ -377,7 +445,6 @@ def disabled_path_overhead(iters: int = 100_000) -> Dict[str, float]:
     ``DML_OBS_PERF_GUARD=1``) so a regression gates the diff.  Any
     installed tracer is stashed and restored around the measurement.
     """
-    import sys
     import time as _time
 
     global _tracer

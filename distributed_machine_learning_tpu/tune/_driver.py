@@ -15,6 +15,7 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from distributed_machine_learning_tpu import obs
 from distributed_machine_learning_tpu.tune.schedulers.base import (
     CONTINUE,
     REQUEUE,
@@ -497,17 +498,21 @@ class TrialLifecycle:
         if extra:
             metrics.update(extra)
         trial.results.append(metrics)
-        self.store.append_result(trial, metrics)
-        self._prune_checkpoints(trial)
+        with obs.span("runner.store_append"):
+            self.store.append_result(trial, metrics)
+            self._prune_checkpoints(trial)
 
         # Snapshot before the scheduler runs: PBT mutates trial.config in
         # place on REQUEUE, and the searcher must see the config that
         # actually produced these metrics.
         reported_config = dict(trial.config)
-        decision = self.scheduler.on_trial_result(trial, metrics)
-        self.searcher.on_trial_result(
-            trial.trial_id, reported_config, metrics, self.metric, self.mode
-        )
+        with obs.span("runner.scheduler"):
+            decision = self.scheduler.on_trial_result(trial, metrics)
+        with obs.span("runner.searcher"):
+            self.searcher.on_trial_result(
+                trial.trial_id, reported_config, metrics, self.metric,
+                self.mode,
+            )
         if self.stop_rules:
             # Dict of key->threshold, or a callable/Stopper
             # (tune/stoppers.py) judging this trial's own trajectory.
@@ -550,17 +555,18 @@ class TrialLifecycle:
                     "restore_base": trial.restore_base,
                 }
             value = metrics.get(self.metric)
-            self.journal.record_report(
-                trial.trial_id,
-                int(metrics.get("training_iteration",
-                                trial.training_iteration)),
-                "requeue" if requeued
-                else ("stop" if decision == STOP else "continue"),
-                float(value)
-                if isinstance(value, (int, float)) else None,
-                self._snapshot(),
-                requeue=requeue_payload,
-            )
+            with obs.span("runner.journal"):
+                self.journal.record_report(
+                    trial.trial_id,
+                    int(metrics.get("training_iteration",
+                                    trial.training_iteration)),
+                    "requeue" if requeued
+                    else ("stop" if decision == STOP else "continue"),
+                    float(value)
+                    if isinstance(value, (int, float)) else None,
+                    self._snapshot(),
+                    requeue=requeue_payload,
+                )
         return "stop" if decision == STOP else "continue"
 
     def final_prune(self) -> None:

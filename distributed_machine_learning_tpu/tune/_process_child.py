@@ -263,9 +263,7 @@ def main() -> None:
                 heartbeat_fn=heartbeat_fn,
             )
         )
-        with obs.maybe_profile_trial(
-            init.get("obs_profile_dir"), init["trial_id"]
-        ), obs.span(
+        with obs.span(
             "trial",
             {"trial_id": init["trial_id"],
              "incarnation": int(init.get("incarnation", 0))},

@@ -436,8 +436,11 @@ class ProfilerCallback(Callback):
 
     The trace is process-global (trials share the process), so this profiles
     the whole sweep — XLA compilations, device compute, and the host-side
-    scheduler — into ``<root>/profile`` for TensorBoard/XProf.  ``duration_s``
-    bounds the capture window to keep traces small on long sweeps.
+    scheduler — into ``<root>/profile`` for TensorBoard/XProf.  While it
+    runs, every ``obs.span`` of the program lands in it as a
+    ``dml:<name>`` host event on the device trace's clock
+    (docs/observability.md).  ``duration_s`` bounds the capture window to
+    keep traces small on long sweeps.
     """
 
     def __init__(self, logdir: Optional[str] = None,

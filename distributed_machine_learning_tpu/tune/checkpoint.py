@@ -88,19 +88,25 @@ def save_checkpoint(path: str, tree: Dict[str, Any]) -> str:
     if _is_sharded(path):
         with obs.span("ckpt.save", {"format": "sharded"}):
             return _sharded_fmt.save_sharded(path, tree)
-    with obs.span("ckpt.save", {"format": "msgpack"}):
+    with obs.span("ckpt.save", {"format": "msgpack"}) as save_span:
         t0 = time.time()
-        payload = serialization.to_bytes(_to_host(tree))
-        backend, p = get_storage(path)
-        backend.write_bytes(p, payload)
-        manifest = {
-            "sha256": hashlib.sha256(payload).hexdigest(),
-            "bytes": len(payload),
-            "format": "flax-msgpack",
-        }
-        backend.write_bytes(
-            manifest_path_for(p), json.dumps(manifest).encode()
-        )
+        with obs.span("ckpt.device_get"):
+            host_tree = _to_host(tree)
+        with obs.span("ckpt.serialize"):
+            payload = serialization.to_bytes(host_tree)
+            del host_tree  # as before the spans: not held through the write
+        save_span.set("bytes", len(payload))
+        with obs.span("ckpt.write"):  # payload, its sha256, the manifest
+            backend, p = get_storage(path)
+            backend.write_bytes(p, payload)
+            manifest = {
+                "sha256": hashlib.sha256(payload).hexdigest(),
+                "bytes": len(payload),
+                "format": "flax-msgpack",
+            }
+            backend.write_bytes(
+                manifest_path_for(p), json.dumps(manifest).encode()
+            )
         get_metrics().record_save(time.time() - t0, len(payload), 1)
     return path
 
@@ -379,9 +385,15 @@ class AsyncCheckpointWriter:
 
     def submit(self, path: str, tree: Dict[str, Any]) -> str:
         """Enqueue a write; returns ``path`` immediately."""
+        from distributed_machine_learning_tpu import obs
+
         metrics = get_metrics()
         t0 = time.time()
-        snapshot = jax.tree.map(self._snapshot_leaf, tree)
+        leaves, treedef = jax.tree.flatten(tree)
+        with obs.span("report.ckpt_snapshot", {"leaves": len(leaves)}):
+            snapshot = treedef.unflatten(
+                [self._snapshot_leaf(x) for x in leaves]
+            )
         metrics.add("save_block_s", time.time() - t0)
         done = threading.Event()
         with self._lock:

@@ -266,6 +266,13 @@ class ThreadTrialExecutor:
         pending_writes = deque()  # this incarnation's in-flight ckpt paths
 
         def report_fn(metrics: Dict, checkpoint) -> str:
+            with obs.span("report", {
+                "trial_id": trial.trial_id,
+                "iteration": trial.training_iteration + 1,
+            }):
+                return _report(metrics, checkpoint)
+
+        def _report(metrics: Dict, checkpoint) -> str:
             # Chaos hooks (no-op without an active plan): an injected hang
             # sleeps HERE — before the result reaches the runner — so the
             # report gap the liveness watchdog measures actually opens; an
@@ -320,7 +327,9 @@ class ThreadTrialExecutor:
                 skip = False
                 while len(pending_writes) >= 2:
                     oldest = pending_writes.popleft()
-                    if not self._ckpt_writer.wait(oldest, timeout=120.0):
+                    with obs.span("report.ckpt_drain"):
+                        drained = self._ckpt_writer.wait(oldest, timeout=120.0)
+                    if not drained:
                         print(
                             f"[executor] WARNING: checkpoint write for "
                             f"{trial.trial_id} still hung after 120s; "
@@ -339,7 +348,8 @@ class ThreadTrialExecutor:
                     trial.latest_checkpoint_iteration = count
             event = ResultEvent(trial, metrics, incarnation)
             self.events.put(("result", event))
-            event.done.wait()
+            with obs.span("report.decide_wait"):
+                event.done.wait()
             return event.decision
 
         def checkpoint_loader():
@@ -369,15 +379,10 @@ class ThreadTrialExecutor:
         set_session(Session(trial, report_fn, checkpoint_loader, devices,
                             heartbeat_fn=heartbeat_fn))
         try:
-            # TraceAnnotation tags this trial's host activity in profiler
-            # captures (ProfilerCallback), so per-trial spans are visible.
             # The obs span parents under the driver's trial.dispatch span
-            # (same thread stack from here on: epoch/ckpt spans nest).
-            with jax.default_device(devices[0]), jax.profiler.TraceAnnotation(
-                f"trial:{trial.trial_id}"
-            ), obs.maybe_profile_trial(
-                getattr(trial, "_obs_profile_dir", None), trial.trial_id
-            ), obs.span(
+            # (same thread stack from here on: epoch/report spans nest), and
+            # tags this trial's host activity in any profiler capture.
+            with jax.default_device(devices[0]), obs.span(
                 "trial",
                 {"trial_id": trial.trial_id, "incarnation": incarnation},
                 parent=getattr(trial, "_obs_parent", None),
@@ -734,9 +739,6 @@ class ProcessTrialExecutor:
                     # its flight ring into the experiment dir.
                     "obs": obs.trace_context_frame(
                         parent=getattr(trial, "_obs_parent", None)
-                    ),
-                    "obs_profile_dir": getattr(
-                        trial, "_obs_profile_dir", None
                     ),
                     "incarnation": incarnation,
                 },

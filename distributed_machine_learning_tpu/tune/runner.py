@@ -101,7 +101,6 @@ def run(
     progress_deadline_s: Optional[float] = None,
     progress_grace_s: Optional[float] = None,
     trace: bool = False,
-    trace_profile_trials: int = 0,
 ) -> ExperimentAnalysis:
     """Run an HPO experiment; see module docstring.
 
@@ -205,15 +204,18 @@ def run(
     None-check per span.  Either way the run points the always-on flight
     recorder at the experiment root: a stall, kill, or SIGTERM dumps the
     last ~2048 events (``flightrec_*.json``) with per-thread open-span
-    stacks — the hang site, not just a counter.
-    ``trace_profile_trials``: programmatically ``jax.profiler``-capture
-    the first N trials into ``<experiment>/profile/<trial_id>/`` (one at
-    a time; concurrent candidates skip, counted).  Independent of
-    ``trace``.
+    stacks — the hang site, not just a counter.  Independent of ``trace``,
+    every span also lands in any running ``jax.profiler`` capture
+    (``ProfilerCallback``) as ``dml:<name>``, on the device trace's clock.
     """
     if mode not in ("min", "max"):
         raise ValueError(f"mode must be 'min' or 'max', got {mode!r}")
+    from distributed_machine_learning_tpu import obs as obs_lib
     from distributed_machine_learning_tpu.tune import journal as journal_lib
+
+    # Store, journal, executor, lifecycle: ended where the experiment span
+    # opens; a set-up that raises drops it.
+    setup_span = obs_lib.span("run.setup")
 
     # resume="auto": resume IFF a prior head left an uncommitted decision
     # journal behind (crash mid-sweep); a committed journal or no journal
@@ -289,8 +291,6 @@ def run(
     # <root>/trace/ per process, merged at teardown.
     import os as _os
 
-    from distributed_machine_learning_tpu import obs as obs_lib
-
     trace = trace or _os.environ.get("DML_OBS_TRACE") == "1"
     trace_dir = _os.path.join(store.root, "trace") if trace else None
     prev_dump_dir = obs_lib.dump_dir()
@@ -307,11 +307,6 @@ def run(
     # effect; `journal.commit()` at clean teardown is what "auto" checks for.
     journal = journal_lib.ExperimentJournal(store.root)
     head_incarnation = journal.open(obs_frame=obs_lib.trace_context_frame())
-    profile_dir = (
-        _os.path.join(store.root, "profile")
-        if trace_profile_trials > 0 else None
-    )
-    profile_budget = [max(int(trace_profile_trials), 0)]
     obs_counters_base = obs_lib.get_registry().counters_snapshot()
     device_mgr = DeviceManager(devices)
     events: "queue.Queue" = queue.Queue()
@@ -426,11 +421,6 @@ def run(
             )
             trial_spans[trial.trial_id] = span
             trial._obs_parent = span.context
-            if profile_dir is not None and profile_budget[0] > 0:
-                profile_budget[0] -= 1
-                trial._obs_profile_dir = profile_dir
-            else:
-                trial._obs_profile_dir = None
             obs_lib.event("trial_dispatch", {"trial_id": trial.trial_id})
             safe_cb("on_trial_start", trial)
             executor.start_trial(trial, trainable, leased)
@@ -619,22 +609,30 @@ def run(
                         trial.stall_recoveries += 1
                         log(f"{trial.trial_id} recovered after stall "
                             f"(report resumed)")
-                result_event.decision = lifecycle.process_result(
-                    trial, result_event.metrics
-                )
+                with obs_lib.span("runner.process_result", {
+                    "trial_id": trial.trial_id,
+                    "iteration": trial.training_iteration + 1,
+                }):
+                    result_event.decision = lifecycle.process_result(
+                        trial, result_event.metrics
+                    )
                 # Unblock the trial thread BEFORE observers run: a slow or
                 # buggy callback must not stall (or hang) training.
                 result_event.done.set()
-                safe_cb("on_trial_result", trial, trial.last_result)
+                with obs_lib.span("runner.callbacks"):
+                    safe_cb("on_trial_result", trial, trial.last_result)
                 # Forensics (satellite of the durable-control-plane work):
                 # persist the scheduler/searcher debug snapshot at report
                 # boundaries, throttled so a chatty sweep doesn't rewrite
                 # experiment_state.json on every epoch.
                 if time.time() - last_sched_persist > 2.0:
                     last_sched_persist = time.time()
-                    store.write_state(trials, extra={
-                        "scheduler": scheduler_debug_block(searcher, sched),
-                    })
+                    with obs_lib.span("runner.write_state"):
+                        store.write_state(trials, extra={
+                            "scheduler": scheduler_debug_block(
+                                searcher, sched
+                            ),
+                        })
 
             elif kind == "complete":
                 trial = event[1]
@@ -665,6 +663,7 @@ def run(
     # callbacks must see experiment end so e.g. ProfilerCallback stops the
     # process-global trace and JsonlCallback closes its file.
     clean_end = False
+    setup_span.end()
     try:
         # The experiment root span: every driver-side span (trial
         # dispatches) and, via frame context, every child/worker span
@@ -683,6 +682,9 @@ def run(
         # processes holding devices (process executor terminates children;
         # thread executor best-effort joins).
         wall = time.time() - start_time
+        # Hand-ended after the last callback: the join, the writer's wait
+        # for its last write, the final state write, the trace merge.
+        teardown_span = obs_lib.span("run.teardown")
         try:
             executor.join_all(timeout=5.0)
         except Exception as exc:  # noqa: BLE001
@@ -816,6 +818,7 @@ def run(
         if counter_scalars:
             safe_cb("on_experiment_counters", counter_scalars)
         safe_cb("on_experiment_end", trials, wall)
+        teardown_span.end()
     analysis = ExperimentAnalysis(
         trials, metric=metric, mode=mode, root=store.root, wall_clock_s=wall,
         device_utilization=utilization,

@@ -233,6 +233,9 @@ def train_regressor(
             config, train_data, val_data, device, compute_dtype
         )
 
+    # Hand-ended before the epoch loop (everything up to there is set-up);
+    # a set-up that raises drops the span with the trial.
+    setup_span = obs.span("trial.setup")
     accum = max(int(config.get("accumulate_grad_batches", 1)), 1)
     lr = float(config["learning_rate"])
     wd = float(config.get("weight_decay", 0.0))
@@ -252,10 +255,11 @@ def train_regressor(
     )
 
     def _build_bundle(use_injected) -> _CohortBundle:
-        data = stage_data(
-            train_data, val_data, int(config.get("batch_size", 32)),
-            compute_dtype,
-        )
+        with obs.span("trial.stage_data"):
+            data = stage_data(
+                train_data, val_data, int(config.get("batch_size", 32)),
+                compute_dtype,
+            )
         steps_per_epoch = data.num_batches
         # The schedule advances once per OPTIMIZER step; with accumulation
         # that is steps_per_epoch // accum per epoch, not per micro-batch.
@@ -326,17 +330,20 @@ def train_regressor(
             steps_per_epoch=steps_per_epoch, total_steps=total_steps,
         )
 
-    if injected and bool(config.get("share_programs", True)):
-        # Everything in the bundle is trial-independent under injection:
-        # one build serves the whole cohort (and the per-key lock makes
-        # the cohort's first backend compile exactly-once in-process).
-        bundle = _cohort_bundle_for(
-            config, train_data, val_data, device,
-            lambda: _build_bundle(True),
-        )
-    else:
-        with dispatch_lock():
-            bundle = _build_bundle(injected)
+    # Model, optimizer and program lookup; staging is its child on a miss.
+    with obs.span("trial.build"):
+        if injected and bool(config.get("share_programs", True)):
+            # Everything in the bundle is trial-independent under
+            # injection: one build serves the whole cohort (and the
+            # per-key lock makes the cohort's first backend compile
+            # exactly-once in-process).
+            bundle = _cohort_bundle_for(
+                config, train_data, val_data, device,
+                lambda: _build_bundle(True),
+            )
+        else:
+            with dispatch_lock():
+                bundle = _build_bundle(injected)
     data = bundle.data
     steps_per_epoch = bundle.steps_per_epoch
     total_steps = bundle.total_steps
@@ -345,6 +352,7 @@ def train_regressor(
     train_epoch = bundle.train_epoch
     evaluate = bundle.evaluate
 
+    init_span = obs.span("trial.init_or_restore")  # ends with the restore
     # Device-call section: serialized across concurrent trial threads
     # when DML_SERIALIZE_DISPATCH is on (utils/dispatch.py; off by default).
     with dispatch_lock():
@@ -438,6 +446,7 @@ def train_regressor(
             # from config).
             with dispatch_lock():
                 opt_state = set_injected_hyperparams(opt_state, lr, wd)
+    init_span.end()
 
     checkpoint_freq = int(config.get("checkpoint_freq", 1))
 
@@ -460,6 +469,7 @@ def train_regressor(
         trial_id=session.current_trial_id(),
     )
     tracker = get_tracker()
+    setup_span.end()
 
     import time as _time
 
@@ -478,31 +488,36 @@ def train_regressor(
         # trials' whole epochs — as this trial's execute time and
         # deflate mfu by ~Nx under serialization.
         with obs.span("epoch", {"epoch": epoch}), dispatch_lock():
-            epoch_key = jax.random.key(
-                fold_seed(seed, "epoch", epoch), impl=rng_impl
-            )
-            # Optax schedules are jnp-based: evaluating one IS a (small)
-            # device dispatch, so it rides inside the hold too — placed
-            # before the t0/c0 stamps so it never counts as epoch execute
-            # time.  Every registered schedule is linear in learning_rate,
-            # so lr x the peak-1.0 shape IS the effective rate on both the
-            # injected and baked paths.
-            lr_now = lr * float(shape_schedule(min(opt_steps, total_steps)))
-            c0 = tracker.thread_seconds()
-            t0 = _time.time()
-            params, opt_state, batch_stats, train_loss = train_epoch(
-                params, opt_state, batch_stats, data.x_train, data.y_train,
-                epoch_key
-            )
-            metrics = evaluate(
-                params, batch_stats, data.x_val, data.y_val, data.val_mask
-            )
+            with obs.span("epoch.dispatch"):
+                epoch_key = jax.random.key(
+                    fold_seed(seed, "epoch", epoch), impl=rng_impl
+                )
+                # Optax schedules are jnp-based: evaluating one IS a
+                # (small) device dispatch, so it rides inside the hold
+                # too — placed before the t0/c0 stamps so it never counts
+                # as epoch execute time.  Every registered schedule is
+                # linear in learning_rate, so lr x the peak-1.0 shape IS
+                # the effective rate on both the injected and baked paths.
+                lr_now = lr * float(
+                    shape_schedule(min(opt_steps, total_steps))
+                )
+                c0 = tracker.thread_seconds()
+                t0 = _time.time()
+                params, opt_state, batch_stats, train_loss = train_epoch(
+                    params, opt_state, batch_stats, data.x_train,
+                    data.y_train, epoch_key
+                )
+                metrics = evaluate(
+                    params, batch_stats, data.x_val, data.y_val,
+                    data.val_mask
+                )
             # Sync INSIDE the locked section via scalar readbacks: jit
             # returns futures, so without this the lock would release
             # while the epoch still runs — the overlap the lock exists
             # to prevent.
-            train_loss = float(train_loss)
-            metrics = {k: float(v) for k, v in metrics.items()}
+            with obs.span("epoch.readback"):
+                train_loss = float(train_loss)
+                metrics = {k: float(v) for k, v in metrics.items()}
         record = {
             "epoch": epoch,
             "train_loss": train_loss,

@@ -876,6 +876,11 @@ def run_vectorized(
     if resume and not name:
         raise ValueError("resume=True requires name= of the prior run")
     name = name or f"vexp_{time.strftime('%Y%m%d_%H%M%S')}_{uuid.uuid4().hex[:6]}"
+    # Hand-ended: vec.run after the teardown; vec.setup by the population
+    # it sets up, at its first dispatch (a later chunk opens its own, so
+    # the suggest/program/init of every chunk lie in one).
+    run_span = _obs.span("vec.run", {"name": name})
+    setup_span = _obs.span("vec.setup")
     store = ExperimentStore(storage_path, name)
     store.set_context(metric, mode)
     start_time = time.time()
@@ -1155,6 +1160,8 @@ def run_vectorized(
                 or resume_state
                 or unstarted
             ):
+                if setup_span is None:
+                    setup_span = _obs.span("vec.setup")
                 if resume_state is not None:
                     chunk = list(resume_state["batch"])
                 elif unstarted:
@@ -1164,19 +1171,23 @@ def run_vectorized(
                     chunk, unstarted = list(unstarted), []
                 else:
                     chunk = []
-                    while len(chunk) < max_batch_trials and next_index < num_samples:
-                        config = searcher.suggest(next_index)
-                        if config is None:
-                            exhausted = True
-                            break
-                        trial = Trial(
-                            trial_id=f"trial_{next_index:05d}", config=config
-                        )
-                        next_index += 1
-                        trials.append(trial)
-                        chunk.append(trial)
-                        sched.on_trial_add(trial)
-                        store.write_params(trial)
+                    with _obs.span("vec.suggest") as suggest_span:
+                        while (len(chunk) < max_batch_trials
+                               and next_index < num_samples):
+                            config = searcher.suggest(next_index)
+                            if config is None:
+                                exhausted = True
+                                break
+                            trial = Trial(
+                                trial_id=f"trial_{next_index:05d}",
+                                config=config,
+                            )
+                            next_index += 1
+                            trials.append(trial)
+                            chunk.append(trial)
+                            sched.on_trial_add(trial)
+                            store.write_params(trial)
+                        suggest_span.set("trials", len(chunk))
                 if not chunk:
                     break
 
@@ -1197,11 +1208,12 @@ def run_vectorized(
                 for sig, members in groups.items():
                     program = programs.get(sig)
                     if program is None:
-                        program = programs[sig] = _group_program_for(
-                            sig, dict(members[0].config), train_data,
-                            val_data, pop_sharding, device, log,
-                            force_restage=force_restage,
-                        )
+                        with _obs.span("vec.program"):
+                            program = programs[sig] = _group_program_for(
+                                sig, dict(members[0].config), train_data,
+                                val_data, pop_sharding, device, log,
+                                force_restage=force_restage,
+                            )
                     compile_before = tracker.thread_seconds()
                     t_pop = time.time()
                     pop_rows, pop_exec_s = _run_population(
@@ -1214,7 +1226,7 @@ def run_vectorized(
                             pop_manager if group_ckpt_path else None
                         ),
                         pbt_compiled=pbt_compiled, pbt_spec=pbt_spec,
-                        pbt_counters=pbt_counters,
+                        pbt_counters=pbt_counters, setup_span=setup_span,
                     )
                     resume_state = None  # consumed by the first (only) group
                     row_epochs += pop_rows
@@ -1227,8 +1239,13 @@ def run_vectorized(
                             f"{compile_s:.1f}s compile "
                             f"({tracker.thread_cache_hits()} cache hits so far)"
                         )
+                setup_span = None  # ended by the chunk's first population
     finally:
-        wall, utilization = _teardown()
+        if setup_span is not None:
+            setup_span.end()
+        with _obs.span("vec.teardown"):
+            wall, utilization = _teardown()
+        run_span.end()
 
     analysis = ExperimentAnalysis(
         trials, metric=metric, mode=mode, root=store.root, wall_clock_s=wall,
@@ -1440,10 +1457,18 @@ def _replay_records(trial_list, sched, searcher, pbt, metric, mode,
 def _emit_epoch_records(
     batch, rows, active, lrs, epoch, step_count, shape_val, now,
     train_losses, metrics_np, pbt_notes, pbt, sched, searcher, store,
-    metric, mode, safe_cb=lambda *a: None, stop_rules=None,
+    metric, mode, safe_cb=lambda *a: None, stop_rules=None, cost=None,
 ):
     """Append one epoch's records for every live trial and route them through
-    the scheduler/searcher (the vectorized analogue of ``session.report``)."""
+    the scheduler/searcher (the vectorized analogue of ``session.report``).
+
+    ``cost`` (:func:`_new_emit_cost`, summed into): ``results`` and
+    ``stopped`` counts and the seconds spent in the store, the callbacks,
+    the scheduler and the searcher — what the caller's ``vec.emit`` span
+    carries as attrs, in place of a span a result."""
+    if cost is None:
+        cost = _new_emit_cost()
+    clock = time.perf_counter
     for i, r in enumerate(rows):
         if r < 0:  # dummy pad row
             continue
@@ -1469,8 +1494,12 @@ def _emit_epoch_records(
         # Keep Trial.training_iteration live (== epochs completed), the
         # same contract the threaded executor maintains via report().
         trial.reports_since_restart += 1
+        cost["results"] += 1
+        t0 = clock()
         store.append_result(trial, record)
+        t1 = clock()
         safe_cb("on_trial_result", trial, record)
+        t2 = clock()
         # PBT never stops trials and its REQUEUE protocol is replaced by
         # the in-population gather at the dispatch boundary, so the
         # scheduler's DECISION surface is bypassed — but model-based
@@ -1480,9 +1509,15 @@ def _emit_epoch_records(
             decision = CONTINUE
         else:
             decision = sched.on_trial_result(trial, record)
+        t3 = clock()
         searcher.on_trial_result(
             trial.trial_id, dict(trial.config), record, metric, mode
         )
+        t4 = clock()
+        cost["store_s"] += t1 - t0
+        cost["callbacks_s"] += t2 - t1
+        cost["scheduler_s"] += t3 - t2
+        cost["searcher_s"] += t4 - t3
         if decision == REQUEUE:
             raise ValueError(
                 "requeue schedulers are not supported in vectorized mode; "
@@ -1494,14 +1529,32 @@ def _emit_epoch_records(
             if stop_hit(stop_rules, trial.trial_id, record):
                 decision = STOP
         if decision == STOP:
+            cost["stopped"] += 1
             active[r] = False
             trial.status = TrialStatus.TERMINATED
             trial.finished_at = time.time()
+            t0 = clock()
             sched.on_trial_complete(trial)
+            t1 = clock()
             searcher.on_trial_complete(
                 trial.trial_id, trial.config, trial.last_result, metric, mode
             )
+            t2 = clock()
             safe_cb("on_trial_complete", trial)
+            cost["scheduler_s"] += t1 - t0
+            cost["searcher_s"] += t2 - t1
+            cost["callbacks_s"] += clock() - t2
+
+
+def _new_emit_cost() -> Dict[str, float]:
+    return {"results": 0, "stopped": 0, "store_s": 0.0, "callbacks_s": 0.0,
+            "scheduler_s": 0.0, "searcher_s": 0.0}
+
+
+def _close_emit_span(span, cost: Dict[str, float]) -> None:
+    """A ``vec.emit`` span's attrs: what its results cost, by layer."""
+    for key, value in cost.items():
+        span.set(key, round(value, 6) if key.endswith("_s") else value)
 
 
 def _pbt_objective_scale(pbt, program, base_keys, row_lr, row_wd) -> float:
@@ -1632,14 +1685,22 @@ def _run_population(
     pbt_compiled: bool = False,
     pbt_spec=None,
     pbt_counters=None,
+    setup_span=None,
 ) -> Tuple[int, float]:
     """Train one population of K same-shape trials to completion.
 
     Returns ``(row_epochs, exec_seconds)``: trial-epochs actually computed
     (rows x epochs — the honest FLOP-cost denominator under compaction) and
-    device-execute wall seconds (the utilization numerator)."""
+    device-execute wall seconds (the utilization numerator).
+
+    ``setup_span``: the caller's open ``vec.setup`` span, ended here at the
+    population's first dispatch."""
     k = len(batch)
     from distributed_machine_learning_tpu.tune import checkpoint as ckpt_lib
+
+    # Population init (or restore), placement, dispatch sizing: ended with
+    # ``setup_span`` where the dispatch loop starts.
+    init_span = _obs.span("vec.init", {"trials": k})
 
     now = time.time()
     epoch_start = 0
@@ -1765,6 +1826,10 @@ def _run_population(
     ckpt_seq = [ckpt_manager.latest()[1] if ckpt_manager is not None else 0]
 
     def save_population(at_epoch: int):
+        with _obs.span("vec.checkpoint", {"epoch": at_epoch}):
+            _save_population(at_epoch)
+
+    def _save_population(at_epoch: int):
         tree = {
             "state": {
                 "params": params,
@@ -1985,6 +2050,9 @@ def _run_population(
     # grants it the first-beat grace.  Compaction changes the compiled size,
     # so the dispatch after it is cold again.
     cold_dispatch = True
+    init_span.end()
+    if setup_span is not None:
+        setup_span.end()
     while epoch0 < epoch_budget:
         iv = max(int(pbt.interval), 1) if pbt is not None else 1
         if (
@@ -2074,6 +2142,8 @@ def _run_population(
 
             t_end = time.time()
             total_e = g * iv
+            emit_span = _obs.span("vec.emit")
+            emit_cost = _new_emit_cost()
             for gi in range(g):
                 gen = gen0 + gi
                 for e_off in range(iv):
@@ -2094,7 +2164,7 @@ def _run_population(
                         batch, rows, active, lrs, epoch, step_count,
                         shape_val, now, train_losses, metrics_np,
                         pbt_notes, pbt, sched, searcher, store, metric,
-                        mode, safe_cb, stop_rules,
+                        mode, safe_cb, stop_rules, emit_cost,
                     )
                 # Mirror this generation's in-device decisions into the
                 # host bookkeeping; notes annotate the NEXT generation's
@@ -2125,6 +2195,8 @@ def _run_population(
                     )
                 pbt_row_lr = newlr_all[gi].copy()
                 pbt_row_wd = newwd_all[gi].copy()
+            _close_emit_span(emit_span, emit_cost)
+            emit_span.end()
             safe_cb("on_heartbeat")
             epoch0 += g * iv
             if (
@@ -2165,37 +2237,41 @@ def _run_population(
             {"epoch0": epoch0, "epochs": chunk, "rows": len(rows)},
         ):
             if chunk == 1:
-                epoch_keys = jax.vmap(
-                    lambda key: jax.random.fold_in(key, epoch0)
-                )(base_keys)
-                params, opt_state, batch_stats, tl = program.train_epoch(
-                    params, opt_state, batch_stats,
-                    data.x_train, data.y_train,
-                    epoch_keys,
-                )
-                metrics_k = program.eval_population(
-                    params, batch_stats, data.x_val, data.y_val,
-                    data.val_mask
-                )
-                tl_chunk = np.asarray(tl)[:, None]  # (K, 1)
-                metrics_chunk = {
-                    key: np.asarray(v)[:, None]
-                    for key, v in metrics_k.items()
-                }
-            else:
-                params, opt_state, batch_stats, tls, ms = (
-                    program.train_epochs(
-                        params, opt_state, batch_stats, base_keys,
+                with _obs.span("vec.launch"):
+                    epoch_keys = jax.vmap(
+                        lambda key: jax.random.fold_in(key, epoch0)
+                    )(base_keys)
+                    params, opt_state, batch_stats, tl = program.train_epoch(
+                        params, opt_state, batch_stats,
                         data.x_train, data.y_train,
-                        data.x_val, data.y_val, data.val_mask,
-                        jnp.arange(epoch0, epoch0 + chunk),
+                        epoch_keys,
                     )
-                )
-                # vmap(scan) stacks as (K, E)
-                tl_chunk = np.asarray(tls)
-                metrics_chunk = {
-                    key: np.asarray(v) for key, v in ms.items()
-                }
+                    metrics_k = program.eval_population(
+                        params, batch_stats, data.x_val, data.y_val,
+                        data.val_mask
+                    )
+                with _obs.span("vec.sync"):
+                    tl_chunk = np.asarray(tl)[:, None]  # (K, 1)
+                    metrics_chunk = {
+                        key: np.asarray(v)[:, None]
+                        for key, v in metrics_k.items()
+                    }
+            else:
+                with _obs.span("vec.launch"):
+                    params, opt_state, batch_stats, tls, ms = (
+                        program.train_epochs(
+                            params, opt_state, batch_stats, base_keys,
+                            data.x_train, data.y_train,
+                            data.x_val, data.y_val, data.val_mask,
+                            jnp.arange(epoch0, epoch0 + chunk),
+                        )
+                    )
+                with _obs.span("vec.sync"):
+                    # vmap(scan) stacks as (K, E)
+                    tl_chunk = np.asarray(tls)
+                    metrics_chunk = {
+                        key: np.asarray(v) for key, v in ms.items()
+                    }
         # Materialize BEFORE reading the clocks: eval execution is part of
         # the per-epoch cost the compaction model weighs (np.asarray above
         # synced everything).
@@ -2232,27 +2308,34 @@ def _run_population(
             pbt_counters["host_dispatches"] += 1
 
         t_end = time.time()
-        for e_off in range(chunk):
-            epoch = epoch0 + e_off
-            train_losses = tl_chunk[:, e_off]
-            metrics_np = {key: v[:, e_off] for key, v in metrics_chunk.items()}
-            metrics_np = _inject_objective(
-                pbt, obj_scale, train_losses, metrics_np
-            )
-            step_count = (epoch + 1) * program.steps_per_epoch
-            # Trial-independent: evaluate once per epoch, not per trial.
-            shape_val = float(
-                program.shape_schedule(min(step_count, program.total_steps))
-            )
-            # Per-epoch completion time is interpolated across the chunk so
-            # timestamp/time_total_s stay monotone and ~epoch-granular (the
-            # device finished epoch e_off at roughly this point).
-            now = t0 + (e_off + 1) * (t_end - t0) / chunk
-            _emit_epoch_records(
-                batch, rows, active, lrs, epoch, step_count, shape_val, now,
-                train_losses, metrics_np, pbt_notes, pbt, sched, searcher,
-                store, metric, mode, safe_cb, stop_rules,
-            )
+        with _obs.span("vec.emit") as emit_span:
+            emit_cost = _new_emit_cost()
+            for e_off in range(chunk):
+                epoch = epoch0 + e_off
+                train_losses = tl_chunk[:, e_off]
+                metrics_np = {
+                    key: v[:, e_off] for key, v in metrics_chunk.items()
+                }
+                metrics_np = _inject_objective(
+                    pbt, obj_scale, train_losses, metrics_np
+                )
+                step_count = (epoch + 1) * program.steps_per_epoch
+                # Trial-independent: evaluate once per epoch, not per trial.
+                shape_val = float(program.shape_schedule(
+                    min(step_count, program.total_steps)
+                ))
+                # Per-epoch completion time is interpolated across the
+                # chunk so timestamp/time_total_s stay monotone and
+                # ~epoch-granular (the device finished epoch e_off at
+                # roughly this point).
+                now = t0 + (e_off + 1) * (t_end - t0) / chunk
+                _emit_epoch_records(
+                    batch, rows, active, lrs, epoch, step_count, shape_val,
+                    now, train_losses, metrics_np, pbt_notes, pbt, sched,
+                    searcher, store, metric, mode, safe_cb, stop_rules,
+                    emit_cost,
+                )
+            _close_emit_span(emit_span, emit_cost)
         epoch0 += chunk
         epoch = epoch0 - 1  # last completed epoch (PBT/compaction below)
         train_losses = tl_chunk[:, -1]
@@ -2466,16 +2549,21 @@ def _run_population(
                 # the persistent cache.
                 pad = [i for i in range(len(rows)) if i not in set(pos)]
                 keep = sorted(pos + pad[: target - len(pos)])
-                sel = jnp.asarray(keep)
-                params, opt_state, batch_stats = jax.tree.map(
-                    lambda a: a[sel], (params, opt_state, batch_stats)
-                )
-                base_keys = base_keys[sel]
-                if pop_sharding is not None:
-                    params, opt_state, batch_stats, base_keys = jax.device_put(
-                        (params, opt_state, batch_stats, base_keys),
-                        pop_sharding,
+                with _obs.span("vec.compact", {
+                    "rows_before": len(rows), "rows_after": len(keep),
+                }):
+                    sel = jnp.asarray(keep)
+                    params, opt_state, batch_stats = jax.tree.map(
+                        lambda a: a[sel], (params, opt_state, batch_stats)
                     )
+                    base_keys = base_keys[sel]
+                    if pop_sharding is not None:
+                        params, opt_state, batch_stats, base_keys = (
+                            jax.device_put(
+                                (params, opt_state, batch_stats, base_keys),
+                                pop_sharding,
+                            )
+                        )
                 rows = [rows[i] for i in keep]
                 cold_dispatch = True  # halved size = fresh compile next
                 log(

@@ -19,9 +19,11 @@ flags live in ONE place:
 
 The gate also runs the observability-plane overhead guard
 (``DML_OBS_PERF_GUARD=1`` in its own environment): the tracing-DISABLED
-``obs.span()`` path must stay at a few hundred ns per call with zero net
-allocation, or always-on instrumentation in epoch/request hot paths stops
-being free — a regression there gates the diff like a lint finding.
+``obs.span()`` path — no tracer and, jax being imported with the package,
+the profiler bridge with no session running — must stay at a few hundred
+ns per call with zero net allocation, or always-on instrumentation in
+epoch/request hot paths stops being free — a regression there gates the
+diff like a lint finding.
 
 Exit code is the lint's: 0 clean, 1 unsuppressed findings, 2 usage/git
 trouble — the same contract as ``dml-tpu lint`` itself.

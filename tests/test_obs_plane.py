@@ -226,6 +226,15 @@ def test_merge_and_chrome_schema(tmp_path):
 
 def test_disabled_span_perf_guard():
     assert not obs.tracing_enabled()
+    # The bridged path: jax is imported (conftest), so every span asks the
+    # profiler whether a session runs before it hands back the singleton.
+    from distributed_machine_learning_tpu.obs import trace as obs_trace
+
+    assert "jax.profiler" in sys.modules
+    assert obs_trace._find_annotation() is sys.modules[
+        "jax.profiler"
+    ].TraceAnnotation
+    assert not obs_trace._find_annotation().is_enabled()
     # Best of three: CI machines stutter; a regression shifts ALL runs.
     best = min(
         (obs.disabled_path_overhead(iters=50_000) for _ in range(3)),
@@ -237,6 +246,235 @@ def test_disabled_span_perf_guard():
     # "allocates nothing per span": net allocated blocks must not scale
     # with the span count (tiny constant jitter from interned state ok).
     assert best["net_blocks"] <= 16, best
+
+
+# ---------------------------------------------------------------------------
+# the bridge to the profiler's host plane (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A profiler session beside jax's persistent compilation cache: off
+    around these tests, as the on-chip-measurement guide says."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _plane_lines(trace_dir):
+    """The ``dml:`` events of a capture, one list per host line (thread):
+    ``(name, start_ns, end_ns, stats)`` in order of start."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(
+                ((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                  dict(e.stats))
+                 for e in line.events if e.name.startswith("dml:")),
+                key=lambda ev: (ev[1], -ev[2]),
+            )
+            if evs:
+                lines.append(evs)
+    return lines
+
+
+def _named(lines, name):
+    return [e for line in lines for e in line if e[0] == name]
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _spans_on_two_threads():
+    with obs.span("plane.outer", {"trial_id": "t,1#", "n": 3,
+                                  "skipped": [1, 2]}) as outer:
+        with obs.span("plane.inner") as inner:
+            inner.set("late", 7)
+        worker = threading.Thread(
+            target=lambda: obs.span("plane.worker", {"w": 1.5}).end()
+        )
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+    return outer, inner
+
+
+@pytest.mark.parametrize("session", [False, True], ids=["nosession", "session"])
+@pytest.mark.parametrize("tracer", [False, True], ids=["notracer", "tracer"])
+def test_span_lands_in_the_profilers_host_plane(tmp_path, no_compile_cache,
+                                                 tracer, session):
+    import jax
+
+    if tracer:
+        obs.configure(trace_dir=str(tmp_path / "spans"), label="t")
+    if not session:
+        # Nothing listens on the plane: the singleton (or a tracer-only
+        # span), and a capture started afterwards holds none of it.
+        if not tracer:
+            assert obs.span("a") is obs.span("b")
+        outer, inner = _spans_on_two_threads()
+        assert getattr(outer, "_ann", None) is None
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        if session:
+            outer, inner = _spans_on_two_threads()
+    finally:
+        jax.profiler.stop_trace()
+    lines = _plane_lines(str(tmp_path / "prof"))
+    if tracer:
+        names = {r["name"] for r in obs.get_tracer().records()}
+        assert {"plane.outer", "plane.inner", "plane.worker"} <= names
+    if not session:
+        assert lines == []
+        return
+    assert len(lines) == 2  # one line a thread
+    main = next(li for li in lines if _named([li], "dml:plane.outer"))
+    (other,) = [li for li in lines if li is not main]
+    assert [e[0] for e in main] == ["dml:plane.outer", "dml:plane.inner"]
+    assert [e[0] for e in other] == ["dml:plane.worker"]
+    o, i = main
+    assert _inside(i, o)
+    # scalar attrs ride as stats (what would end a value early replaced),
+    # a list does not; an attr set while the span is open arrives too
+    assert o[3]["trial_id"] == "t_1_" and o[3]["n"] == 3
+    assert "skipped" not in o[3]
+    assert i[3]["late"] == 7
+    assert other[0][3]["w"] == 1.5
+    if tracer:
+        assert o[3]["span_id"] == outer.span_id
+        assert i[3]["parent_id"] == outer.span_id
+        assert outer.context == (outer.trace_id, outer.span_id)
+    else:
+        assert "span_id" not in o[3] and outer.context is None
+
+
+def test_train_run_spans_on_the_profilers_clock(tmp_results,
+                                                no_compile_cache):
+    """tune.run of train_regressor, 2 epochs, a checkpoint each, under a
+    profiler session: the report path's spans, where and in what order."""
+    import jax
+
+    from distributed_machine_learning_tpu.data import dummy_regression_data
+
+    train, val = dummy_regression_data(
+        num_samples=64, seq_len=8, num_features=4
+    )
+    prof = os.path.join(tmp_results, "plane_train_prof")
+    jax.profiler.start_trace(prof)
+    try:
+        tune.run(
+            tune.with_parameters(
+                tune.train_regressor, train_data=train, val_data=val
+            ),
+            {"model": "simple_transformer", "d_model": 16, "num_heads": 2,
+             "num_layers": 1, "learning_rate": 1e-3, "num_epochs": 2,
+             "batch_size": 16, "max_seq_length": 16},
+            metric="validation_loss", num_samples=1,
+            storage_path=tmp_results, name="plane_train", verbose=0,
+        )
+    finally:
+        jax.profiler.stop_trace()
+    lines = _plane_lines(prof)
+    epochs = _named(lines, "dml:epoch")
+    reports = _named(lines, "dml:report")
+    decides = _named(lines, "dml:runner.process_result")
+    saves = _named(lines, "dml:ckpt.save")
+    assert [len(x) for x in (epochs, reports, decides, saves)] == [2, 2, 2, 2]
+    (trial,) = _named(lines, "dml:trial")
+    (trial_line,) = [li for li in lines if _named([li], "dml:epoch")]
+    assert _named([trial_line], "dml:report") == reports
+    assert not _named([trial_line], "dml:ckpt.save")  # the writer's thread
+    assert not _named([trial_line], "dml:runner.process_result")
+    for ep, rep, dec in zip(epochs, reports, decides):
+        assert ep[2] <= rep[1]  # the report after its epoch
+        assert _inside(ep, trial) and _inside(rep, trial)
+        assert _inside(dec, rep)  # the runner decides while the trial waits
+        assert rep[3]["iteration"] == dec[3]["iteration"]
+    assert [r[3]["iteration"] for r in reports] == [1, 2]
+    for save in saves:
+        assert save[3]["format"] == "msgpack" and save[3]["bytes"] > 0
+    for name in ("dml:run.setup", "dml:run.teardown", "dml:trial.setup",
+                 "dml:trial.build", "dml:trial.init_or_restore"):
+        assert len(_named(lines, name)) == 1, name
+    for name in ("dml:epoch.dispatch", "dml:epoch.readback",
+                 "dml:report.ckpt_snapshot", "dml:report.decide_wait",
+                 "dml:ckpt.device_get", "dml:ckpt.serialize",
+                 "dml:ckpt.write", "dml:runner.store_append",
+                 "dml:runner.scheduler", "dml:runner.searcher",
+                 "dml:runner.callbacks"):
+        assert len(_named(lines, name)) == 2, name
+
+
+def test_vectorized_run_spans_on_the_profilers_clock(tmp_results,
+                                                     no_compile_cache):
+    """run_vectorized under ASHA: one vec.emit a vec.dispatch, and their
+    ``results`` add up to the records the trials hold."""
+    import jax
+
+    from distributed_machine_learning_tpu.data import dummy_regression_data
+
+    train, val = dummy_regression_data(
+        num_samples=64, seq_len=8, num_features=4
+    )
+    prof = os.path.join(tmp_results, "plane_vec_prof")
+    jax.profiler.start_trace(prof)
+    try:
+        analysis = tune.run_vectorized(
+            {"model": "simple_transformer", "d_model": 16, "num_heads": 2,
+             "num_layers": 1, "learning_rate": tune.loguniform(1e-4, 1e-2),
+             "num_epochs": 4, "batch_size": 16, "max_seq_length": 16},
+            train_data=train, val_data=val, metric="validation_loss",
+            num_samples=8, max_batch_trials=8, storage_path=tmp_results,
+            name="plane_vec", verbose=0, epochs_per_dispatch=1,
+            scheduler=tune.ASHAScheduler(
+                max_t=4, grace_period=1, reduction_factor=2
+            ),
+        )
+    finally:
+        jax.profiler.stop_trace()
+    lines = _plane_lines(prof)
+    assert len(lines) == 1  # the caller's thread does it all
+    dispatches = _named(lines, "dml:vec.dispatch")
+    emits = _named(lines, "dml:vec.emit")
+    assert len(dispatches) == len(emits) >= 2
+    for disp, emit in zip(dispatches, emits):
+        assert disp[2] <= emit[1]
+    records = sum(len(t.results) for t in analysis.trials)
+    assert sum(e[3]["results"] for e in emits) == records
+    assert sum(e[3]["stopped"] for e in emits) == 8  # ASHA ended them all
+    for key in ("store_s", "callbacks_s", "scheduler_s", "searcher_s"):
+        assert all(e[3][key] >= 0 for e in emits)
+    (run_span,) = _named(lines, "dml:vec.run")
+    assert run_span[3]["name"] == "plane_vec"
+    (setup,) = _named(lines, "dml:vec.setup")
+    (suggest,) = _named(lines, "dml:vec.suggest")
+    assert suggest[3]["trials"] == 8 and _inside(suggest, setup)
+    assert _inside(_named(lines, "dml:vec.program")[0], setup)
+    assert _inside(_named(lines, "dml:vec.init")[0], setup)
+    assert setup[2] <= dispatches[0][1]
+    for disp in dispatches:
+        (launch,) = [e for e in _named(lines, "dml:vec.launch")
+                     if _inside(e, disp)]
+        (sync,) = [e for e in _named(lines, "dml:vec.sync")
+                   if _inside(e, disp)]
+        assert launch[2] <= sync[1]
+    (teardown,) = _named(lines, "dml:vec.teardown")
+    assert emits[-1][2] <= teardown[1] and _inside(teardown, run_span)
 
 
 # ---------------------------------------------------------------------------
