@@ -118,14 +118,22 @@ def _partitioned(mesh: Optional[Mesh]) -> bool:
 
 def _route_softmax_to_flash(seq_len: int, head_dim: int) -> bool:
     """Whether a plain softmax attention call should run the Pallas flash
-    kernel instead: same exact math (online softmax), recorded faster on
-    chip from ~1k sequence length at head_dim <= 64 (fwd ~20%, fwd+bwd
-    2.0x at seq 4096, full train step 1.48x at seq 2048; at D=128 the
-    flash FORWARD 2x slower than XLA, only the grad path ahead — all
-    figures from an earlier round, not measured on today's code). Gated
-    to that regime and to lengths the kernel can tile; this route also
+    kernel instead: same exact math (online softmax). Gated to S >= 1024 at
+    head_dim <= 64 and to lengths the kernel can tile; this route also
     serves eval — configs wanting flash at bigger head dims select
-    attention_type='flash' explicitly."""
+    attention_type='flash' explicitly.
+
+    Read on a v5e chip on today's code (PERF.md, PR 30: all device time of
+    a call, layout copies included, bf16, 65,536 tokens), XLA's attention |
+    the kernels, ms. head_dim 64 forward: S 512 2.58 | 2.78, S 1024 5.06 |
+    3.35, S 2048 9.36 | 5.01, S 4096 12.64 | 9.26; forward + backward: 8.50
+    | 7.64, 17.34 | 10.74, 31.96 | 17.10, 60.97 | 31.62. head_dim 128, 4
+    heads, forward: 1.71 | 1.48, 2.99 | 1.84, 5.09 | 3.09, 9.75 | 5.48;
+    forward + backward: 5.75 | 3.87, 9.72 | 5.27, 17.74 | 9.22, 33.43 |
+    16.75. So inside the gate the kernels are 1.4 to 1.9 times faster, and
+    the gate is narrower than it need be (head_dim 128, and S 512 where the
+    backward runs): widening it moves which program a model trains with,
+    and is its own change."""
     from distributed_machine_learning_tpu.ops.pallas_attention import (
         flash_can_tile,
     )
@@ -452,7 +460,8 @@ class MultiHeadAttention(nn.Module):
                 # Pallas kernel (long sequences on TPU only). Blocks stay
                 # None — the kernel's measured-fastest tiles; block_size
                 # here is the blockwise-scan knob, and a small value would
-                # turn the fast path into the measured-slow 128-tile one.
+                # turn the fast path into a slow one (a 256-key block: the
+                # forward call 18.4 ms against 6.2, PERF.md PR 30).
                 from distributed_machine_learning_tpu.ops.pallas_attention import (
                     flash_attention,
                 )

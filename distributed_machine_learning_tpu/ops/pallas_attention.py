@@ -9,19 +9,37 @@ written Pallas kernel tiled for the MXU:
   so each (q-block, head) streams key/value blocks HBM -> VMEM while running
   (max, denom, accumulator) statistics live in VMEM scratch — the flash
   online-softmax recurrence; peak VMEM is O(block_q * (head_dim + block_k))
-  instead of O(seq^2).
-* both matmuls (`q k^T` and `p v`) hit the MXU via ``jnp.dot`` with
-  ``preferred_element_type=float32``; the softmax chain stays on the VPU in
-  float32 regardless of input dtype (bfloat16 inputs supported).
+  instead of O(seq^2). Where one kv block spans the sequence (S <= 2048 at
+  the default blocks) the recurrence has one step, and the kernel is a plain
+  softmax over the block with no running state.
+* every matmul is a ``dot_general`` with ``preferred_element_type=float32``,
+  and the softmax chain, the row statistics, ``lse``, ``delta`` and every
+  accumulator are float32 whatever the input. bfloat16 inputs without a
+  mask reach the MXU as they are, with ``p`` and ``ds`` narrowed to bfloat16
+  at the products that consume them; any other input has float32 operands,
+  which Mosaic feeds to the MXU in one bf16 pass of its own, so the two
+  give the same bits (``_operand_dtype`` has the readings).
+* the work per score is what the body can see it needs. Without ``causal``
+  nothing can make a score infinite, so there is no mask and no guard (a
+  non-finite input then reaches the output as NaN). In the forward a
+  ``scale`` that is a power of two (head size 64: 0.125) is multiplied into
+  the ``[block_q, D]`` q block, exactly, and not into the ``[block_q,
+  block_k]`` scores; the backward kernels keep it on their tiles, where
+  moving it measured nothing.
 * causal masking skips fully-masked kv blocks entirely (``@pl.when``), so the
-  causal forward does ~half the work.
+  causal forward does ~half the work, and keeps its guards: a row with
+  nothing to attend gives ``lse = -inf`` and zeros, which the ring's merge
+  of chunk results relies on.
 
 Gradients: ``jax.custom_vjp`` with hand-written Pallas backward kernels —
 the forward additionally emits per-row logsumexp; the backward recomputes
 ``P = exp(logits - lse)`` per block (flash-style) in two passes, a dK/dV
 kernel (kv block resident, q blocks streaming) and a dQ kernel (q block
 resident, kv blocks streaming), with the standard ``delta = rowsum(dO*O)``
-correction. Exact gradients, O(block) memory, every matmul on the MXU.
+correction. The dK/dV kernel works on transposed scores (``k q^T``), so that
+``P^T dO`` and ``dS^T Q`` are plain products and ``lse``/``delta`` broadcast
+along lanes as they are stored. Exact gradients, O(block) memory, every
+matmul on the MXU.
 
 Selected via ``MultiHeadAttention(attention_type="flash")`` (models/layers.py),
 which routes to this kernel on TPU backends and to the differentiable
@@ -33,6 +51,7 @@ tests exercise exactly that.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -43,25 +62,115 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float("-inf")
 
 
+def _operand_dtype(dtype, causal: bool):
+    """What the matmuls take (accumulation is float32 either way).
+
+    bfloat16 inputs without a mask go in as they are, and ``p`` and ``ds``
+    are narrowed beside them. Float32 operands reach the MXU in one bf16
+    pass of Mosaic's own, so the bits are the same and so is the time
+    (v5e, [32, 2048, 8, 64]: forward 3.777 against 3.797 ms, dK/dV 6.054
+    against 6.053); what the narrow operands buy is VMEM, 11.9 MiB in place
+    of 15.5 of the 16 at the backward's 1024 tiles. Under a mask the
+    explicit narrowing measured slower (dK/dV 6.59 against 5.99 ms, dQ 4.99
+    against 4.71), so there, as for any other input, operands are float32."""
+    narrow = dtype == jnp.bfloat16 and not causal
+    return jnp.bfloat16 if narrow else jnp.float32
+
+
+def _folds_scale(scale: float) -> bool:
+    """A power of two multiplies into q exactly, so it can leave the
+    [block_q, block_k] tile without changing a bit; any other scale stays
+    on the float32 scores."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _dot(a, b, contract_a: int, contract_b: int):
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((contract_a,), (contract_b,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _causal_keep(shape, q_start, k_start, q_axis: int):
+    """Where a [.., ..] tile of scores may attend: q position >= k position,
+    with the q positions along ``q_axis``."""
+    q_pos = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) + q_start
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) + k_start
+    return q_pos >= k_pos
+
+
 def _flash_kernel(
     q_ref,
     k_ref,
     v_ref,
     o_ref,
     lse_ref,
-    m_ref,
-    l_ref,
-    acc_ref,
-    *,
+    *state_refs,
     scale: float,
     block_q: int,
     block_k: int,
     causal: bool,
 ):
-    """One (bh, q_block, kv_block) grid step of the online-softmax recurrence."""
+    """One (bh, q_block, kv_block) grid step of the online-softmax recurrence.
+
+    ``state_refs`` is the (max, denom, accumulator) scratch, or nothing when
+    the kv axis is one block: the step then starts from no state and writes
+    its result straight out."""
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
     num_kv = pl.num_programs(2)
+    q_start = q_idx * block_q
+    k_start = kv_idx * block_k
+    op = _operand_dtype(q_ref.dtype, causal)
+    folded = _folds_scale(scale)
+
+    def _step(state):
+        """(m, l, acc) after this kv block, from ``state`` or from nothing."""
+        q = q_ref[0]                              # [block_q, d]
+        if folded:
+            q = q.astype(jnp.float32) * scale
+        k = k_ref[0].astype(op)                   # [block_k, d]
+        v = v_ref[0].astype(op)                   # [block_k, d]
+        logits = _dot(q.astype(op), k, 1, 1)      # [block_q, block_k]
+        if not folded:
+            logits = logits * scale
+        if causal:
+            keep = _causal_keep(logits.shape, q_start, k_start, 0)
+            logits = jnp.where(keep, logits, NEG_INF)
+        m_new = jnp.max(logits, axis=-1, keepdims=True)  # [block_q, 1]
+        if state is not None:
+            m_prev, l_prev, acc_prev = state
+            m_new = jnp.maximum(m_prev, m_new)
+        # Causal only: fully-masked rows keep m=-inf; exp against a safe max
+        # stays 0. Without a mask every score is finite and so is m.
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0) if causal else m_new
+        p = jnp.exp(logits - m_safe)
+        if causal:
+            p = jnp.where(jnp.isfinite(logits), p, 0.0)
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+        acc = _dot(p.astype(op), v, 1, 0)
+        if state is not None:
+            corr = jnp.exp(m_prev - m_safe)
+            if causal:
+                corr = jnp.where(jnp.isfinite(m_prev), corr, 0.0)
+            l_new = l_prev * corr + l_new
+            acc = acc_prev * corr + acc
+        return m_new, l_new, acc
+
+    def _write(m, l, acc):
+        denom = jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc / denom).astype(o_ref.dtype)
+        # Logsumexp per row, for the backward kernels' softmax recompute
+        # (P = exp(logits - lse)). Causal: fully-masked rows keep -inf.
+        lse = m + jnp.log(denom)
+        if causal:
+            lse = jnp.where(jnp.isfinite(m), lse, NEG_INF)
+        lse_ref[0, 0] = lse[:, 0]
+
+    if not state_refs:
+        _write(*_step(None))
+        return
+    m_ref, l_ref, acc_ref = state_refs
 
     @pl.when(kv_idx == 0)
     def _init():
@@ -69,52 +178,16 @@ def _flash_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Causal: a kv block strictly above the diagonal of this q block is all
-    # masked; skip its matmuls entirely.
-    q_start = q_idx * block_q
-    k_start = kv_idx * block_k
-
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [block_q, d]
-        k = k_ref[0].astype(jnp.float32)  # [block_k, d]
-        v = v_ref[0].astype(jnp.float32)  # [block_k, d]
-
-        logits = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [block_q, block_k]
-
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0) + q_start
-            cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + k_start
-            logits = jnp.where(rows >= cols, logits, NEG_INF)
-
-        m_prev = m_ref[:, :1]  # [block_q, 1]
-        l_prev = l_ref[:, :1]
-        row_max = jnp.max(logits, axis=-1, keepdims=True)  # [block_q, 1]
-        m_new = jnp.maximum(m_prev, row_max)
-        # Fully-masked rows keep m=-inf; exp against a safe max stays 0.
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(logits - m_safe)
-        p = jnp.where(jnp.isfinite(logits), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p,
-            v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        m_new, l_new, acc = _step((m_ref[:, :1], l_ref[:, :1], acc_ref[:]))
+        acc_ref[:] = acc
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     if causal:
-        # Live iff this kv block intersects the causal triangle of this q block.
+        # A kv block strictly above the diagonal of this q block is all
+        # masked; skip its matmuls entirely. Live iff this kv block
+        # intersects the causal triangle of this q block.
         @pl.when(k_start <= q_start + block_q - 1)
         def _():
             _compute()
@@ -124,13 +197,7 @@ def _flash_kernel(
 
     @pl.when(kv_idx == num_kv - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-        # Logsumexp per row, for the backward kernels' softmax recompute
-        # (P = exp(logits - lse)). Fully-masked rows keep -inf.
-        m = m_ref[:, :1]
-        lse = jnp.where(jnp.isfinite(m), m + jnp.log(denom), NEG_INF)
-        lse_ref[0, 0] = lse[:, 0]
+        _write(m_ref[:, :1], l_ref[:, :1], acc_ref[:])
 
 
 def _tileable_block(S: int, target: int, align: int) -> Optional[int]:
@@ -248,7 +315,8 @@ def _flash_forward(
         causal=causal,
     )
 
-    scratch_shapes = [
+    # One kv block: no state runs from step to step, so none is kept.
+    scratch_shapes = [] if nk == 1 else [
         pltpu.VMEM((block_q, 128), jnp.float32),  # running max
         pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
         pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
@@ -284,28 +352,26 @@ def _flash_forward(
     return (out, lse) if with_lse else out
 
 
-def _bwd_recompute(q, k, v, do, lse, delta, q_start, k_start, scale, causal):
+def _bwd_recompute(x, y, dx, dy, lse, delta, keep, scale):
     """Shared backward block math: recompute P from the forward's logsumexp
-    and form dS — used identically by both backward kernels.
+    and form dS — used identically by both backward kernels, each in its own
+    orientation: the scores are ``x y^T`` and dP is ``dx dy^T``, so (q, k,
+    do, v) gives [bq, bk] tiles against column ``lse``/``delta`` and (k, q,
+    v, do) gives the transposed [bk, bq] tiles against row vectors.
 
-    Returns (p, ds): p = exp(logits - lse) [bq, bk] with masked/fully-masked
-    rows zeroed; ds = p * (dO V^T - delta) * scale."""
-    logits = jax.lax.dot_general(
-        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                      # [bq, bk]
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0) + q_start
-        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) + k_start
-        logits = jnp.where(rows >= cols, logits, NEG_INF)
-    p = jnp.where(jnp.isfinite(lse), jnp.exp(logits - lse), 0.0)
-    p = jnp.where(jnp.isfinite(logits), p, 0.0)
-    dp = jax.lax.dot_general(
-        do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                              # [bq, bk]
-    ds = p * (dp - delta) * scale
-    return p, ds
+    ``keep`` is the causal mask of the tile, or None; with it, masked and
+    fully-masked rows are zeroed. The scale stays on the tiles here: out of
+    them it measured no faster in either kernel (PERF.md, PR 30).
+
+    Returns (p, ds): p = exp(logits - lse); ds = p * (dP - delta) * scale."""
+    logits = _dot(x, y, 1, 1) * scale
+    if keep is None:
+        p = jnp.exp(logits - lse)
+    else:
+        logits = jnp.where(keep, logits, NEG_INF)
+        p = jnp.where(jnp.isfinite(lse), jnp.exp(logits - lse), 0.0)
+        p = jnp.where(jnp.isfinite(logits), p, 0.0)
+    return p, p * (_dot(dx, dy, 1, 1) - delta) * scale
 
 
 def _bwd_dkdv_kernel(
@@ -317,8 +383,11 @@ def _bwd_dkdv_kernel(
     """dK/dV for one kv block: grid (b*kv_head, kv_block, q_stream).
 
     Streams q/do/lse/delta blocks past a resident kv block, recomputing
-    P = exp(logits - lse) from the forward's logsumexp, accumulating
-    dV += P^T dO and dK += dS^T Q in VMEM scratch.
+    P^T = exp(k q^T - lse) from the forward's logsumexp on TRANSPOSED
+    [bk, bq] tiles — lse/delta broadcast along lanes as they are stored, and
+    dV += P^T dO, dK += dS^T Q are plain products (6.03 ms a call against
+    6.34 contracting over the rows of untransposed tiles, PERF.md PR 30) —
+    accumulating in VMEM scratch.
 
     Under grouped-query attention the innermost axis streams ``nq`` q
     blocks for EACH of the group's q heads (length nq*group): the grouped
@@ -336,26 +405,22 @@ def _bwd_dkdv_kernel(
 
     q_start = q_idx * block_q
     k_start = kv_idx * block_k
+    op = _operand_dtype(q_ref.dtype, causal)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32)          # [bq, d]
-        do = do_ref[0].astype(jnp.float32)        # [bq, d]
-        lse = lse_ref[0, 0][:, None]              # [bq, 1]
-        delta = delta_ref[0, 0][:, None]          # [bq, 1]
-        k = k_ref[0].astype(jnp.float32)          # [bk, d]
-        v = v_ref[0].astype(jnp.float32)          # [bk, d]
-
-        p, ds = _bwd_recompute(
-            q, k, v, do, lse, delta, q_start, k_start, scale, causal
+        q = q_ref[0].astype(op)                   # [bq, d]
+        do = do_ref[0].astype(op)                 # [bq, d]
+        k = k_ref[0].astype(op)                   # [bk, d]
+        v = v_ref[0].astype(op)                   # [bk, d]
+        keep = (
+            _causal_keep((block_k, block_q), q_start, k_start, 1)
+            if causal else None
         )
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                          # [bk, d]
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                          # [bk, d]
+        p_t, ds_t = _bwd_recompute(
+            k, q, v, do, lse_ref[0], delta_ref[0], keep, scale
+        )                                          # [bk, bq]
+        dv_acc[:] = dv_acc[:] + _dot(p_t.astype(op), do, 1, 0)
+        dk_acc[:] = dk_acc[:] + _dot(ds_t.astype(op), q, 1, 0)
 
     if causal:
         # Live iff some row of this q block can attend into this kv block.
@@ -389,22 +454,22 @@ def _bwd_dq_kernel(
 
     q_start = q_idx * block_q
     k_start = kv_idx * block_k
+    op = _operand_dtype(q_ref.dtype, causal)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-
+        q = q_ref[0].astype(op)
+        do = do_ref[0].astype(op)
+        k = k_ref[0].astype(op)
+        v = v_ref[0].astype(op)
+        keep = (
+            _causal_keep((block_q, block_k), q_start, k_start, 0)
+            if causal else None
+        )
         _, ds = _bwd_recompute(
-            q, k, v, do, lse, delta, q_start, k_start, scale, causal
-        )
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            q, k, do, v, lse_ref[0, 0][:, None], delta_ref[0, 0][:, None],
+            keep, scale,
+        )                                          # [bq, bk]
+        dq_acc[:] = dq_acc[:] + _dot(ds.astype(op), k, 1, 0)
 
     if causal:
         @pl.when(k_start <= q_start + block_q - 1)
@@ -522,40 +587,51 @@ def _flash_backward(
     )
 
 
-def _default_blocks(S: int, D: int, block_q, block_k, backward: bool = False):
-    """Resolve block sizes: as large as VMEM comfortably allows.
+def _default_blocks(S: int, D: int, dtype, causal: bool, block_q, block_k,
+                    backward: bool = False):
+    """Resolve block sizes: as large as the 16 MiB of scoped VMEM allow, and
+    what a v5e chip measured fastest (PERF.md, PR 30: kernel time of a call
+    from the profiler's trace, bfloat16, 65,536 tokens of S 2048 unless
+    said; VMEM is what the chip's compiler asks for the call).
 
-    Recorded on a v5e chip in an earlier round (not measured on today's
-    code): 128x128 blocks ran 54ms forward vs XLA's fused attention at
-    24ms (seq 4096, D=64) — grid overhead and tiny MXU matmuls dominated;
-    1024-tile forwards ~20% faster than XLA, and with the 512-tile
-    backward the fwd+bwd pair 2.0x faster. The caps clamp by head dim to keep the
-    per-step VMEM working set (f32 [bq, bk] intermediates + streamed
-    blocks + Pallas double-buffering) inside the ~16MB scoped budget:
-    1024-tile forwards fail Mosaic compilation at D=256 (measured), and
-    1024-tile backwards fail inside real models even at D=64 (stack
-    measured 16.69MB vs the 16MB limit).
+    Forward without a mask, D <= 128: one kv block of up to 2048 keys and a
+    q block that gives way to it (512), so that the float32 score tile
+    stays at 4 MB. At S <= 2048 the recurrence is then a single step with
+    no running state. ms at (1024, 1024) | (512, 2048) with state | without:
+    D 64 4.83 | 4.03 | 3.77, D 128 2.48 | 2.19 | 2.09, D 32 9.93 | 8.83 |
+    8.36; float32 D 64 4.90 | - | 3.89, D 128 2.49 | - | 2.10; S 4096, two
+    kv blocks: 4.59 | 4.26. S 1024 in one 1024-key block: 5.02 -> 4.22.
+    6.4 MiB (8.9 in float32).
+    Forward with a mask: (1024, 1024) as before. A kv block that spans the
+    sequence leaves the block skip nothing to skip: 4.42 ms against 4.90 at
+    (512, 2048), and 3.52 against 4.22 at S 4096.
+    Backward without a mask on bfloat16 operands, D <= 128: 1024 x 1024,
+    11.9 MiB. ms at 512 | 1024 tiles: dK/dV D 64 6.88 | 6.24, D 128 3.33 |
+    2.98, D 32 13.70 | 12.41; dQ D 64 5.37 | 4.71, D 128 2.61 | 2.25, D 32
+    10.70 | 9.40 (2048 along either axis: within 1.5 % of that; 1024 x
+    2048 does not fit).
+    Backward with a mask or with float32 operands: 512, as before. 1024
+    tiles ask 16.01 MiB with a mask (refused in a program of batch 32,
+    accepted in one of batch 8: on the line) and 15.5 MiB in float32.
+    D > 128: the caps of before (512, then 256), which no reading here
+    covers beyond D 256 at those caps.
     """
+    # Explicit blocks are clamped to the same caps: a block past them is a
+    # compile error (1024-tile forwards fail Mosaic compilation at D=256),
+    # not a knob, and a user-tuned forward tile must not push the backward's
+    # larger working set (the logits, p, dp and ds tiles at once) past VMEM.
     if backward:
-        # The backward cap binds EXPLICIT blocks too (the pre-kernel
-        # backward enforced a hard 512 ceiling the same way): a user-tuned
-        # forward tile must not push the backward's larger working set past
-        # VMEM. 512 max: the dK/dV kernel holds FOUR [bq, bk] f32
-        # intermediates (logits, p, dp, ds), and at 1024 tiles Mosaic's
-        # scoped-vmem stack measured 16.69MB against the 16MB limit inside
-        # a real model's backward (OOM observed on v5e at D=64, seq 2048 —
-        # the standalone microbench sat just under the line).
-        cap = 512 if D <= 256 else 256
+        narrow = _operand_dtype(dtype, causal) == jnp.bfloat16
+        cap = 1024 if D <= 128 and narrow else (512 if D <= 256 else 256)
         bq = min(cap, S) if block_q is None else min(block_q, cap, S)
         bk = min(cap, S) if block_k is None else min(block_k, cap, S)
         return bq, bk
-    # The cap binds EXPLICIT blocks too (same policy as the backward):
-    # 1024-tile forwards fail Mosaic compilation at D=256 (measured), so a
-    # user-pinned block_q=1024 there would be a compile error, not a knob.
-    cap = 1024 if D <= 128 else (512 if D <= 512 else 256)
-    bq = min(cap, S) if block_q is None else min(block_q, cap, S)
-    bk = min(cap, S) if block_k is None else min(block_k, cap, S)
-    return bq, bk
+    cap_q = 1024 if D <= 128 else (512 if D <= 512 else 256)
+    cap_k = 2048 if D <= 128 and not causal else cap_q
+    bk = min(cap_k, S) if block_k is None else min(block_k, cap_k, S)
+    # The [bq, bk] float32 score tile stays at the 4 MB of a 1024 x 1024 one.
+    bq = min(cap_q, S, max(512, (1 << 20) // bk))
+    return (bq if block_q is None else min(block_q, bq)), bk
 
 
 def flash_can_tile(S: int, D: int) -> bool:
@@ -564,9 +640,12 @@ def flash_can_tile(S: int, D: int) -> bool:
     ring, Ulysses) ask before selecting the kernel."""
     return all(
         None not in _tileable_blocks(
-            S, *_default_blocks(S, D, None, None, backward=backward)
+            S,
+            *_default_blocks(S, D, dtype, causal, None, None, backward=backward),
         )
         for backward in (False, True)
+        for dtype in (jnp.bfloat16, jnp.float32)
+        for causal in (False, True)
     )
 
 
@@ -595,18 +674,24 @@ def flash_attention(
     False.
     """
     s = (q.shape[-1] ** -0.5) if scale is None else scale
-    bq, bk = _default_blocks(q.shape[1], q.shape[-1], block_q, block_k)
+    bq, bk = _default_blocks(
+        q.shape[1], q.shape[-1], q.dtype, causal, block_q, block_k
+    )
     return _flash_forward(q, k, v, s, causal, bq, bk, interpret)
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     S, D = q.shape[1], q.shape[-1]
-    bq, bk = _default_blocks(S, D, block_q, block_k)
+    bq, bk = _default_blocks(S, D, q.dtype, causal, block_q, block_k)
     # An S the backward cannot tile is refused here, while the forward is
     # traced, not halfway into the gradient's trace.
     _adjust_blocks(
-        S, *_default_blocks(S, D, block_q, block_k, backward=True), interpret
+        S,
+        *_default_blocks(
+            S, D, q.dtype, causal, block_q, block_k, backward=True
+        ),
+        interpret,
     )
     out, lse = _flash_forward(
         q, k, v, s, causal, bq, bk, interpret, with_lse=True
@@ -621,7 +706,8 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     s = (q.shape[-1] ** -0.5) if scale is None else scale
     bq, bk = _default_blocks(
-        q.shape[1], q.shape[-1], block_q, block_k, backward=True
+        q.shape[1], q.shape[-1], q.dtype, causal, block_q, block_k,
+        backward=True,
     )
     return _flash_backward(
         q, k, v, out, lse, g, s, causal, bq, bk, interpret

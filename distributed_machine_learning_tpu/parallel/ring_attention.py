@@ -135,7 +135,7 @@ def _flash_chunk_fwd(q, k, v, scale, causal, interpret):
     )
 
     S, D = q.shape[1], q.shape[-1]
-    bq, bk = _default_blocks(S, D, None, None)
+    bq, bk = _default_blocks(S, D, q.dtype, causal, None, None)
     return _flash_forward(
         q, k, v, scale, causal, bq, bk, interpret, with_lse=True
     )
@@ -153,7 +153,9 @@ def _flash_chunk_bwd(q, k, v, out, lse, do, scale, causal, interpret,
     )
 
     S, D = q.shape[1], q.shape[-1]
-    bq, bk = _default_blocks(S, D, None, None, backward=True)
+    bq, bk = _default_blocks(
+        S, D, q.dtype, causal, None, None, backward=True
+    )
     return _flash_backward(
         q, k, v, out, lse, do, scale, causal, bq, bk, interpret,
         q_side=q_side,

@@ -71,6 +71,16 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernel_yields(compiled):
+    """What each ``tpu_custom_call`` of a compiled program yields: the text
+    between ``=`` and ``custom-call(`` of its HLO line, layouts and all."""
+    return [
+        line.split(" custom-call(")[0].split("=", 1)[1]
+        for line in compiled.as_text().splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+
+
 # The `train` phase of chip_smoke.py runs B 8, S 2048, 8 heads x 64.
 TRAIN_SHAPES = {
     "bf16": dict(),
@@ -90,6 +100,11 @@ def test_flash_forward_compiles(one_chip, name, causal):
         lambda q, k, v: pa.flash_attention(q, k, v, None, causal), q, k, v
     )
     assert compiled.as_text().count("tpu_custom_call") == 1
+    # The forward is the kernel that yields float32 row statistics beside
+    # its output: benchmark/metrics/flash_fwd_roofline.json finds it so.
+    (yields,) = _kernel_yields(compiled)
+    assert yields.count("f32[") == (2 if kw.get("dtype") == jnp.float32 else 1)
+    assert f"f32[{8 * kw.get('H', 8)},1,{q.shape[1]}]" in yields
 
 
 @pytest.mark.parametrize("name", sorted(TRAIN_SHAPES))
@@ -102,6 +117,14 @@ def test_flash_backward_compiles(one_chip, name, causal):
         jax.grad(_flash_loss(causal), argnums=(0, 1, 2)), q, k, v
     )
     assert compiled.as_text().count("tpu_custom_call") == 3
+    # One kernel yields float32 (the forward's lse) and two yield gradients
+    # in the input's dtype only, which is how flash_fwd_roofline.json and
+    # flash_bwd_roofline.json tell them apart (``yields`` / ``yields_no``
+    # "f32["). With float32 inputs everything is f32 and nothing matches;
+    # the benchmark's cells are bfloat16.
+    if kw.get("dtype", jnp.bfloat16) == jnp.bfloat16:
+        with_f32 = ["f32[" in y for y in _kernel_yields(compiled)]
+        assert sorted(with_f32) == [False, False, True]
 
 
 @pytest.mark.parametrize("S", [50, 96, 100, 200, 500, 1536])
@@ -113,22 +136,35 @@ def test_flash_compiles_at_search_space_lengths(one_chip, S):
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
-@pytest.mark.parametrize("S", [1000, 2000])
-def test_untileable_length_raises_named_error(one_chip, S):
+@pytest.mark.parametrize("S,dtype,causal", [
+    (1000, jnp.float32, False), (1000, jnp.bfloat16, True),
+    (2000, jnp.bfloat16, False), (2000, jnp.float32, False),
+])
+def test_untileable_length_raises_named_error(one_chip, S, dtype, causal):
     """1000 and 2000 have no divisor that is a multiple of 128 under the
-    caps: asked for by name, the kernel says so at trace time — never the
-    lowering's own block-shape error, and nothing is padded."""
-    q, k, v = _qkv(one_chip, S, B=2)
+    backward's caps (2000 under any; 1000 under the 512 of float32 operands
+    or a mask): asked for by name, the kernel says so at trace time — never
+    the lowering's own block-shape error, and nothing is padded."""
+    q, k, v = _qkv(one_chip, S, B=2, dtype=dtype)
     with pytest.raises(ValueError, match=f"cannot tile seq len {S}"):
-        _compile(jax.grad(_flash_loss(), argnums=(0, 1, 2)), q, k, v)
+        _compile(jax.grad(_flash_loss(causal), argnums=(0, 1, 2)), q, k, v)
     assert not pa.flash_can_tile(S, 64)
 
 
 def test_untileable_1000_forward_alone_compiles(one_chip):
     """Forward-only (eval/serve) S=1000 fits one whole-axis block under the
     forward cap; only the backward's 512 cap refuses it."""
-    q, k, v = _qkv(one_chip, 1000, B=2)
+    q, k, v = _qkv(one_chip, 1000, B=2, dtype=jnp.float32)
     _compile(lambda q, k, v: pa.flash_attention(q, k, v), q, k, v)
+
+
+def test_1000_fits_the_unmasked_bfloat16_backward_whole(one_chip):
+    """Under that body's 1024 cap S=1000 is one whole-axis block, forward
+    and backward; the automatic routes still decline it (``flash_can_tile``
+    answers for every dtype and mask, and is not asked about one)."""
+    q, k, v = _qkv(one_chip, 1000, B=2)
+    compiled = _compile(jax.grad(_flash_loss(), argnums=(0, 1, 2)), q, k, v)
+    assert compiled.as_text().count("tpu_custom_call") == 3
 
 
 @pytest.mark.parametrize("S,D,ok", [
