@@ -7,11 +7,16 @@ architecture fields from the config keys the reference's search spaces use
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict
 
 import jax.numpy as jnp
 
 from distributed_machine_learning_tpu.models.cnn import CNN1DRegressor
+from distributed_machine_learning_tpu.models.hybrid_lm import (
+    GatedHybridLM,
+    HybridSizes,
+)
 from distributed_machine_learning_tpu.models.mlp import MLPRegressor
 from distributed_machine_learning_tpu.models.moe import MoEFF
 from distributed_machine_learning_tpu.models.rnn import RNNRegressor
@@ -144,6 +149,25 @@ def _build_rnn(config: Dict[str, Any]):
     )
 
 
+@models.register("gated_hybrid_lm")
+def _build_gated_hybrid_lm(config: Dict[str, Any]):
+    """A decoder LM over int32 token ids (models/hybrid_lm.py).  Every size
+    is a config key of ``HybridSizes``'s name; ``held_experts`` is
+    ``[first id, count]`` of the experts this chip holds."""
+    sizes = {
+        f.name: config[f.name]
+        for f in dataclasses.fields(HybridSizes) if f.name in config
+    }
+    if sizes.get("held_experts") is not None:
+        sizes["held_experts"] = tuple(int(v) for v in sizes["held_experts"])
+    return GatedHybridLM(
+        vocab_size=int(config["vocab_size"]),
+        num_layers=int(config.get("num_layers", 4)),
+        sizes=HybridSizes(**sizes),
+        dtype=compute_dtype_of(config),
+    )
+
+
 def build_model(config: Dict[str, Any]):
     """Construct a model from a trial config; ``config['model']`` picks the family."""
     return models.get(config.get("model", "transformer"))(config)
@@ -154,6 +178,8 @@ __all__ = [
     "build_model",
     "compute_dtype_of",
     "MLPRegressor",
+    "GatedHybridLM",
+    "HybridSizes",
     "MoEFF",
     "CNN1DRegressor",
     "TransformerRegressor",

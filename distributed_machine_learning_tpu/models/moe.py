@@ -52,6 +52,13 @@ def expert_capacity(capacity_factor: float, top_k: int, group: int,
     return max(math.ceil(capacity_factor * top_k * group / num_experts), 1)
 
 
+# The collection an expert layer sows its routing counts into (never added
+# to the objective, unlike ``"moe"``): ``local_pairs`` and
+# ``load_max_over_mean`` a layer (models/hybrid_lm.py DroplessMoE); the
+# token evaluation program reads it (tune/_regression_program.py).
+STATS_COLLECTION = "moe_stats"
+
+
 def collect_aux(mutated_collections) -> jnp.ndarray:
     """Sum every aux term sown into the ``"moe"`` collection of a
     ``model.apply(..., mutable=["moe"])`` result — THE way training loops

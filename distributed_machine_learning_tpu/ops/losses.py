@@ -8,6 +8,7 @@ returning a scalar, so they fuse into the jitted train step.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -49,6 +50,54 @@ def mape_loss(predictions: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
 @losses.register("rmse")
 def rmse_loss(predictions: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     return jnp.sqrt(jnp.mean((predictions - targets) ** 2))
+
+
+# Losses whose targets are integer token ids and whose predictions are
+# logits over a vocabulary: evaluation then reports the loss itself and
+# not the regression errors (tune/_regression_program.py).
+TOKEN_LOSSES = frozenset({"cross_entropy"})
+
+
+def token_cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """Per-position ``-log softmax(logits)[target]`` in float32: logits
+    [..., V], integer targets [...]."""
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(
+        logits, targets.astype(jnp.int32)[..., None], axis=-1
+    )[..., 0]
+    return jax.nn.logsumexp(logits, axis=-1) - picked
+
+
+# Positions of a sequence whose float32 logits are live at once in
+# ``cross_entropy``: [B, 8192, V] logits are gigabytes in float32.
+CROSS_ENTROPY_CHUNK = 1024
+
+
+@losses.register("cross_entropy")
+def cross_entropy_loss(predictions: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """Mean next-token cross-entropy: logits [B, S, V] against ids [B, S]
+    (the data gives each position's target; nothing is shifted here).
+    Logits come in the model's compute dtype and are widened here, a chunk
+    of the sequence at a time, each chunk rematerialised in the backward
+    pass."""
+    with jax.named_scope("cross_entropy"):
+        B, S = targets.shape
+        chunk = CROSS_ENTROPY_CHUNK
+        if S <= chunk or S % chunk:
+            return jnp.mean(token_cross_entropy(predictions, targets))
+        n = S // chunk
+        logits = jnp.moveaxis(predictions.reshape(B, n, chunk, -1), 1, 0)
+        ids = jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0)
+        sums = jax.lax.map(
+            jax.checkpoint(lambda xs: jnp.sum(token_cross_entropy(*xs))),
+            (logits, ids),
+        )
+        return jnp.sum(sums) / (B * S)
+
+
+# The training step widens predictions to float32 before a loss sees them,
+# except for a loss that says it does so itself.
+cross_entropy_loss.widens_itself = True
 
 
 def get_loss(name: str):

@@ -615,6 +615,12 @@ def _default_blocks(S: int, D: int, dtype, causal: bool, block_q, block_k,
     accepted in one of batch 8: on the line) and 15.5 MiB in float32.
     D > 128: the caps of before (512, then 256), which no reading here
     covers beyond D 256 at those caps.
+    The masked body at D 256 with grouped kv (PERF.md, PR 32: kernel time
+    of a call from the trace of `train_q3next_s8192`, bfloat16,
+    [2, 8192, 16:2, 256], 16,384 tokens; float32 operands under the mask):
+    forward 14.85 ms at (512, 512), dK/dV 16.68 and dQ 12.65 at 512 tiles:
+    39 % and 48 % of the causal half's roofline. No other tile was tried
+    at that shape.
     """
     # Explicit blocks are clamped to the same caps: a block past them is a
     # compile error (1024-tile forwards fail Mosaic compilation at D=256),

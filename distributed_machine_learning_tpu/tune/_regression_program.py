@@ -48,6 +48,15 @@ def make_forward(model, flag_name: str, has_bn: bool) -> Callable:
     return forward
 
 
+def _widened(loss_fn: Callable, preds: jnp.ndarray) -> jnp.ndarray:
+    """Predictions as the loss takes them: float32, but as they are for a
+    loss that widens them itself (``cross_entropy`` does, a chunk of the
+    sequence at a time: ops/losses.py)."""
+    if getattr(loss_fn, "widens_itself", False):
+        return preds
+    return preds.astype(jnp.float32)
+
+
 def per_example_losses(preds: jnp.ndarray, targets: jnp.ndarray):
     """Per-example squared error, absolute error, and APE (for masked eval)."""
     se = jnp.mean((preds - targets) ** 2, axis=-1)
@@ -83,7 +92,7 @@ def make_epoch_fn(
 
             def loss_of(p):
                 preds, new_bs, aux = forward(p, batch_stats, xb, dkey, train=True)
-                return loss_fn(preds.astype(jnp.float32), yb) + aux, new_bs
+                return loss_fn(_widened(loss_fn, preds), yb) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params
@@ -132,7 +141,7 @@ def make_chunk_epoch_fn(
             def loss_of(p):
                 preds, new_bs, aux = forward(p, batch_stats, xb_, dkey,
                                              train=True)
-                return loss_fn(preds.astype(jnp.float32), yb_) + aux, new_bs
+                return loss_fn(_widened(loss_fn, preds), yb_) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params
@@ -175,7 +184,7 @@ def make_indexed_epoch_fn(
 
             def loss_of(p):
                 preds, new_bs, aux = forward(p, batch_stats, x, key, True)
-                return loss_fn(preds.astype(jnp.float32), y) + aux, new_bs
+                return loss_fn(_widened(loss_fn, preds), y) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(
                 loss_of, has_aux=True
@@ -214,7 +223,7 @@ def make_indexed_chunk_fn(
 
             def loss_of(p):
                 preds, new_bs, aux = forward(p, batch_stats, x, key, True)
-                return loss_fn(preds.astype(jnp.float32), y) + aux, new_bs
+                return loss_fn(_widened(loss_fn, preds), y) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(
                 loss_of, has_aux=True
@@ -335,6 +344,59 @@ def make_eval_fn(
     return evaluate
 
 
+def make_token_eval_fn(
+    model, flag_name: str, n_blocks: int, eval_bs: int
+) -> Callable:
+    """Masked blockwise eval of a language model (a loss of
+    ``ops.losses.TOKEN_LOSSES``): ``(params, batch_stats, x, y, mask) ->
+    {validation_loss, validation_perplexity}``, the mean cross-entropy
+    over the positions of the unmasked rows.  Where the model's expert
+    layers sow their routing counts (``models/hybrid_lm.py``), the
+    evaluation batches' counts ride along: ``moe_local_pairs`` (token-expert
+    pairs computed here a batch, summed over the layers) and
+    ``moe_load_max_over_mean`` (the fullest held expert over the mean one,
+    the worst layer's)."""
+    from distributed_machine_learning_tpu.models.moe import STATS_COLLECTION
+    from distributed_machine_learning_tpu.ops.losses import token_cross_entropy
+
+    kwargs = {flag_name: flag_name == "deterministic"}
+
+    def evaluate(params, batch_stats, x_all, y_all, mask):
+        del batch_stats  # no family with batch statistics predicts tokens
+        xb = x_all.reshape(n_blocks, eval_bs, *x_all.shape[1:])
+        yb = y_all.reshape(n_blocks, eval_bs, *y_all.shape[1:])
+        mb = mask.reshape(n_blocks, eval_bs)
+
+        def step(_, batch):
+            x, y, m = batch
+            logits, mut = model.apply(
+                {"params": params}, x, mutable=[STATS_COLLECTION], **kwargs
+            )
+            nll = jnp.mean(token_cross_entropy(logits, y), axis=-1)
+            sown = jax.tree_util.tree_leaves_with_path(
+                mut.get(STATS_COLLECTION, {})
+            )
+
+            def layers(name):  # what every expert layer sowed under ``name``
+                return [v for path, v in sown
+                        if name in jax.tree_util.keystr(path)]
+
+            pairs = [jnp.sum(v) for v in layers("local_pairs")]
+            loads = [jnp.max(v) for v in layers("load_max_over_mean")]
+            routing = (sum(pairs), jnp.max(jnp.stack(loads))) if pairs else ()
+            return None, ((nll * m).sum(), routing)
+
+        _, (nll, routing) = jax.lax.scan(step, None, (xb, yb, mb))
+        loss = nll.sum() / mask.sum()
+        out = {"validation_loss": loss, "validation_perplexity": jnp.exp(loss)}
+        if routing:
+            out["moe_local_pairs"] = routing[0].mean()
+            out["moe_load_max_over_mean"] = routing[1].max()
+        return out
+
+    return evaluate
+
+
 @dataclass
 class StagedData:
     """Device-resident dataset + padded validation block layout."""
@@ -378,11 +440,16 @@ def stage_data(
         if pad
         else val_data.y
     )
+    def staged(a, float_dtype):
+        # Token ids stay integers: no float of the compute dtype holds them.
+        integer = np.issubdtype(np.asarray(a).dtype, np.integer)
+        return jnp.asarray(a, dtype=jnp.int32 if integer else float_dtype)
+
     return StagedData(
-        x_train=jnp.asarray(train_data.x, dtype=compute_dtype),
-        y_train=jnp.asarray(train_data.y, dtype=jnp.float32),
-        x_val=jnp.asarray(x_val, dtype=compute_dtype),
-        y_val=jnp.asarray(y_val, dtype=jnp.float32),
+        x_train=staged(train_data.x, compute_dtype),
+        y_train=staged(train_data.y, jnp.float32),
+        x_val=staged(x_val, compute_dtype),
+        y_val=staged(y_val, jnp.float32),
         val_mask=jnp.asarray(
             np.concatenate([np.ones(n_val, np.float32), np.zeros(pad, np.float32)])
         ),

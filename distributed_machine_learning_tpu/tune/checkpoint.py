@@ -383,6 +383,33 @@ class AsyncCheckpointWriter:
             return x.copy()
         return x
 
+    @staticmethod
+    def _device_has_room_for(leaves) -> bool:
+        """Whether every device that holds leaves of the tree can hold a
+        second copy of its share beside what it holds already (the
+        allocator's ``bytes_limit - bytes_in_use``).  A device that reports
+        no statistics (the CPU) is taken to have room."""
+        need: Dict[Any, int] = {}
+        for x in leaves:
+            if not isinstance(x, jax.Array):
+                continue
+            devices = x.sharding.device_set
+            if len(devices) == 1:  # the common case, and no shard objects
+                (device,) = devices
+                need[device] = need.get(device, 0) + x.nbytes
+                continue
+            for shard in x.addressable_shards:
+                need[shard.device] = (
+                    need.get(shard.device, 0) + shard.data.nbytes
+                )
+        for device, nbytes in need.items():
+            stats = device.memory_stats() or {}
+            if "bytes_limit" in stats and nbytes > (
+                stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+            ):
+                return False
+        return True
+
     def submit(self, path: str, tree: Dict[str, Any]) -> str:
         """Enqueue a write; returns ``path`` immediately."""
         from distributed_machine_learning_tpu import obs
@@ -390,10 +417,21 @@ class AsyncCheckpointWriter:
         metrics = get_metrics()
         t0 = time.time()
         leaves, treedef = jax.tree.flatten(tree)
-        with obs.span("report.ckpt_snapshot", {"leaves": len(leaves)}):
-            snapshot = treedef.unflatten(
-                [self._snapshot_leaf(x) for x in leaves]
-            )
+        with obs.span("report.ckpt_snapshot", {"leaves": len(leaves)}) as sp:
+            if self._device_has_room_for(leaves):
+                snapshot = [self._snapshot_leaf(x) for x in leaves]
+            else:
+                # A state that fills the chip (parameters and optimizer
+                # state of a model sized to it) has no room for its copy:
+                # read it to the host here, leaf by leaf, before the
+                # caller's next step donates the buffers.
+                sp.set("to_host", True)
+                snapshot = [
+                    np.asarray(x) if isinstance(x, jax.Array)
+                    else self._snapshot_leaf(x)
+                    for x in leaves
+                ]
+            snapshot = treedef.unflatten(snapshot)
         metrics.add("save_block_s", time.time() - t0)
         done = threading.Event()
         with self._lock:

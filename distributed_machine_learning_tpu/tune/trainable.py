@@ -49,7 +49,7 @@ from distributed_machine_learning_tpu import obs
 from distributed_machine_learning_tpu.analysis.locks import named_lock
 from distributed_machine_learning_tpu.data.loader import Dataset
 from distributed_machine_learning_tpu.models import build_model
-from distributed_machine_learning_tpu.ops.losses import get_loss
+from distributed_machine_learning_tpu.ops.losses import TOKEN_LOSSES, get_loss
 from distributed_machine_learning_tpu.ops.optimizers import (
     INJECTABLE_OPTIMIZERS,
     make_injected_optimizer,
@@ -67,6 +67,7 @@ from distributed_machine_learning_tpu.tune._regression_program import (
     make_epoch_fn,
     make_eval_fn,
     make_forward,
+    make_token_eval_fn,
     per_example_losses,
     stage_data,
 )
@@ -320,7 +321,13 @@ def train_regressor(
             donate_argnums=(0, 1, 2),
         )
         evaluate = jax.jit(
-            make_eval_fn(forward, loss_name, data.n_val_blocks, data.eval_bs)
+            make_token_eval_fn(
+                model, flag_name, data.n_val_blocks, data.eval_bs
+            )
+            if loss_name in TOKEN_LOSSES
+            else make_eval_fn(
+                forward, loss_name, data.n_val_blocks, data.eval_bs
+            )
         )
         return _CohortBundle(
             data=data, model=model, flag_name=flag_name, has_bn=has_bn,
@@ -531,6 +538,14 @@ def train_regressor(
             _time.time() - t0 - (tracker.thread_seconds() - c0), 1e-9
         )
         perf_acct.annotate(record, exec_s, device=device)
+        if "moe_local_pairs" in metrics:
+            # An expert layer's routing counts (make_token_eval_fn): the
+            # mean ratio is load_max_over_mean_sum over reports.
+            registry = obs.get_registry()
+            registry.add("moe.local_pairs", metrics["moe_local_pairs"])
+            registry.add("moe.load_max_over_mean_sum",
+                         metrics["moe_load_max_over_mean"])
+            registry.add("moe.reports")
         checkpoint = None
         if checkpoint_freq and (epoch + 1) % checkpoint_freq == 0:
             checkpoint = {
