@@ -30,6 +30,7 @@ class CheckpointMetrics:
 
     _FIELDS = (
         "saves",
+        "saves_streamed",
         "save_bytes",
         "save_wall_s",
         "save_block_s",
@@ -67,9 +68,15 @@ class CheckpointMetrics:
         with self._lock:
             return int(self._c["steps"])
 
-    def record_save(self, wall_s: float, nbytes: int, chunks: int = 1) -> None:
+    def record_save(self, wall_s: float, nbytes: int, chunks: int = 1,
+                    streamed: bool = False) -> None:
+        """One save done.  ``streamed``: a msgpack blob that went from the
+        leaves to storage without the payload being built (a tree the
+        streamer does not cover is packed whole and counts in ``saves``
+        alone)."""
         with self._lock:
             self._c["saves"] += 1
+            self._c["saves_streamed"] += bool(streamed)
             self._c["save_wall_s"] += wall_s
             self._c["save_bytes"] += nbytes
             self._c["chunks_written"] += chunks

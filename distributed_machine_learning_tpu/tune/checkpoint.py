@@ -6,7 +6,9 @@ Two on-disk formats, one API:
 
 * **legacy msgpack blob** (``ckpt_NNNNNN.msgpack`` + ``.manifest.json``
   sha256 sidecar) — flax msgpack of the whole pytree, written atomically;
-  the format every pre-``ckpt/`` experiment on disk already uses.
+  the format every pre-``ckpt/`` experiment on disk already uses.  The
+  save is streamed, flax's bytes: headers and each leaf's own memory go to
+  storage and into the sha256 in turn, and the payload is never built.
 * **sharded generation** (``gen_NNNNNN/`` — per-shard chunk files + JSON
   index + COMMIT marker, ``ckpt/format.py``) — async-friendly and
   topology-portable (restore onto a different mesh/device count).
@@ -26,6 +28,7 @@ framework-portable (enforced by the import-guard test in CI).
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import json
 import os
@@ -33,9 +36,10 @@ import queue
 import re
 import threading
 import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Deque, Dict, NamedTuple, Optional, Tuple
 
 import jax
+import msgpack
 import numpy as np
 from flax import serialization
 
@@ -74,6 +78,207 @@ def _is_sharded(path: str) -> bool:
     return _sharded_fmt.is_sharded_path(path)
 
 
+# -- the msgpack road, streamed ------------------------------------------------
+#
+# flax's encoding is small: ``to_state_dict`` turns every container into a
+# dict with ``str`` keys, a dict is a msgpack map, an array is ext type 1
+# around an inner ``packb((shape, dtype.name, bytes))`` (an array over
+# ``MAX_CHUNK_SIZE`` a dictionary of such chunks), and every other leaf a
+# few bytes.  Every header follows from a leaf's shape and dtype alone, so
+# one walk over the tree hands storage the headers and, between them, each
+# leaf's own memory.
+
+# Leaves under this many bytes are copied into the run of headers around
+# them: one chunk per bias would be a hash call and a write call each.
+_COALESCE_BYTES = 1 << 16
+
+# The leaves msgpack takes as they are under ``strict_types`` (exact types:
+# a subclass goes to flax's ``default``), and the two flax wraps itself.
+_SCALAR_TYPES = (type(None), bool, int, float, str, complex)
+
+
+def _ext_header(code: int, n: int) -> bytes:
+    """msgpack's header of an ext value of ``n`` payload bytes."""
+    if n in (1, 2, 4, 8, 16):
+        return bytes((0xD4 + n.bit_length() - 1, code))
+    if n <= 0xFF:
+        return bytes((0xC7, n, code))
+    if n <= 0xFFFF:
+        return b"\xc8" + n.to_bytes(2, "big") + bytes((code,))
+    return b"\xc9" + n.to_bytes(4, "big") + bytes((code,))
+
+
+def _bin_header(n: int) -> bytes:
+    if n <= 0xFF:
+        return bytes((0xC4, n))
+    if n <= 0xFFFF:
+        return b"\xc5" + n.to_bytes(2, "big")
+    return b"\xc6" + n.to_bytes(4, "big")
+
+
+def _array_header(shape, dtype, nbytes: int) -> bytes:
+    """Everything of flax's encoding of an array but its ``nbytes`` of
+    row-major data: the ext header, then the inner 3-tuple's head."""
+    inner = b"\x93" + msgpack.packb((tuple(shape), dtype.name))[1:]
+    inner += _bin_header(nbytes)
+    return _ext_header(1, len(inner) + nbytes) + inner
+
+
+def _kind(x) -> Optional[str]:
+    """How the streamer encodes a node of a state dict ("map", "array",
+    "scalar"), or None for what it leaves to flax."""
+    if type(x) is dict:
+        return "map"
+    if type(x) is np.ndarray or isinstance(x, (jax.Array, np.generic)):
+        dtype = x.dtype
+        plain = (isinstance(dtype, np.dtype) and not dtype.hasobject
+                 and dtype.fields is None)
+        return "array" if plain else None
+    return "scalar" if type(x) in _SCALAR_TYPES else None
+
+
+def _streamable(state) -> bool:
+    """Whether every node of ``state`` is one the streamer encodes."""
+    kind = _kind(state)
+    if kind != "map":
+        return kind is not None
+    return all(
+        type(key) is str and _streamable(value)
+        for key, value in state.items()
+    )
+
+
+class _Slice(NamedTuple):
+    """Elements ``start:stop`` of a leaf, flattened row-major (the whole
+    leaf when ``stop`` is None), whose bytes the stream reads in turn."""
+
+    leaf: Any
+    start: int
+    stop: Optional[int]
+    nbytes: int
+
+    def read(self) -> memoryview:
+        """The slice's bytes, a view of the host array wherever it is
+        contiguous (a device leaf is read here, whole).  bfloat16 and its
+        kin export no buffer, so the view is taken as ``uint8``."""
+        arr = np.ascontiguousarray(np.asarray(self.leaf)).reshape(-1)
+        if self.stop is not None:
+            arr = arr[self.start:self.stop]
+        return memoryview(arr.view(np.uint8))
+
+
+def _pieces(x, packer: msgpack.Packer):
+    """flax's msgpack payload of a streamable state dict, in order and as
+    it is asked for: ``bytes`` (headers, keys, scalar leaves) and a
+    :class:`_Slice` wherever an array's data goes; nothing is read from a
+    device.  Lazy, so that the walk over a large tree is spread through the
+    write and never holds the interpreter lock for long."""
+    kind = _kind(x)
+    if kind == "map":
+        yield packer.pack_map_header(len(x))
+        for key, value in x.items():
+            yield packer.pack(key)
+            yield from _pieces(value, packer)
+    elif kind == "scalar":
+        yield serialization.msgpack_serialize(x)
+    elif x.size * x.dtype.itemsize <= serialization.MAX_CHUNK_SIZE:
+        nbytes = x.size * x.dtype.itemsize
+        yield _array_header(x.shape, x.dtype, nbytes)
+        if nbytes:
+            yield _Slice(x, 0, None, nbytes)
+    else:  # flax's ``_chunk``: flat slices under the msgpack limit
+        itemsize = x.dtype.itemsize
+        if type(x) is np.ndarray:
+            x = np.ascontiguousarray(x)  # once, not once a slice
+        step = max(1, int(serialization.MAX_CHUNK_SIZE / itemsize))
+        starts = range(0, x.size, step)
+        yield (
+            packer.pack_map_header(3)
+            + packer.pack("__msgpack_chunked_array__") + packer.pack(True)
+            + packer.pack("shape")
+            + packer.pack({str(i): int(n) for i, n in enumerate(x.shape)})
+            + packer.pack("chunks") + packer.pack_map_header(len(starts))
+        )
+        for i, start in enumerate(starts):
+            stop = min(start + step, x.size)
+            nbytes = (stop - start) * itemsize
+            yield packer.pack(str(i)) + _array_header(
+                (stop - start,), x.dtype, nbytes
+            )
+            yield _Slice(x, start, stop, nbytes)
+
+
+class _PayloadStream:
+    """A streamable state dict's payload as the chunks a storage backend
+    writes, hashed as they pass: ``sha256``, ``nbytes``, ``chunks`` and
+    ``serialize_s`` are those of the newest pass (a backend that retries
+    iterates again, from the start).
+
+    A chunk is valid until the next is asked for.  Beyond the tree itself
+    one leaf is in host memory at a time (jax keeps a device leaf's host
+    copy with the array, until the caller drops the tree); the next
+    device leaf's copy is started while this one is hashed and written."""
+
+    def __init__(self, state):
+        self._state = state
+        self._start_pass()
+
+    def _start_pass(self):
+        self.sha256 = hashlib.sha256()
+        self.nbytes = self.chunks = 0
+        self.serialize_s = 0.0
+
+    def _chunk(self, data):
+        t0 = time.perf_counter()
+        self.sha256.update(data)  # over 2 KB: without the interpreter lock
+        self.serialize_s += time.perf_counter() - t0
+        self.nbytes += len(data)
+        self.chunks += 1
+        return data
+
+    def __iter__(self):
+        from distributed_machine_learning_tpu import obs
+
+        self._start_pass()
+        pieces = _pieces(self._state, msgpack.Packer())
+        coming: Deque = collections.deque()
+
+        def pull_through_next_slice():
+            for piece in pieces:
+                coming.append(piece)
+                if isinstance(piece, _Slice):
+                    if isinstance(piece.leaf, jax.Array):
+                        piece.leaf.copy_to_host_async()
+                    return
+
+        pull_through_next_slice()
+        run = bytearray()
+        while coming:
+            piece = coming.popleft()
+            if not isinstance(piece, _Slice):
+                run += piece
+                continue
+            pull_through_next_slice()  # its read-back runs beside this one
+            if isinstance(piece.leaf, jax.Array):
+                with obs.span("ckpt.device_get"):
+                    data = piece.read()
+            else:
+                t0 = time.perf_counter()
+                data = piece.read()  # a copy where the leaf is a strided view
+                self.serialize_s += time.perf_counter() - t0
+            if piece.nbytes < _COALESCE_BYTES:
+                run += data
+                data = b""
+                if len(run) < _COALESCE_BYTES:
+                    continue
+            yield self._chunk(bytes(run))
+            run = bytearray()
+            if data:
+                yield self._chunk(data)
+        if run:
+            yield self._chunk(bytes(run))
+
+
 def save_checkpoint(path: str, tree: Dict[str, Any]) -> str:
     """Serialize a pytree dict to ``path`` (any storage scheme). Returns path.
 
@@ -82,6 +287,12 @@ def save_checkpoint(path: str, tree: Dict[str, Any]) -> str:
     ``<path>.manifest.json`` sidecar (sha256 + byte count) lands AFTER the
     payload — a crash between the two leaves a checkpoint that is merely
     unverifiable, never a manifest pointing at absent data.
+
+    The blob is flax's bytes (``serialization.to_bytes`` of the tree read
+    to the host), streamed: one walk over the tree hands storage the
+    headers and each leaf's own memory in turn and hashes them as they
+    pass, so no copy of the state is built.  A tree with a leaf the
+    streamer does not cover is packed by flax itself, whole, as before.
     """
     from distributed_machine_learning_tpu import obs
 
@@ -90,24 +301,39 @@ def save_checkpoint(path: str, tree: Dict[str, Any]) -> str:
             return _sharded_fmt.save_sharded(path, tree)
     with obs.span("ckpt.save", {"format": "msgpack"}) as save_span:
         t0 = time.time()
-        with obs.span("ckpt.device_get"):
-            host_tree = _to_host(tree)
-        with obs.span("ckpt.serialize"):
-            payload = serialization.to_bytes(host_tree)
-            del host_tree  # as before the spans: not held through the write
-        save_span.set("bytes", len(payload))
-        with obs.span("ckpt.write"):  # payload, its sha256, the manifest
-            backend, p = get_storage(path)
-            backend.write_bytes(p, payload)
-            manifest = {
-                "sha256": hashlib.sha256(payload).hexdigest(),
-                "bytes": len(payload),
-                "format": "flax-msgpack",
-            }
+        backend, p = get_storage(path)
+        # jax's map sorts every dict's keys, as the read to the host did.
+        state = serialization.to_state_dict(jax.tree.map(lambda x: x, tree))
+        streamed = _streamable(state)
+        save_span.set("streamed", streamed)
+        with obs.span("ckpt.write") as write_span:
+            if streamed:
+                stream = _PayloadStream(state)
+                backend.write_chunks(p, stream)
+                nbytes, digest = stream.nbytes, stream.sha256.hexdigest()
+                chunks, serialize_s = stream.chunks, stream.serialize_s
+            else:
+                with obs.span("ckpt.device_get"):
+                    host_tree = _to_host(tree)
+                t1 = time.perf_counter()
+                payload = serialization.to_bytes(host_tree)
+                del host_tree  # not held through the write
+                nbytes = len(payload)
+                digest = hashlib.sha256(payload).hexdigest()
+                chunks, serialize_s = 1, time.perf_counter() - t1
+                backend.write_bytes(p, payload)
+            manifest = {"sha256": digest, "bytes": nbytes,
+                        "format": "flax-msgpack"}
             backend.write_bytes(
                 manifest_path_for(p), json.dumps(manifest).encode()
             )
-        get_metrics().record_save(time.time() - t0, len(payload), 1)
+            write_span.set("bytes", nbytes).set("chunks", chunks).set(
+                "serialize_s", round(serialize_s, 6)
+            )
+        save_span.set("bytes", nbytes)
+        get_metrics().record_save(
+            time.time() - t0, nbytes, 1, streamed=streamed
+        )
     return path
 
 
@@ -374,6 +600,10 @@ class AsyncCheckpointWriter:
                 with self._lock:
                     self._pending.pop(path, None)
                 done.set()
+            # Not held while the queue is empty: the snapshot is a second
+            # copy of the state on the device (and of what was read of it
+            # on the host).
+            item = tree = None
 
     @staticmethod
     def _snapshot_leaf(x):

@@ -40,7 +40,7 @@ import tempfile
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Tuple
 from distributed_machine_learning_tpu.analysis.locks import named_lock
 
 
@@ -49,6 +49,17 @@ class StorageBackend:
 
     def write_bytes(self, path: str, data: bytes) -> str:
         raise NotImplementedError
+
+    def write_chunks(self, path: str, chunks: Iterable[bytes]) -> str:
+        """Write the concatenation of ``chunks`` (bytes-like objects) to
+        ``path`` — how a payload too large to build in memory reaches
+        storage.  A chunk may be a view of memory its producer reuses:
+        consume it before asking for the next.  ``chunks`` is iterated once
+        per attempt, from the start (the retry wrapper iterates again), so
+        a caller that may be retried passes a re-iterable, not a one-shot
+        generator.  This default joins them and calls ``write_bytes``, so a
+        backend that only knows whole payloads behaves as it always did."""
+        return self.write_bytes(path, b"".join(chunks))
 
     def read_bytes(self, path: str) -> Optional[bytes]:
         raise NotImplementedError
@@ -71,12 +82,16 @@ class LocalStorage(StorageBackend):
     """Local filesystem with atomic writes (temp file + rename)."""
 
     def write_bytes(self, path: str, data: bytes) -> str:
+        return self.write_chunks(path, (data,))
+
+    def write_chunks(self, path: str, chunks: Iterable[bytes]) -> str:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path) or ".",
                                    suffix=".tmp")
         try:
             with os.fdopen(fd, "wb") as f:
-                f.write(data)
+                for chunk in chunks:
+                    f.write(chunk)
             os.replace(tmp, path)  # atomic on POSIX
         finally:
             if os.path.exists(tmp):
@@ -169,6 +184,25 @@ class FsspecStorage(StorageBackend):
     def write_bytes(self, path: str, data: bytes) -> str:
         with self._fs.open(self._strip(path), "wb") as f:
             f.write(data)
+        return path
+
+    def write_chunks(self, path: str, chunks: Iterable[bytes]) -> str:
+        p = self._strip(path)
+        # Uncommitted until every chunk is in: an object store then shows
+        # the whole payload or nothing (the upload is aborted on an error).
+        f = self._fs.open(p, "wb", autocommit=False)
+        try:
+            for chunk in chunks:
+                f.write(chunk)
+            f.close()
+        except BaseException:
+            f.discard()
+            if getattr(f, "autocommit", True):
+                # A filesystem that publishes at open (fsspec's memory://
+                # has no deferred commit): take the partial object away.
+                self._fs.rm(p)
+            raise
+        f.commit()
         return path
 
     def read_bytes(self, path: str) -> Optional[bytes]:
@@ -290,6 +324,9 @@ class RetryingStorage(StorageBackend):
 
     def write_bytes(self, path: str, data: bytes) -> str:
         return self._retry("write", self.inner.write_bytes, path, data)
+
+    def write_chunks(self, path: str, chunks: Iterable[bytes]) -> str:
+        return self._retry("write", self.inner.write_chunks, path, chunks)
 
     def read_bytes(self, path: str) -> Optional[bytes]:
         return self._retry("read", self.inner.read_bytes, path)

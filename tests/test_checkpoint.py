@@ -1,7 +1,13 @@
 """Checkpoint round-trip tests, including real optax optimizer state."""
 
+import gc
+import hashlib
+import json
+import os
 import threading
 import time
+import tracemalloc
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +75,310 @@ def test_atomic_write_no_partial_files(tmp_path):
     np.testing.assert_array_equal(raw["x"], np.zeros(4))
     leftovers = [p for p in (tmp_path / "a").iterdir() if p.suffix == ".tmp"]
     assert not leftovers
+
+
+# -- the streamed msgpack save: flax's bytes, from the leaves to the file ------
+
+
+def _parents_bytes(tree):
+    """What ``save_checkpoint`` wrote before it streamed: the tree read to
+    the host, then flax's ``to_bytes`` of all of it."""
+    from flax import serialization
+
+    return serialization.to_bytes(jax.tree.map(
+        lambda x: np.asarray(x) if hasattr(x, "shape") else x, tree
+    ))
+
+
+def _streamable_trees():
+    import ml_dtypes
+
+    rng = np.random.default_rng(0)
+    wide = rng.normal(size=(300, 70)).astype(np.float32)
+    return {
+        "nested": lambda: {
+            "b": [np.arange(5), (1, {"deep": [np.ones(2)]})],
+            "a": {"z": (np.zeros(3), [4, 5]), "y": []},
+        },
+        "empty_dict": lambda: {},
+        "keys_16": lambda: {f"k{i}": i for i in range(16)},
+        "keys_70000": lambda: {f"k{i}": i for i in range(70000)},
+        "float32": lambda: {"w": wide, "none": np.zeros((0, 3), np.float32)},
+        "bfloat16": lambda: {
+            "w": np.arange(40000).astype(ml_dtypes.bfloat16).reshape(200, 200),
+            "small": np.ones(3, ml_dtypes.bfloat16),
+        },
+        "int8_bool": lambda: {
+            "i": np.arange(-100, 100, dtype=np.int8),
+            "fixext16": np.arange(6, dtype=np.int8),
+            "b": rng.random(70000) > 0.5,
+        },
+        "zero_d": lambda: {"s": np.array(2.5, np.float32),
+                           "i": np.array(7)},
+        "numpy_scalars": lambda: {"f": np.float32(1.5), "i": np.int64(-3),
+                                  "b": np.bool_(True), "d": np.float64(2.0)},
+        "python_scalars": lambda: {
+            "i": 12, "neg": -(2 ** 40), "f": 0.25, "s": "text", "n": None,
+            "t": True, "c": 1.5 - 2j,
+        },
+        "non_contiguous": lambda: {
+            "t": wide.T, "strided": np.arange(100000)[::3],
+            "fortran": np.asfortranarray(wide),
+        },
+        "jax_arrays": lambda: {
+            "p": jnp.arange(70000, dtype=jnp.float32).reshape(7, 10000),
+            "q": jnp.ones((3, 3), jnp.bfloat16), "s": jnp.float32(2),
+        },
+        "over_chunk_size": lambda: {
+            "big": np.arange(1000, dtype=np.float32).reshape(10, 100),
+            "big_t": np.arange(2000, dtype=np.float32).reshape(40, 50).T,
+            "big_dev": jnp.arange(999, dtype=jnp.bfloat16),
+            "small": np.arange(3),
+        },
+    }
+
+
+def _spans_of(fn):
+    """Run ``fn`` under a tracer of its own; the span records it left."""
+    from distributed_machine_learning_tpu import obs
+
+    tracer = obs.Tracer()
+    obs.install_tracer(tracer)
+    try:
+        fn()
+    finally:
+        obs.install_tracer(None)
+    return tracer.records()
+
+
+def _assert_trees_equal(got, want):
+    assert type(got) is type(want) or not isinstance(want, dict)
+    if isinstance(want, dict):
+        assert list(got) == list(want)
+        for key in want:
+            _assert_trees_equal(got[key], want[key])
+    elif hasattr(want, "shape"):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    else:
+        assert got == want
+
+
+@pytest.mark.parametrize("case", sorted(_streamable_trees()))
+def test_streamed_save_writes_flax_bytes(tmp_path, monkeypatch, case):
+    """The file is ``to_bytes`` of the host tree to the byte, the manifest
+    its sha256 and length, and both load as a blob flax wrote does."""
+    from flax import serialization
+
+    from distributed_machine_learning_tpu.tune.checkpoint import (
+        manifest_path_for,
+    )
+
+    if case == "over_chunk_size":
+        monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", 1000)
+    tree = _streamable_trees()[case]()
+    want = _parents_bytes(tree)
+    path = str(tmp_path / "c.msgpack")
+    spans = _spans_of(lambda: save_checkpoint(path, tree))
+    with open(path, "rb") as f:
+        assert f.read() == want
+    with open(manifest_path_for(path)) as f:
+        assert json.load(f) == {
+            "sha256": hashlib.sha256(want).hexdigest(),
+            "bytes": len(want), "format": "flax-msgpack",
+        }
+    (save,) = [sp for sp in spans if sp["name"] == "ckpt.save"]
+    assert save["args"]["streamed"] is True
+    assert save["args"]["bytes"] == len(want)
+    loaded = load_checkpoint(path)
+    flax_path = str(tmp_path / "flax.msgpack")
+    with open(flax_path, "wb") as f:  # a checkpoint from before this road
+        f.write(want)
+    _assert_trees_equal(loaded, load_checkpoint(flax_path))
+    _assert_trees_equal(loaded, serialization.msgpack_restore(want))
+
+
+@pytest.mark.parametrize("n", [0, 1, 6, 200, 65500, 65536])
+def test_streamed_array_headers_at_msgpack_size_classes(n):
+    """fixext, ext8/16/32 and bin8/16/32 each where msgpack puts them."""
+    from flax import serialization
+
+    from distributed_machine_learning_tpu.tune.checkpoint import (
+        _PayloadStream,
+    )
+
+    for dtype in (np.int8, np.float32):
+        for size in range(n, n + 40):
+            tree = {"a": np.arange(size).astype(dtype)}
+            got = b"".join(bytes(c) for c in _PayloadStream(tree))
+            assert got == serialization.to_bytes(tree), (dtype, size)
+
+
+def test_save_reports_which_road_a_tree_took(tmp_path):
+    """``streamed`` on ``ckpt.save`` and ``saves_streamed``: a tree with a
+    leaf the streamer does not cover is packed by flax, whole, to the same
+    bytes it always was."""
+    from distributed_machine_learning_tpu.ckpt.metrics import get_metrics
+
+    trees = [({"w": jnp.ones(5), "epoch": 3}, True),
+             ({"w": jnp.ones(5), "blob": b"raw bytes"}, False)]
+
+    def save_both():
+        for i, (tree, streamed) in enumerate(trees):
+            before = get_metrics().snapshot()
+            path = str(tmp_path / f"c{i}.msgpack")
+            save_checkpoint(path, tree)
+            delta = get_metrics().delta_since(before)
+            assert delta["saves"] == 1
+            assert delta["saves_streamed"] == int(streamed)
+            with open(path, "rb") as f:
+                assert f.read() == _parents_bytes(tree)
+            assert load_checkpoint(path)["w"].shape == (5,)
+
+    spans = _spans_of(save_both)
+    saves = [sp for sp in spans if sp["name"] == "ckpt.save"]
+    assert [sp["args"]["streamed"] for sp in saves] == [True, False]
+    assert [sp["args"]["format"] for sp in saves] == ["msgpack", "msgpack"]
+    writes = [sp for sp in spans if sp["name"] == "ckpt.write"]
+    assert [sp["args"]["parent_id"] for sp in writes] == [
+        sp["args"]["span_id"] for sp in saves
+    ]
+    for sp in writes:
+        assert {"bytes", "chunks", "serialize_s"} <= set(sp["args"])
+    # One read a device leaf inside the streamed write, when its turn
+    # comes; on flax's road one read of the whole tree before it is packed.
+    reads = [sp for sp in spans if sp["name"] == "ckpt.device_get"]
+    assert [sp["args"]["parent_id"] for sp in reads] == [
+        sp["args"]["span_id"] for sp in writes
+    ]
+    assert not [sp for sp in spans if sp["name"] == "ckpt.serialize"]
+
+
+def test_failed_chunk_leaves_nothing_and_surfaces_on_wait(tmp_path):
+    """A backend that fails at its n-th chunk: no payload under the final
+    name, no manifest, no temporary file; the writer raises on ``wait``."""
+    from distributed_machine_learning_tpu.tune import storage as storage_lib
+    from distributed_machine_learning_tpu.tune.checkpoint import (
+        AsyncCheckpointWriter,
+    )
+
+    class FailsAtThirdChunk(storage_lib.LocalStorage):
+        def write_chunks(self, path, chunks):
+            def failing():
+                for n, chunk in enumerate(chunks, start=1):
+                    if n == 3:
+                        raise RuntimeError("disk gone at chunk 3")
+                    yield chunk
+
+            return super().write_chunks(path, failing())
+
+    tree = {f"w{i}": np.full(40000, i, np.float32) for i in range(4)}
+    storage_lib.set_fault_wrapper(lambda backend: FailsAtThirdChunk())
+    writer = AsyncCheckpointWriter(log=lambda m: None)
+    try:
+        path = writer.submit(str(tmp_path / "ck" / "c.msgpack"), tree)
+        with pytest.raises(RuntimeError, match="disk gone at chunk 3"):
+            writer.wait(path, timeout=30)
+    finally:
+        storage_lib.set_fault_wrapper(None)
+        writer.close()
+    assert os.listdir(tmp_path / "ck") == []
+    save_checkpoint(path, tree)  # the same path takes a sound save after
+    assert sorted(os.listdir(tmp_path / "ck")) == [
+        "c.msgpack", "c.msgpack.manifest.json"
+    ]
+
+
+def test_retried_streamed_write_hashes_the_pass_that_landed(tmp_path):
+    """A transient fault part-way: the retry layer iterates the stream
+    again from its start, and the manifest is of the bytes on storage."""
+    from distributed_machine_learning_tpu.tune import storage as storage_lib
+
+    attempts = []
+
+    class FlakyOnce(storage_lib.LocalStorage):
+        def write_chunks(self, path, chunks):
+            def flaky():
+                for n, chunk in enumerate(chunks, start=1):
+                    if n == 3 and len(attempts) == 1:
+                        raise OSError("transient")
+                    yield chunk
+
+            attempts.append(path)
+            return super().write_chunks(path, flaky())
+
+    tree = {f"w{i}": np.full(40000, i, np.float32) for i in range(4)}
+    path = str(tmp_path / "c.msgpack")
+    storage_lib.set_fault_wrapper(lambda backend: FlakyOnce())
+    try:
+        save_checkpoint(path, tree)
+    finally:
+        storage_lib.set_fault_wrapper(None)
+    assert attempts == [path, path, path + ".manifest.json"]
+    with open(path, "rb") as f:
+        assert f.read() == _parents_bytes(tree)
+    assert load_checkpoint(path, verify=True)["w3"][0] == 3.0
+
+
+def test_save_lets_go_of_the_tree_without_the_cycle_collector(tmp_path):
+    """A snapshot is a second copy of the state on the device: once it is
+    written, reference counts alone must free it (a planner made of
+    recursive closures, a reference cycle, kept five of them alive on a chip, and the next
+    epoch's program no longer fitted)."""
+    from distributed_machine_learning_tpu.tune.checkpoint import (
+        AsyncCheckpointWriter,
+    )
+
+    tree = {"w": np.ones(70000, np.float32), "d": jnp.ones((300, 300)),
+            "n": {"b": np.ones(3)}}
+    alive = [weakref.ref(x) for x in jax.tree.leaves(tree)]
+    writer = AsyncCheckpointWriter(log=lambda m: None)
+    gc.collect()
+    gc.disable()
+    try:
+        save_checkpoint(str(tmp_path / "sync.msgpack"), tree)
+        del tree
+        assert [ref() for ref in alive] == [None] * 3
+        # ... and the writer's thread does not sit on the last snapshot
+        # while its queue is empty.
+        snapshots = []
+        real = AsyncCheckpointWriter._snapshot_leaf
+
+        def noting(x):
+            snapshots.append(weakref.ref(copy := real(x)))
+            return copy
+
+        writer._snapshot_leaf = noting
+        path = writer.submit(str(tmp_path / "async.msgpack"),
+                             {"w": np.ones(70000, np.float32)})
+        assert writer.wait(path, timeout=30)
+        deadline = time.monotonic() + 10
+        while snapshots[0]() is not None and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert snapshots[0]() is None
+    finally:
+        gc.enable()
+        writer.close()
+
+
+def test_streamed_save_holds_one_leaf_not_the_payload(tmp_path):
+    """The mechanism itself: a 64 MB tree of 8 MB leaves is saved with
+    under two leaves' bytes of new host memory at the peak (packed whole it
+    was the payload and a copy of every leaf: over 128 MB)."""
+    leaf = 8 << 20
+    tree = {f"w{i}": np.full(leaf // 4, i, np.float32) for i in range(8)}
+    path = str(tmp_path / "c.msgpack")
+    save_checkpoint(str(tmp_path / "warm.msgpack"), {"w": np.ones(3)})
+    tracemalloc.start()
+    try:
+        start, _ = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        save_checkpoint(path, tree)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak - start < 2 * leaf, peak - start
+    assert os.path.getsize(path) > 8 * leaf
 
 
 class TestAsyncCheckpointWriter:

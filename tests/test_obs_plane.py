@@ -406,14 +406,23 @@ def test_train_run_spans_on_the_profilers_clock(tmp_results,
         assert _inside(dec, rep)  # the runner decides while the trial waits
         assert rep[3]["iteration"] == dec[3]["iteration"]
     assert [r[3]["iteration"] for r in reports] == [1, 2]
-    for save in saves:
-        assert save[3]["format"] == "msgpack" and save[3]["bytes"] > 0
+    writes = _named(lines, "dml:ckpt.write")
+    reads = _named(lines, "dml:ckpt.device_get")
+    for save, write in zip(saves, writes):
+        assert save[3]["format"] == "msgpack" and save[3]["streamed"]
+        assert _inside(write, save)
+        # the payload goes from the leaves to the file: its bytes are the
+        # write's, and the writer's device leaves are read inside it
+        assert write[3]["bytes"] == save[3]["bytes"] > 0
+        assert write[3]["chunks"] >= 1 and write[3]["serialize_s"] >= 0
+        assert [r for r in reads if _inside(r, write)]
+    assert all(any(_inside(r, w) for w in writes) for r in reads)
+    assert not _named(lines, "dml:ckpt.serialize")
     for name in ("dml:run.setup", "dml:run.teardown", "dml:trial.setup",
                  "dml:trial.build", "dml:trial.init_or_restore"):
         assert len(_named(lines, name)) == 1, name
     for name in ("dml:epoch.dispatch", "dml:epoch.readback",
                  "dml:report.ckpt_snapshot", "dml:report.decide_wait",
-                 "dml:ckpt.device_get", "dml:ckpt.serialize",
                  "dml:ckpt.write", "dml:runner.store_append",
                  "dml:runner.scheduler", "dml:runner.searcher",
                  "dml:runner.callbacks"):

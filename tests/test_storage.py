@@ -55,6 +55,94 @@ def test_local_backend_roundtrip_and_listdir(tmp_path):
     assert backend.read_bytes(path) is None
 
 
+def _chunks():
+    block = np.arange(50000, dtype=np.float32)
+    return [b"head", memoryview(block.view(np.uint8)), b"", bytearray(b"tail")]
+
+
+@pytest.mark.parametrize("scheme", ["local", "mem", "memory"])
+def test_write_chunks_writes_what_write_bytes_would(tmp_path, scheme):
+    """Local files, the in-process fake and an fsspec filesystem
+    (``memory://``), each through ``get_storage``'s retry wrapper: the
+    chunks' concatenation, as ``write_bytes`` of the joined payload."""
+    pytest.importorskip("fsspec")
+    root = {"local": str(tmp_path / "d"), "mem": "mem://chunks",
+            "memory": "memory://chunks"}[scheme]
+    backend, p = get_storage(root + "/a.bin")
+    assert backend.write_chunks(p, _chunks()) == p
+    whole = b"".join(_chunks())
+    assert backend.read_bytes(p) == whole
+    other, q = get_storage(root + "/b.bin")
+    other.write_bytes(q, whole)
+    assert other.read_bytes(q) == backend.read_bytes(p)
+    assert backend.listdir(get_storage(root)[1]) == ["a.bin", "b.bin"]
+
+
+class _Broken(Exception):
+    pass
+
+
+def _breaking_chunks():
+    yield from _chunks()[:2]
+    raise _Broken("the producer failed part-way")
+
+
+def test_write_chunks_is_atomic_on_local(tmp_path):
+    """A producer that fails part-way leaves the old file whole and no
+    temporary file; so does a chunk the file cannot take."""
+    backend = LocalStorage()
+    path = str(tmp_path / "d" / "a.bin")
+    backend.write_bytes(path, b"old")
+    for chunks in (_breaking_chunks(), [b"new", "not bytes"]):
+        with pytest.raises((_Broken, TypeError)):
+            backend.write_chunks(path, chunks)
+        assert backend.read_bytes(path) == b"old"
+        assert backend.listdir(str(tmp_path / "d")) == ["a.bin"]
+
+
+def test_write_chunks_failure_leaves_no_object_on_fsspec():
+    pytest.importorskip("fsspec")
+    backend, p = get_storage("memory://chunks_fail/a.bin")
+    with pytest.raises(_Broken):
+        backend.write_chunks(p, _breaking_chunks())
+    assert not backend.exists(p)
+    assert backend.read_bytes(p) is None
+
+
+def test_write_chunks_default_joins_and_retries_from_the_start():
+    """A backend that knows only ``write_bytes`` gets the joined payload;
+    the retry wrapper iterates the chunks again for each attempt."""
+    from distributed_machine_learning_tpu.tune.storage import (
+        RetryingStorage,
+        RetryPolicy,
+        StorageBackend,
+    )
+
+    class WholePayloads(StorageBackend):
+        def __init__(self):
+            self.calls = []
+
+        def write_bytes(self, path, data):
+            self.calls.append((path, data))
+            if len(self.calls) == 1:
+                raise OSError("transient")
+            return path
+
+    class Chunks:
+        passes = 0
+
+        def __iter__(self):
+            self.passes += 1
+            return iter(_chunks())
+
+    inner, chunks = WholePayloads(), Chunks()
+    backend = RetryingStorage(inner, RetryPolicy(attempts=3, base_delay_s=0))
+    assert backend.write_chunks("x/a.bin", chunks) == "x/a.bin"
+    whole = b"".join(_chunks())
+    assert inner.calls == [("x/a.bin", whole)] * 2
+    assert chunks.passes == 2
+
+
 def test_memory_backend_shared_namespace():
     a, b = MemoryStorage(), MemoryStorage()
     a.write_bytes("mem://exp/t0/ck1", b"x")
