@@ -353,17 +353,12 @@ def phase_cohort(device, *, num_trials, num_epochs, seq_len, features,
                  n_samples, storage):
     """``tune.run`` with the default thread executor: ``num_trials``
     concurrent trial threads all leased the ONE ``device`` (the device list
-    names it once per slot), dispatch serialization off."""
+    names it once per slot)."""
     import numpy as np
 
     from distributed_machine_learning_tpu import tune
     from distributed_machine_learning_tpu.data import dummy_regression_data
-    from distributed_machine_learning_tpu.utils import dispatch
 
-    assert not dispatch.serialization_on(), (
-        "cohort must run with dispatch serialization off "
-        "(unset DML_SERIALIZE_DISPATCH)"
-    )
     train, val = dummy_regression_data(
         num_samples=n_samples, seq_len=seq_len, num_features=features, seed=3
     )

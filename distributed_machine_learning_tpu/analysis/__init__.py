@@ -5,8 +5,8 @@ invariant violation that could have been caught mechanically (ISSUE 6):
 
 * ``dmlint`` (:mod:`engine`, :mod:`rules`, :mod:`findings`): an AST rules
   engine encoding the repo's JAX/concurrency invariants — donation
-  aliasing, unlocked dispatch, chaos determinism, wall-clock deadlines,
-  pickle-free checkpoints, import-time tracing, swallowed thread
+  aliasing, chaos determinism, wall-clock deadlines, pickle-free
+  checkpoints, import-time tracing, swallowed thread
   exceptions.  Since v2 (ISSUE 11) the engine is whole-project: every
   file parses once into a shared context, and cross-file rules reason
   over a symbol table + call graph (:mod:`callgraph`) and an

@@ -106,7 +106,7 @@ def eqn_line(eqn, within: str) -> Optional[Tuple[str, int]]:
     try:
         from jax._src import source_info_util
 
-        frames = source_info_util.user_frames(eqn.source_info)
+        frames = source_info_util.user_frames(eqn.source_info.traceback)
     except Exception:  # noqa: BLE001 - traceback APIs are private/fluid
         return None
     within = os.path.abspath(within)

@@ -7,9 +7,6 @@ with the war stories in docs/static-analysis.md):
   optimizer counts: ``np.asarray`` on a CPU-backed ``jax.Array`` aliases
   the device buffer, and a donated buffer is overwritten in place by the
   next step.
-* DML002 ``unlocked-dispatch`` — a module that opted into dispatch
-  serialization must make every device call inside ``dispatch_lock``
-  (utils/dispatch.py), or the lock serializes nothing.
 * DML003 ``chaos-determinism`` — PR 3 shipped two flaky tests because
   fault decisions hashed run-varying absolute paths; a fault plan that
   consults wall time, PIDs, or ``random`` is a flake generator.
@@ -26,9 +23,9 @@ with the war stories in docs/static-analysis.md):
   class the whole liveness layer exists to catch.
 
 Rules are deliberately project-native: they encode THIS repo's idioms
-(``dispatch_lock`` with-blocks, ``_is_jax_array`` guards, FaultPlan
-decision methods) rather than generic lint heuristics, which is what keeps
-the false-positive rate at zero on the gate (tests/test_analysis.py).
+(``_is_jax_array`` guards, FaultPlan decision methods) rather than generic
+lint heuristics, which is what keeps the false-positive rate at zero on
+the gate (tests/test_analysis.py).
 """
 
 from __future__ import annotations
@@ -306,111 +303,6 @@ class DonationAliasRule(Rule):
 
 
 # --------------------------------------------------------------------------
-# DML002 unlocked-dispatch
-# --------------------------------------------------------------------------
-
-
-_DISPATCH_PREFIXES = ("jnp.", "jax.numpy.", "jax.random.")
-_DISPATCH_EXACT = {
-    "jax.device_put", "jax.device_get", "jax.block_until_ready",
-}
-_SCHEDULE_BUILDER = re.compile(r"^(get_|make_|resolve_|register_)")
-
-
-class UnlockedDispatchRule(Rule):
-    name = "unlocked-dispatch"
-    rule_id = "DML002"
-    severity = "error"
-    description = (
-        "Device dispatch (jnp ops, jax.random key creation, schedule "
-        "evaluation, calling a jitted program) in a module that opted into "
-        "dispatch serialization must happen inside `with dispatch_lock():` "
-        "— one call outside the lock and the serialization the module "
-        "asked for no longer holds (utils/dispatch.py)."
-    )
-    _HINT = "move the call inside a `with dispatch_lock():` block"
-
-    def applies(self, ctx) -> bool:
-        if "dispatch-serialized" in ctx.scopes:
-            return True
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.ImportFrom):
-                if any(a.name == "dispatch_lock" for a in node.names):
-                    return True
-        return False
-
-    def check(self, ctx) -> Iterator[Finding]:
-        for node in ctx.tree.body:
-            yield from self._visit(node, in_function=False, lock_depth=0,
-                                   ctx=ctx)
-
-    def _is_lock_with(self, node: ast.With) -> bool:
-        for item in node.items:
-            expr = item.context_expr
-            if isinstance(expr, ast.Call):
-                callee = _call_name(expr) or ""
-                if callee.rsplit(".", 1)[-1] == "dispatch_lock":
-                    return True
-        return False
-
-    def _dispatchy(self, node: ast.Call) -> Optional[str]:
-        # jax.jit(...)(...) — compiling AND calling in one expression (the
-        # callee is itself a Call, so check before the dotted-name paths).
-        if isinstance(node.func, ast.Call):
-            inner = _call_name(node.func) or ""
-            if inner in ("jax.jit", "jit", "pjit", "jax.pjit"):
-                return f"{inner}(...)(...)"
-        callee = _call_name(node)
-        if callee is None:
-            return None
-        if callee.startswith(_DISPATCH_PREFIXES) or callee in _DISPATCH_EXACT:
-            return callee
-        # Schedule evaluation: optax schedules are jnp-backed, so calling
-        # one IS a (small) device dispatch.  Builders (get_/make_*) only
-        # construct the closure and stay host-side.
-        if (
-            isinstance(node.func, ast.Name)
-            and "schedule" in node.func.id
-            and not _SCHEDULE_BUILDER.match(node.func.id)
-        ):
-            return node.func.id
-        return None
-
-    def _visit(self, node: ast.AST, in_function: bool, lock_depth: int,
-               ctx) -> Iterator[Finding]:
-        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            if in_function:
-                # Nested defs are this codebase's traced-closure idiom
-                # (epoch fns, schedule shapes): their jnp ops run under
-                # jit tracing, not as eager dispatches.
-                return
-            for stmt in node.body:
-                yield from self._visit(stmt, True, lock_depth, ctx)
-            return
-        if isinstance(node, ast.Lambda):
-            return  # lambdas here are jit payloads
-        if isinstance(node, ast.With):
-            depth = lock_depth + (1 if self._is_lock_with(node) else 0)
-            for item in node.items:
-                yield from self._visit(item.context_expr, in_function,
-                                       lock_depth, ctx)
-            for stmt in node.body:
-                yield from self._visit(stmt, in_function, depth, ctx)
-            return
-        if isinstance(node, ast.Call) and in_function and lock_depth == 0:
-            what = self._dispatchy(node)
-            if what:
-                yield self.finding(
-                    ctx, node,
-                    f"device dispatch `{what}` outside dispatch_lock() in a "
-                    f"serialized-dispatch module",
-                    self._HINT,
-                )
-        for child in ast.iter_child_nodes(node):
-            yield from self._visit(child, in_function, lock_depth, ctx)
-
-
-# --------------------------------------------------------------------------
 # DML003 chaos-determinism
 # --------------------------------------------------------------------------
 
@@ -642,6 +534,7 @@ class PickleCheckpointRule(Rule):
 # --------------------------------------------------------------------------
 
 
+_DISPATCH_PREFIXES = ("jnp.", "jax.numpy.", "jax.random.")
 _IMPORT_TRACE_EXACT = {
     "jax.device_put", "jax.device_get", "jax.devices",
     "jax.local_devices", "jax.eval_shape", "jax.make_jaxpr",
@@ -1076,7 +969,7 @@ class UnboundedQueueRule(Rule):
 
 # Vectorized hot-loop modules: anything whose scan bodies carry
 # population-stacked state (the fused epoch scans, the PBT generation
-# scan, the sharded fused epoch program).  Opt-in like DML002/DML008.
+# scan, the sharded fused epoch program).  Opt-in like DML008.
 VECTORIZED_HOT_LOOP_PATTERNS = (
     "tune/vectorized.py",
     "tune/_regression_program.py",
@@ -1177,7 +1070,7 @@ class HostSyncInScanRule(Rule):
 
 
 # Hot input-path modules: anywhere an epoch/step loop moves training bytes
-# host->device.  Opt-in like DML002/DML008/DML010.  tune/vectorized.py is
+# host->device.  Opt-in like DML008/DML010.  tune/vectorized.py is
 # deliberately absent: its in-loop transfers are dispatch-BOUNDARY control
 # ops (row selectors, per-row lr/wd vectors, population re-pins after a
 # compaction), a few KB between whole-population programs — not per-batch
@@ -3007,7 +2900,6 @@ class RawHashedWriteOutsideStoreRule(Rule):
 
 ALL_RULES: List[Rule] = [
     DonationAliasRule(),
-    UnlockedDispatchRule(),
     ChaosDeterminismRule(),
     WallclockDeadlineRule(),
     PickleCheckpointRule(),

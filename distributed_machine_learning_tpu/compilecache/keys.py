@@ -30,7 +30,7 @@ NON_STRUCTURAL_KEYS = frozenset({"learning_rate", "weight_decay", "seed"})
 
 # Driver-level knobs that select HOW a program is built/cached but never
 # appear in the traced computation itself.
-_DRIVER_KEYS = frozenset({"share_programs", "checkpoint_freq"})
+_DRIVER_KEYS = frozenset({"checkpoint_freq"})
 
 
 def _canonical(value: Any) -> Any:

@@ -32,7 +32,6 @@ from distributed_machine_learning_tpu.compilecache import (
     program_key,
 )
 from distributed_machine_learning_tpu.serve.export import ServableBundle
-from distributed_machine_learning_tpu.utils.dispatch import dispatch_lock
 
 DEFAULT_MAX_BUCKET = 1024
 
@@ -51,9 +50,7 @@ def bucket_sizes(max_bucket: int = DEFAULT_MAX_BUCKET) -> Tuple[int, ...]:
 class InferenceEngine:
     """Compiled forward pass over a bundle's params, bucketed by batch size.
 
-    Thread-safe: the program cache is lock-guarded and jit dispatch runs
-    under ``dispatch_lock()`` (the optional dispatch serialization the
-    trainables use, utils/dispatch.py; a no-op unless it is switched on).
+    Thread-safe: the program cache is lock-guarded.
     """
 
     def __init__(
@@ -303,7 +300,7 @@ class InferenceEngine:
         key = (bucket, x.shape[1:], str(x.dtype))
         if self._mesh is not None:
             return self._run_bucket_mesh(key, x)[:n]
-        with obs.span("engine.step", {"bucket": bucket}), dispatch_lock():
+        with obs.span("engine.step", {"bucket": bucket}):
             ctx = (
                 jax.default_device(self._device)
                 if self._device is not None
@@ -315,7 +312,7 @@ class InferenceEngine:
                 # the pinned device (thread-local jax config).
                 prog = self._program(key, x)
                 out = prog(self._variables, x)
-            out = np.asarray(out)  # readback inside the hold (sync point)
+            out = np.asarray(out)  # readback inside the span (sync point)
         return out[:n]
 
     def _run_bucket_mesh(self, key: Tuple, x: np.ndarray) -> np.ndarray:
@@ -333,7 +330,7 @@ class InferenceEngine:
         )
 
         bucket = key[0]
-        with obs.span("engine.step", {"bucket": bucket}), dispatch_lock():
+        with obs.span("engine.step", {"bucket": bucket}):
             staged = _runtime.stage_global(
                 x, NamedSharding(self._mesh, PartitionSpec())
             )

@@ -37,9 +37,9 @@ seed, but different impls produce different trajectories).
 
 from __future__ import annotations
 
-import threading
+import time
 from collections import namedtuple
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +68,6 @@ from distributed_machine_learning_tpu.tune._regression_program import (
     make_eval_fn,
     make_forward,
     make_token_eval_fn,
-    per_example_losses,
     stage_data,
 )
 from distributed_machine_learning_tpu.perf.costmodel import (
@@ -76,18 +75,297 @@ from distributed_machine_learning_tpu.perf.costmodel import (
 )
 from distributed_machine_learning_tpu.tune.checkpoint import restore_into
 from distributed_machine_learning_tpu.utils.compile_cache import get_tracker
-from distributed_machine_learning_tpu.utils.dispatch import (
-    dispatch_lock,
-    serialization_on,
-)
 from distributed_machine_learning_tpu.utils.seeding import (
     fold_seed,
     init_rngs_for,
 )
 
-# Back-compat aliases (vectorized.py and external users imported these names).
-_detect_call_convention = detect_call_convention
-_per_example_losses = per_example_losses
+
+# ---------------------------------------------------------------------------
+# What every per-trial trainable decides the same way: the resident and
+# streaming loops below and ``tune/trainable_sharded.py`` all call these.
+
+
+class TrialSettings(NamedTuple):
+    """The config keys a trial's set-up and epoch loop read, read once."""
+
+    num_epochs: int
+    seed: int
+    loss_name: str
+    accum: int
+    lr: float
+    wd: float
+    opt_name: str
+    lr_schedule: str
+    warmup_steps: int
+    momentum: float
+    gradient_clipping: float
+    checkpoint_freq: int
+    injected: bool
+    config_total_steps: Optional[int]
+
+    def opt_steps(self, steps_per_epoch: int, epochs: int = 1) -> int:
+        # The schedule advances once per OPTIMIZER step; with accumulation
+        # that is steps_per_epoch // accum per epoch, not per micro-batch.
+        return epochs * max(steps_per_epoch // self.accum, 1)
+
+    def schedule_steps(self, steps_per_epoch: int) -> int:
+        if self.config_total_steps is not None:
+            return max(self.config_total_steps, 1)
+        return max(self.opt_steps(steps_per_epoch, self.num_epochs), 1)
+
+    def checkpoint_due(self, epoch: int) -> bool:
+        return bool(self.checkpoint_freq) and (
+            (epoch + 1) % self.checkpoint_freq == 0
+        )
+
+
+def trial_settings(config: Dict[str, Any]) -> TrialSettings:
+    accum = max(int(config.get("accumulate_grad_batches", 1)), 1)
+    opt_name = str(config.get("optimizer", "adam")).lower()
+    total_steps = config.get("total_steps")
+    return TrialSettings(
+        num_epochs=int(config.get("num_epochs", 20)),
+        seed=int(config.get("seed", 0)),
+        loss_name=str(config.get("loss_function", "mse")),
+        accum=accum,
+        lr=float(config["learning_rate"]),
+        wd=float(config.get("weight_decay", 0.0)),
+        opt_name=opt_name,
+        lr_schedule=str(config.get("lr_schedule", "warmup_linear_decay")),
+        warmup_steps=int(config.get("warmup_steps", 0)),
+        momentum=float(config.get("momentum", 0.0)),
+        gradient_clipping=float(config.get("gradient_clipping", 0.0)),
+        checkpoint_freq=int(config.get("checkpoint_freq", 1)),
+        # lr/wd as optimizer STATE, not baked HLO constants, whenever the
+        # optimizer supports it: every same-architecture trial then traces
+        # to IDENTICAL HLO and the persistent XLA cache serves ONE backend
+        # compile to the whole cohort (per-trial compiles otherwise
+        # dominate multi-trial thread-executor runs).  The baked path
+        # remains for the optimizers whose chains can't inject (lamb,
+        # adafactor, ...) and for gradient accumulation (MultiSteps wraps
+        # the hyperparam slots); config["inject_hyperparams"]=False forces
+        # it.
+        injected=(
+            opt_name in INJECTABLE_OPTIMIZERS
+            and accum == 1
+            and bool(config.get("inject_hyperparams", True))
+        ),
+        config_total_steps=(
+            None if total_steps is None else int(total_steps)
+        ),
+    )
+
+
+def build_optimizer(s: TrialSettings, total_steps: int, injected: bool):
+    """``(tx, shape_schedule)``: the optimizer chain, injected or baked,
+    and the schedule at peak 1.0.  Every registered schedule is linear in
+    ``learning_rate``, so ``lr * shape_schedule(step)`` is the effective
+    rate on both paths."""
+
+    def schedule(peak):
+        return get_schedule(
+            s.lr_schedule,
+            learning_rate=peak,
+            warmup_steps=s.warmup_steps,
+            total_steps=total_steps,
+        )
+
+    shape_schedule = schedule(1.0)
+    if injected:
+        tx = make_injected_optimizer(
+            s.opt_name,
+            shape_schedule,
+            momentum=s.momentum,
+            gradient_clipping=s.gradient_clipping,
+        )
+    else:
+        tx = make_optimizer(
+            s.opt_name,
+            learning_rate=schedule(s.lr),
+            weight_decay=s.wd,
+            momentum=s.momentum,
+            gradient_clipping=s.gradient_clipping,
+            accumulate_grad_batches=s.accum,
+        )
+    return tx, shape_schedule
+
+
+def lr_after_epoch(s, shape_schedule, total_steps, steps_per_epoch, epoch):
+    """The rate the optimizer used at ``epoch``'s last step.  Optax
+    schedules are jnp-based: evaluating one IS a (small) device dispatch."""
+    opt_steps = s.opt_steps(steps_per_epoch, epoch + 1)
+    return s.lr * float(shape_schedule(min(opt_steps, total_steps)))
+
+
+def epoch_perf_accounting(
+    config, x_shape, *, batch_size, steps_per_epoch, eval_rows, device,
+    **program,
+):
+    """Per-epoch MFU accounting (perf/costmodel.py), attributed to THIS
+    trial for the step-stream anomaly detector (straggler naming in
+    sweeps).  ``program``: the sharded trainable's device count and AOT
+    program key."""
+    return EpochPerfAccounting(
+        config,
+        batch_size=batch_size,
+        seq_len=int(x_shape[1]) if len(x_shape) == 3 else 1,
+        features=int(x_shape[-1]),
+        steps_per_epoch=steps_per_epoch,
+        eval_rows=eval_rows,
+        device=device,
+        trial_id=session.current_trial_id(),
+        **program,
+    )
+
+
+def epoch_record(
+    perf_acct, device, epoch, steps_per_epoch, train_loss, lr_now, metrics,
+    exec_s, *, observe_s=None, **extra,
+):
+    """One epoch's result line: the loop's readings, ``extra`` (what names
+    the path: mesh, input mode), the metrics, the perf annotation."""
+    record = {
+        "epoch": epoch,
+        "train_loss": train_loss,
+        "lr": lr_now,
+        "steps": (epoch + 1) * steps_per_epoch,
+        **extra,
+        **metrics,
+    }
+    perf_acct.annotate(record, exec_s, device=device, observe_s=observe_s)
+    if "moe_local_pairs" in metrics:
+        # An expert layer's routing counts (make_token_eval_fn): the
+        # mean ratio is load_max_over_mean_sum over reports.
+        registry = obs.get_registry()
+        registry.add("moe.local_pairs", metrics["moe_local_pairs"])
+        registry.add("moe.load_max_over_mean_sum",
+                     metrics["moe_load_max_over_mean"])
+        registry.add("moe.reports")
+    return record
+
+
+def _epoch_checkpoint(s, epoch, params, opt_state, batch_stats, rng_impl):
+    """The state to save with ``epoch``'s report, device-held (the async
+    writer's read-back overlaps the next epoch), or None when none is due."""
+    if not s.checkpoint_due(epoch):
+        return None
+    return {
+        "params": params,
+        "opt_state": opt_state,
+        "batch_stats": batch_stats,
+        "epoch": epoch,
+        # Stream family the trial's epochs were drawn from; a restore on
+        # another backend must keep it (_init_or_restore).  Extra key:
+        # older restore templates ignore it.
+        "rng_impl": rng_impl or "",
+    }
+
+
+_ModelPrograms = namedtuple("_ModelPrograms", [
+    "model", "flag_name", "has_bn", "init_model", "forward", "evaluate",
+])
+
+
+def _model_programs(config, loss_name, probe_x, n_val_blocks, eval_bs,
+                    abstract=False) -> _ModelPrograms:
+    """The model and the programs that depend on it alone.  ``probe_x``: a
+    concrete row, or with ``abstract`` a ShapeDtypeStruct (flag kwarg + BN
+    detection with NOTHING allocated: an over-budget dataset often rides
+    with a big model too)."""
+    model = build_model(config)
+    # Convention probe (fixed rng, discarded): learns the train-flag
+    # kwarg and whether the family carries batch stats.
+    probe, flag_name = detect_call_convention(
+        model, probe_x, abstract=abstract
+    )
+    has_bn = "batch_stats" in probe
+    init_kwargs = {flag_name: True if flag_name == "deterministic" else False}
+    # Per-trial init diversity rides through the rng ARGUMENT (the
+    # reference's torch trials each start from their own random init):
+    # one compiled init program serves every seed.
+    init_model = jax.jit(lambda rngs, x: model.init(rngs, x, **init_kwargs))
+    forward = make_forward(model, flag_name, has_bn)
+    evaluate = jax.jit(
+        make_token_eval_fn(model, flag_name, n_val_blocks, eval_bs)
+        if loss_name in TOKEN_LOSSES
+        else make_eval_fn(forward, loss_name, n_val_blocks, eval_bs)
+    )
+    return _ModelPrograms(
+        model=model, flag_name=flag_name, has_bn=has_bn,
+        init_model=init_model, forward=forward, evaluate=evaluate,
+    )
+
+
+def _init_or_restore(bundle, s, config, sample_x, ckpt):
+    """The trial's starting state: a fresh init, and over it the checkpoint
+    (PBT exploit / fault retry) when there is one.
+
+    Returns ``(params, opt_state, batch_stats, start_epoch, rng_impl, tx)``.
+    ``tx`` is ``bundle.tx`` unless the checkpoint holds the baked optimizer
+    layout: the caller then rebuilds its train program over the returned
+    chain.
+    """
+    variables = bundle.init_model(init_rngs_for(s.seed), sample_x)
+    params = variables["params"]
+    batch_stats = variables.get("batch_stats", {})
+    opt_state = bundle.init_opt(params)
+    if s.injected:
+        opt_state = set_injected_hyperparams(opt_state, s.lr, s.wd)
+    # Dropout PRNG implementation (ops/rng.py): defaults to the hardware
+    # RNG on TPU — threefry key derivation measurably dominates small-shape
+    # sweeps there — threefry elsewhere; rng_impl="threefry"/"rbg"
+    # overrides.  The resolved impl is recorded in every checkpoint and a
+    # restore REUSES the recorded one, so a trial restored on a different
+    # backend keeps the stream family its earlier epochs were drawn from
+    # instead of silently mixing trajectories ("" = jax default).
+    rng_impl = resolve_rng_impl(config)
+    if ckpt is None:
+        return params, opt_state, batch_stats, 0, rng_impl, bundle.tx
+    saved_impl = ckpt.get("rng_impl") if isinstance(ckpt, dict) else None
+    if saved_impl is not None:
+        rng_impl = saved_impl or None
+    else:
+        # Legacy checkpoint (predates impl recording): its epochs were
+        # drawn under the RAW config value (no auto-resolution then),
+        # so continue with exactly that — resolving anew could switch
+        # stream families mid-trial (same fallback as vectorized.py).
+        rng_impl = config.get("rng_impl") or None
+    template = {
+        "params": params,
+        "opt_state": opt_state,
+        "batch_stats": batch_stats,
+        "epoch": 0,
+    }
+    tx, injected = bundle.tx, s.injected
+    try:
+        restored = restore_into(template, ckpt)
+    except (ValueError, KeyError, TypeError, AttributeError):
+        if not injected:
+            raise
+        # Legacy checkpoint: written by the pre-injection (baked)
+        # optimizer layout — its opt_state pytree does not match the
+        # InjectHyperparamsState template.  Fall back to the baked chain
+        # for THIS incarnation so old experiments stay resumable (the next
+        # fresh trial uses injection again).  Only the optimizer chain
+        # (and the train program that closes over it) differ from the
+        # cached bundle: its staged data, forward, init and eval programs
+        # are reused.
+        injected = False
+        tx, _ = build_optimizer(s, bundle.total_steps, False)
+        template["opt_state"] = jax.jit(tx.init)(params)
+        restored = restore_into(template, ckpt)
+    opt_state = restored["opt_state"]
+    if injected:
+        # PBT exploit copies a PEER's optimizer state and explore rewrites
+        # config lr/wd — this trial's config values must win over whatever
+        # rode in the restored hyperparam slots (the baked path achieves
+        # the same by rebuilding the schedule from config).
+        opt_state = set_injected_hyperparams(opt_state, s.lr, s.wd)
+    return (
+        restored["params"], opt_state, restored["batch_stats"],
+        int(restored["epoch"]) + 1, rng_impl, tx,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -177,13 +455,7 @@ def _cohort_bundle_for(config, train_data, val_data, device, build):
             bundle = _COHORT_CACHE.get(key)
             if bundle is not None:
                 return bundle
-        # The build stages data and compiles through the backend; in a
-        # MIXED-architecture cohort it can otherwise overlap another
-        # architecture's epoch dispatches (utils/dispatch.py; ordering
-        # is always cohort lock -> dispatch lock, never the reverse, so
-        # no cycle with the epoch path which takes only dispatch_lock).
-        with dispatch_lock():
-            bundle = build()
+        bundle = build()
         with _COHORT_GUARD:
             _COHORT_CACHE[key] = bundle
             while len(_COHORT_CACHE) > 1 and (
@@ -206,9 +478,6 @@ def train_regressor(
     if train_data is None or val_data is None:
         raise ValueError("train_regressor needs train_data/val_data bound")
 
-    num_epochs = int(config.get("num_epochs", 20))
-    seed = int(config.get("seed", 0))
-    loss_name = str(config.get("loss_function", "mse"))
     # One resolver for both the staged-input dtype and (inside build_model)
     # the model's matmul dtype — they must agree or mixed precision is a lie.
     from distributed_machine_learning_tpu.models import compute_dtype_of
@@ -229,346 +498,120 @@ def train_regressor(
         hostpipe.staged_nbytes(train_data, val_data, compute_dtype),
         device,
     )
+    s = trial_settings(config)
     if input_mode == "streaming":
         return _train_regressor_streaming(
-            config, train_data, val_data, device, compute_dtype
+            config, s, train_data, val_data, device, compute_dtype
         )
 
     # Hand-ended before the epoch loop (everything up to there is set-up);
     # a set-up that raises drops the span with the trial.
     setup_span = obs.span("trial.setup")
-    accum = max(int(config.get("accumulate_grad_batches", 1)), 1)
-    lr = float(config["learning_rate"])
-    wd = float(config.get("weight_decay", 0.0))
-    opt_name = str(config.get("optimizer", "adam")).lower()
-    # lr/wd as optimizer STATE, not baked HLO constants, whenever the
-    # optimizer supports it: every same-architecture trial then traces to
-    # IDENTICAL HLO and the persistent XLA cache serves ONE backend
-    # compile to the whole cohort (per-trial compiles otherwise dominate
-    # multi-trial thread-executor runs).  The legacy baked path
-    # remains for the optimizers whose chains can't inject (lamb,
-    # adafactor, ...) and for gradient accumulation (MultiSteps wraps the
-    # hyperparam slots); config["inject_hyperparams"]=False forces it.
-    injected = (
-        opt_name in INJECTABLE_OPTIMIZERS
-        and accum == 1
-        and bool(config.get("inject_hyperparams", True))
-    )
 
-    def _build_bundle(use_injected) -> _CohortBundle:
+    def _build_bundle() -> _CohortBundle:
         with obs.span("trial.stage_data"):
             data = stage_data(
                 train_data, val_data, int(config.get("batch_size", 32)),
                 compute_dtype,
             )
-        steps_per_epoch = data.num_batches
-        # The schedule advances once per OPTIMIZER step; with accumulation
-        # that is steps_per_epoch // accum per epoch, not per micro-batch.
-        total_steps = max(int(config.get(
-            "total_steps", num_epochs * max(steps_per_epoch // accum, 1)
-        )), 1)
-        shape_schedule = get_schedule(
-            str(config.get("lr_schedule", "warmup_linear_decay")),
-            learning_rate=1.0,
-            warmup_steps=int(config.get("warmup_steps", 0)),
-            total_steps=total_steps,
+        total_steps = s.schedule_steps(data.num_batches)
+        tx, shape_schedule = build_optimizer(s, total_steps, s.injected)
+        programs = _model_programs(
+            config, s.loss_name, data.x_train[:1], data.n_val_blocks,
+            data.eval_bs,
         )
-        if use_injected:
-            tx = make_injected_optimizer(
-                opt_name,
-                shape_schedule,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(
-                    config.get("gradient_clipping", 0.0)
-                ),
-            )
-        else:
-            tx = make_optimizer(
-                opt_name,
-                learning_rate=get_schedule(
-                    str(config.get("lr_schedule", "warmup_linear_decay")),
-                    learning_rate=lr,
-                    warmup_steps=int(config.get("warmup_steps", 0)),
-                    total_steps=total_steps,
-                ),
-                weight_decay=wd,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(
-                    config.get("gradient_clipping", 0.0)
-                ),
-                accumulate_grad_batches=accum,
-            )
-        model = build_model(config)
-        # Convention probe (fixed rng, discarded): learns the train-flag
-        # kwarg and whether the family carries batch stats.
-        probe, flag_name = detect_call_convention(model, data.x_train[:1])
-        has_bn = "batch_stats" in probe
-        init_kwargs = {
-            flag_name: True if flag_name == "deterministic" else False
-        }
-        # Per-trial init diversity rides through the rng ARGUMENT (the
-        # reference's torch trials each start from their own random
-        # init): one compiled init program serves every seed.
-        init_model = jax.jit(
-            lambda rngs, x: model.init(rngs, x, **init_kwargs)
-        )
-        forward = make_forward(model, flag_name, has_bn)
         train_epoch = jax.jit(
             make_epoch_fn(
-                forward, tx, get_loss(loss_name),
+                programs.forward, tx, get_loss(s.loss_name),
                 data.n_train, data.num_batches, data.batch_size,
             ),
             donate_argnums=(0, 1, 2),
         )
-        evaluate = jax.jit(
-            make_token_eval_fn(
-                model, flag_name, data.n_val_blocks, data.eval_bs
-            )
-            if loss_name in TOKEN_LOSSES
-            else make_eval_fn(
-                forward, loss_name, data.n_val_blocks, data.eval_bs
-            )
-        )
         return _CohortBundle(
-            data=data, model=model, flag_name=flag_name, has_bn=has_bn,
-            forward=forward, tx=tx, init_model=init_model,
-            init_opt=jax.jit(tx.init), train_epoch=train_epoch,
-            evaluate=evaluate, shape_schedule=shape_schedule,
-            steps_per_epoch=steps_per_epoch, total_steps=total_steps,
+            data=data, tx=tx, init_opt=jax.jit(tx.init),
+            train_epoch=train_epoch, shape_schedule=shape_schedule,
+            steps_per_epoch=data.num_batches, total_steps=total_steps,
+            **programs._asdict(),
         )
 
     # Model, optimizer and program lookup; staging is its child on a miss.
     with obs.span("trial.build"):
-        if injected and bool(config.get("share_programs", True)):
+        if s.injected:
             # Everything in the bundle is trial-independent under
             # injection: one build serves the whole cohort (and the
             # per-key lock makes the cohort's first backend compile
             # exactly-once in-process).
             bundle = _cohort_bundle_for(
-                config, train_data, val_data, device,
-                lambda: _build_bundle(True),
+                config, train_data, val_data, device, _build_bundle
             )
         else:
-            with dispatch_lock():
-                bundle = _build_bundle(injected)
+            bundle = _build_bundle()
     data = bundle.data
     steps_per_epoch = bundle.steps_per_epoch
-    total_steps = bundle.total_steps
-    shape_schedule = bundle.shape_schedule
-    tx = bundle.tx
+
+    with obs.span("trial.init_or_restore"):
+        params, opt_state, batch_stats, start_epoch, rng_impl, tx = (
+            _init_or_restore(
+                bundle, s, config, data.x_train[:1], session.get_checkpoint()
+            )
+        )
     train_epoch = bundle.train_epoch
-    evaluate = bundle.evaluate
+    if tx is not bundle.tx:
+        train_epoch = jax.jit(
+            make_epoch_fn(
+                bundle.forward, tx, get_loss(s.loss_name),
+                data.n_train, data.num_batches, data.batch_size,
+            ),
+            donate_argnums=(0, 1, 2),
+        )
 
-    init_span = obs.span("trial.init_or_restore")  # ends with the restore
-    # Device-call section: serialized across concurrent trial threads
-    # when DML_SERIALIZE_DISPATCH is on (utils/dispatch.py; off by default).
-    with dispatch_lock():
-        variables = bundle.init_model(init_rngs_for(seed), data.x_train[:1])
-        params = variables["params"]
-        batch_stats = variables.get("batch_stats", {})
-        opt_state = bundle.init_opt(params)
-        if injected:
-            opt_state = set_injected_hyperparams(opt_state, lr, wd)
-
-    # ---- restore (PBT exploit / fault retry) -------------------------------
-    # Dropout PRNG implementation (ops/rng.py): defaults to the hardware
-    # RNG on TPU — threefry key derivation measurably dominates small-shape
-    # sweeps there — threefry elsewhere; rng_impl="threefry"/"rbg"
-    # overrides.  The resolved impl is recorded in every checkpoint and a
-    # restore REUSES the recorded one, so a trial restored on a different
-    # backend keeps the stream family its earlier epochs were drawn from
-    # instead of silently mixing trajectories ("" = jax default).
-    rng_impl = resolve_rng_impl(config)
-    start_epoch = 0
-    ckpt = session.get_checkpoint()
-    if ckpt is not None:
-        saved_impl = ckpt.get("rng_impl") if isinstance(ckpt, dict) else None
-        if saved_impl is not None:
-            rng_impl = saved_impl or None
-        else:
-            # Legacy checkpoint (predates impl recording): its epochs were
-            # drawn under the RAW config value (no auto-resolution then),
-            # so continue with exactly that — resolving anew could switch
-            # stream families mid-trial (same fallback as vectorized.py).
-            rng_impl = config.get("rng_impl") or None
-        template = {
-            "params": params,
-            "opt_state": opt_state,
-            "batch_stats": batch_stats,
-            "epoch": 0,
-        }
-        # One hold for the whole restore (including the legacy-layout
-        # fallback's jit(tx.init) dispatch and retry): same coverage as
-        # the sharded twin.
-        with dispatch_lock():
-          try:
-            restored = restore_into(template, ckpt)
-          except (ValueError, KeyError, TypeError, AttributeError):
-            if not injected:
-                raise
-            # Legacy checkpoint: written by the pre-injection (baked)
-            # optimizer layout — its opt_state pytree does not match the
-            # InjectHyperparamsState template.  Fall back to the baked
-            # chain for THIS incarnation so old experiments stay
-            # resumable (the next fresh trial uses injection again).
-            injected = False
-            # Only the optimizer chain (and the epoch program that closes
-            # over it) differ from the cached bundle — reuse its staged
-            # data, forward, init, and eval programs instead of paying a
-            # second stage + compile set (review r5).
-            tx = make_optimizer(
-                opt_name,
-                learning_rate=get_schedule(
-                    str(config.get("lr_schedule", "warmup_linear_decay")),
-                    learning_rate=lr,
-                    warmup_steps=int(config.get("warmup_steps", 0)),
-                    total_steps=total_steps,
-                ),
-                weight_decay=wd,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(
-                    config.get("gradient_clipping", 0.0)
-                ),
-                accumulate_grad_batches=accum,
-            )
-            train_epoch = jax.jit(
-                make_epoch_fn(
-                    bundle.forward, tx, get_loss(loss_name),
-                    data.n_train, data.num_batches, data.batch_size,
-                ),
-                donate_argnums=(0, 1, 2),
-            )
-            opt_state = jax.jit(tx.init)(params)
-            template["opt_state"] = opt_state
-            restored = restore_into(template, ckpt)
-        params = restored["params"]
-        opt_state = restored["opt_state"]
-        batch_stats = restored["batch_stats"]
-        start_epoch = int(restored["epoch"]) + 1
-        if injected:
-            # PBT exploit copies a PEER's optimizer state and explore
-            # rewrites config lr/wd — this trial's config values must win
-            # over whatever rode in the restored hyperparam slots (the
-            # baked path achieved the same by rebuilding the schedule
-            # from config).
-            with dispatch_lock():
-                opt_state = set_injected_hyperparams(opt_state, lr, wd)
-    init_span.end()
-
-    checkpoint_freq = int(config.get("checkpoint_freq", 1))
-
-    # ---- per-epoch MFU accounting (BASELINE.md utilization target) ---------
-    # One perf-owned derivation for every trainable (perf/costmodel.py):
-    # flops/peak/MFU keys stay byte-compatible with the block this
-    # replaced, and each epoch's timing feeds the step-stream anomaly
-    # detector attributed to THIS trial (straggler naming in sweeps).
-    x_shape = data.x_train.shape
-    seq_len = int(x_shape[1]) if len(x_shape) == 3 else 1
-    feats = int(x_shape[-1])
-    perf_acct = EpochPerfAccounting(
-        config,
-        batch_size=data.batch_size,
-        seq_len=seq_len,
-        features=feats,
-        steps_per_epoch=steps_per_epoch,
-        eval_rows=int(data.x_val.shape[0]),
+    perf_acct = epoch_perf_accounting(
+        config, data.x_train.shape, batch_size=data.batch_size,
+        steps_per_epoch=steps_per_epoch, eval_rows=int(data.x_val.shape[0]),
         device=device,
-        trial_id=session.current_trial_id(),
     )
     tracker = get_tracker()
     setup_span.end()
 
-    import time as _time
-
     # ---- epoch loop: host-driven so the scheduler can interrupt ------------
-    for epoch in range(start_epoch, num_epochs):
-        step_count = (epoch + 1) * steps_per_epoch
-        # The schedule is indexed by OPTIMIZER steps; with accumulation
-        # that is micro-steps // accum, or the logged lr would decay
-        # ``accum`` times faster than the one the optimizer actually used.
-        opt_steps = (epoch + 1) * max(steps_per_epoch // accum, 1)
-        # One lock hold per epoch (train + eval): the chip runs one
-        # program at a time regardless (utils/dispatch.py; a no-op unless
-        # serialization is on).  The key creation
-        # (a small device dispatch) and the t0/c0 stamps live INSIDE
-        # the hold: stamping outside would count lock-wait — other
-        # trials' whole epochs — as this trial's execute time and
-        # deflate mfu by ~Nx under serialization.
-        with obs.span("epoch", {"epoch": epoch}), dispatch_lock():
+    for epoch in range(start_epoch, s.num_epochs):
+        with obs.span("epoch", {"epoch": epoch}):
             with obs.span("epoch.dispatch"):
                 epoch_key = jax.random.key(
-                    fold_seed(seed, "epoch", epoch), impl=rng_impl
+                    fold_seed(s.seed, "epoch", epoch), impl=rng_impl
                 )
-                # Optax schedules are jnp-based: evaluating one IS a
-                # (small) device dispatch, so it rides inside the hold
-                # too — placed before the t0/c0 stamps so it never counts
-                # as epoch execute time.  Every registered schedule is
-                # linear in learning_rate, so lr x the peak-1.0 shape IS
-                # the effective rate on both the injected and baked paths.
-                lr_now = lr * float(
-                    shape_schedule(min(opt_steps, total_steps))
+                # Before the t0/c0 stamps, so that the schedule's own
+                # dispatch never counts as epoch execute time.
+                lr_now = lr_after_epoch(
+                    s, bundle.shape_schedule, bundle.total_steps,
+                    steps_per_epoch, epoch,
                 )
                 c0 = tracker.thread_seconds()
-                t0 = _time.time()
+                t0 = time.time()
                 params, opt_state, batch_stats, train_loss = train_epoch(
                     params, opt_state, batch_stats, data.x_train,
                     data.y_train, epoch_key
                 )
-                metrics = evaluate(
+                metrics = bundle.evaluate(
                     params, batch_stats, data.x_val, data.y_val,
                     data.val_mask
                 )
-            # Sync INSIDE the locked section via scalar readbacks: jit
-            # returns futures, so without this the lock would release
-            # while the epoch still runs — the overlap the lock exists
-            # to prevent.
+            # jit returns futures: the scalar readbacks are the sync.
             with obs.span("epoch.readback"):
                 train_loss = float(train_loss)
                 metrics = {k: float(v) for k, v in metrics.items()}
-        record = {
-            "epoch": epoch,
-            "train_loss": train_loss,
-            "lr": lr_now,
-            "steps": step_count,
-            **metrics,
-        }
-        # The in-lock readbacks above synced both programs; wall minus
-        # this thread's compile seconds is device-execute time.
+        # The readbacks above synced both programs; wall minus this
+        # thread's compile seconds is device-execute time.
         exec_s = max(
-            _time.time() - t0 - (tracker.thread_seconds() - c0), 1e-9
+            time.time() - t0 - (tracker.thread_seconds() - c0), 1e-9
         )
-        perf_acct.annotate(record, exec_s, device=device)
-        if "moe_local_pairs" in metrics:
-            # An expert layer's routing counts (make_token_eval_fn): the
-            # mean ratio is load_max_over_mean_sum over reports.
-            registry = obs.get_registry()
-            registry.add("moe.local_pairs", metrics["moe_local_pairs"])
-            registry.add("moe.load_max_over_mean_sum",
-                         metrics["moe_load_max_over_mean"])
-            registry.add("moe.reports")
-        checkpoint = None
-        if checkpoint_freq and (epoch + 1) % checkpoint_freq == 0:
-            checkpoint = {
-                "params": params,
-                "opt_state": opt_state,
-                "batch_stats": batch_stats,
-                "epoch": epoch,
-                # Stream family the trial's epochs were drawn from; a
-                # restore on another backend must keep it (see restore
-                # above).  Extra key: older restore templates ignore it.
-                "rng_impl": rng_impl or "",
-            }
-            if serialization_on():
-                # The async writer would otherwise read these device
-                # buffers back OUTSIDE any lock, concurrent with other
-                # threads' dispatches — the exact traffic pattern the
-                # serialization exists to prevent.  With serialization
-                # off, the device-held pytree keeps the writer's
-                # readback overlapped with training (the designed
-                # async-checkpoint behavior).
-                with dispatch_lock():
-                    checkpoint = jax.device_get(checkpoint)
-        session.report(record, checkpoint=checkpoint)
+        record = epoch_record(
+            perf_acct, device, epoch, steps_per_epoch, train_loss, lr_now,
+            metrics, exec_s,
+        )
+        session.report(record, checkpoint=_epoch_checkpoint(
+            s, epoch, params, opt_state, batch_stats, rng_impl
+        ))
 
     return None
 
@@ -586,6 +629,7 @@ _StreamBundle = namedtuple("_StreamBundle", [
 
 def _train_regressor_streaming(
     config: Dict[str, Any],
+    s: TrialSettings,
     train_data: Dataset,
     val_data: Dataset,
     device,
@@ -607,35 +651,27 @@ def _train_regressor_streaming(
     resident (bit-identical metrics with the resident path's eval
     program).
     """
+    if s.loss_name in TOKEN_LOSSES:
+        raise ValueError(
+            f"loss_function={s.loss_name!r} is not supported under "
+            "input_mode='streaming': the producer stages every array in "
+            "the compute dtype (token ids included) and streamed "
+            "validation is regression-only; use input_mode='resident'"
+        )
     from distributed_machine_learning_tpu.compilecache import (
         chunked_program_key,
     )
     from distributed_machine_learning_tpu.data import pipeline as hostpipe
 
-    counters = hostpipe.get_host_input_counters()
-    counters.add("streams_engaged")
-
-    num_epochs = int(config.get("num_epochs", 20))
-    seed = int(config.get("seed", 0))
-    loss_name = str(config.get("loss_function", "mse"))
-    accum = max(int(config.get("accumulate_grad_batches", 1)), 1)
-    lr = float(config["learning_rate"])
-    wd = float(config.get("weight_decay", 0.0))
-    opt_name = str(config.get("optimizer", "adam")).lower()
-    injected = (
-        opt_name in INJECTABLE_OPTIMIZERS
-        and accum == 1
-        and bool(config.get("inject_hyperparams", True))
-    )
+    setup_span = obs.span("trial.setup")  # as on the resident path
+    hostpipe.get_host_input_counters().add("streams_engaged")
 
     x_np, y_np = train_data.x, train_data.y
     n_train = len(train_data)
     batch_size = int(min(int(config.get("batch_size", 32)), n_train))
     num_batches = max(n_train // batch_size, 1)
     steps_per_epoch = num_batches
-    total_steps = max(int(config.get(
-        "total_steps", num_epochs * max(steps_per_epoch // accum, 1)
-    )), 1)
+    total_steps = s.schedule_steps(steps_per_epoch)
 
     # Chunk geometry: ring slabs sized to the device budget.
     row_nbytes = (
@@ -672,123 +708,76 @@ def _train_regressor_streaming(
         else None
     )
 
-    def _build_stream_bundle(use_injected) -> _StreamBundle:
-        shape_schedule = get_schedule(
-            str(config.get("lr_schedule", "warmup_linear_decay")),
-            learning_rate=1.0,
-            warmup_steps=int(config.get("warmup_steps", 0)),
-            total_steps=total_steps,
+    # ONE jitted chunk program serves the full chunk AND the tail (jit
+    # retraces per slab shape: at most two traces per epoch geometry — the
+    # chunk COUNT never shapes a trace).  Donation covers the state and the
+    # consumed slab, so each chunk's staging buffers free at the boundary
+    # (the ring's memory bound).
+    def _chunk_train(forward, tx):
+        return jax.jit(
+            make_chunk_epoch_fn(forward, tx, get_loss(s.loss_name)),
+            donate_argnums=(0, 1, 2, 4, 5),
         )
-        if use_injected:
-            tx = make_injected_optimizer(
-                opt_name,
-                shape_schedule,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(config.get("gradient_clipping", 0.0)),
-            )
-        else:
-            tx = make_optimizer(
-                opt_name,
-                learning_rate=get_schedule(
-                    str(config.get("lr_schedule", "warmup_linear_decay")),
-                    learning_rate=lr,
-                    warmup_steps=int(config.get("warmup_steps", 0)),
-                    total_steps=total_steps,
-                ),
-                weight_decay=wd,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(config.get("gradient_clipping", 0.0)),
-                accumulate_grad_batches=accum,
-            )
-        model = build_model(config)
-        # Abstract probe: flag kwarg + BN detection with NOTHING allocated
-        # (an over-budget dataset often rides with a big model too).
-        abstract_vars, flag_name = detect_call_convention(
-            model,
+
+    def _build_stream_bundle() -> _StreamBundle:
+        tx, shape_schedule = build_optimizer(s, total_steps, s.injected)
+        programs = _model_programs(
+            config, s.loss_name,
             jax.ShapeDtypeStruct(
                 (1, *x_np.shape[1:]), np.dtype(compute_dtype)
             ),
-            abstract=True,
-        )
-        has_bn = "batch_stats" in abstract_vars
-        init_kwargs = {
-            flag_name: True if flag_name == "deterministic" else False
-        }
-        init_model = jax.jit(
-            lambda rngs, x: model.init(rngs, x, **init_kwargs)
-        )
-        forward = make_forward(model, flag_name, has_bn)
-        # ONE jitted chunk program serves the full chunk AND the tail
-        # (jit retraces per slab shape: at most two traces per epoch
-        # geometry — the chunk COUNT never shapes a trace).  Donation
-        # covers the state and the consumed slab, so each chunk's staging
-        # buffers free at the boundary (the ring's memory bound).
-        chunk_train = jax.jit(
-            make_chunk_epoch_fn(forward, tx, get_loss(loss_name)),
-            donate_argnums=(0, 1, 2, 4, 5),
-        )
-        evaluate = (
-            None
-            if val_streaming
-            else jax.jit(
-                make_eval_fn(forward, loss_name, n_val_blocks, eval_bs)
-            )
-        )
-        eval_chunk = (
-            jax.jit(make_chunk_eval_fn(forward), donate_argnums=(2, 3, 4))
-            if val_streaming
-            else None
+            n_val_blocks, eval_bs, abstract=True,
         )
         return _StreamBundle(
-            model=model, flag_name=flag_name, has_bn=has_bn,
-            forward=forward, tx=tx, init_model=init_model,
-            init_opt=jax.jit(tx.init), chunk_train=chunk_train,
-            evaluate=evaluate, eval_chunk=eval_chunk,
+            tx=tx, init_opt=jax.jit(tx.init),
+            chunk_train=_chunk_train(programs.forward, tx),
+            eval_chunk=jax.jit(
+                make_chunk_eval_fn(programs.forward), donate_argnums=(2, 3, 4)
+            ),
             shape_schedule=shape_schedule, total_steps=total_steps,
+            **programs._asdict(),
         )
 
-    # The chunked program's OWN cache identity: slab rows fold in, chunk
-    # count does not (compilecache.chunked_program_key) — one build per
-    # cohort under injection, same discipline as the resident bundle.
-    program_key = chunked_program_key(
-        config,
-        chunk_rows=plan.chunk_batches,
-        batch_shape=[
-            [plan.chunk_batches, batch_size, *x_np.shape[1:]],
-            [plan.chunk_batches, batch_size, *y_np.shape[1:]],
-        ],
-        dtype=str(config.get("compute_dtype") or "float32"),
-        donation=(0, 1, 2, 4, 5),
-        extra={
-            "tail_rows": plan.tail_batches,
-            "val": ["streamed", eval_plan.chunk_batches]
-            if val_streaming else ["resident", n_val_blocks, eval_bs],
-            "device": [getattr(device, "platform", "cpu"),
-                       int(getattr(device, "id", 0))],
-        },
-    )
-    if injected and bool(config.get("share_programs", True)):
-        with dispatch_lock():
-            bundle = hostpipe.stream_bundle_for(
-                program_key, lambda: _build_stream_bundle(True)
+    with obs.span("trial.build"):
+        if s.injected:
+            # The chunked program's OWN cache identity: slab rows fold in,
+            # chunk count does not (compilecache.chunked_program_key) — one
+            # build per cohort under injection, same discipline as the
+            # resident bundle.
+            program_key = chunked_program_key(
+                config,
+                chunk_rows=plan.chunk_batches,
+                batch_shape=[
+                    [plan.chunk_batches, batch_size, *x_np.shape[1:]],
+                    [plan.chunk_batches, batch_size, *y_np.shape[1:]],
+                ],
+                dtype=str(config.get("compute_dtype") or "float32"),
+                donation=(0, 1, 2, 4, 5),
+                extra={
+                    "tail_rows": plan.tail_batches,
+                    "val": ["streamed", eval_plan.chunk_batches]
+                    if val_streaming else ["resident", n_val_blocks, eval_bs],
+                    "device": [getattr(device, "platform", "cpu"),
+                               int(getattr(device, "id", 0))],
+                },
             )
-    else:
-        with dispatch_lock():
-            bundle = _build_stream_bundle(injected)
-    tx = bundle.tx
-    chunk_train = bundle.chunk_train
-    shape_schedule = bundle.shape_schedule
+            bundle = hostpipe.stream_bundle_for(
+                program_key, _build_stream_bundle
+            )
+        else:
+            bundle = _build_stream_bundle()
 
-    with dispatch_lock():
-        variables = bundle.init_model(
-            init_rngs_for(seed),
-            jnp.asarray(x_np[:1], dtype=compute_dtype),
+    with obs.span("trial.init_or_restore"):
+        params, opt_state, batch_stats, start_epoch, rng_impl, tx = (
+            _init_or_restore(
+                bundle, s, config,
+                jnp.asarray(x_np[:1], dtype=compute_dtype),
+                session.get_checkpoint(),
+            )
         )
-        params = variables["params"]
-        batch_stats = variables.get("batch_stats", {})
-        opt_state = bundle.init_opt(params)
-        if injected:
-            opt_state = set_injected_hyperparams(opt_state, lr, wd)
+    chunk_train = bundle.chunk_train
+    if tx is not bundle.tx:
+        chunk_train = _chunk_train(bundle.forward, tx)
 
     # Resident validation staging (the common case: train dominates).
     xv = yv = vmask = None
@@ -806,84 +795,15 @@ def _train_regressor_streaming(
                                      val_data.y.dtype)])
             if pad else val_data.y
         )
-        with dispatch_lock():
-            xv = jnp.asarray(xv_np, dtype=compute_dtype)
-            yv = jnp.asarray(yv_np, dtype=jnp.float32)
-            vmask = jnp.asarray(np.concatenate(
-                [np.ones(n_val, np.float32), np.zeros(pad, np.float32)]
-            ))
+        xv = jnp.asarray(xv_np, dtype=compute_dtype)
+        yv = jnp.asarray(yv_np, dtype=jnp.float32)
+        vmask = jnp.asarray(np.concatenate(
+            [np.ones(n_val, np.float32), np.zeros(pad, np.float32)]
+        ))
 
-    # ---- restore (PBT exploit / fault retry) -------------------------------
-    rng_impl = resolve_rng_impl(config)
-    start_epoch = 0
-    ckpt = session.get_checkpoint()
-    if ckpt is not None:
-        saved_impl = ckpt.get("rng_impl") if isinstance(ckpt, dict) else None
-        if saved_impl is not None:
-            rng_impl = saved_impl or None
-        else:
-            rng_impl = config.get("rng_impl") or None
-        template = {
-            "params": params,
-            "opt_state": opt_state,
-            "batch_stats": batch_stats,
-            "epoch": 0,
-        }
-        with dispatch_lock():
-          try:
-            restored = restore_into(template, ckpt)
-          except (ValueError, KeyError, TypeError, AttributeError):
-            if not injected:
-                raise
-            # Legacy (baked-optimizer) checkpoint: rebuild the baked chain
-            # for this incarnation — same fallback as the resident path.
-            injected = False
-            tx = make_optimizer(
-                opt_name,
-                learning_rate=get_schedule(
-                    str(config.get("lr_schedule", "warmup_linear_decay")),
-                    learning_rate=lr,
-                    warmup_steps=int(config.get("warmup_steps", 0)),
-                    total_steps=total_steps,
-                ),
-                weight_decay=wd,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(
-                    config.get("gradient_clipping", 0.0)
-                ),
-                accumulate_grad_batches=accum,
-            )
-            chunk_train = jax.jit(
-                make_chunk_epoch_fn(
-                    bundle.forward, tx, get_loss(loss_name)
-                ),
-                donate_argnums=(0, 1, 2, 4, 5),
-            )
-            opt_state = jax.jit(tx.init)(params)
-            template["opt_state"] = opt_state
-            restored = restore_into(template, ckpt)
-        params = restored["params"]
-        opt_state = restored["opt_state"]
-        batch_stats = restored["batch_stats"]
-        start_epoch = int(restored["epoch"]) + 1
-        if injected:
-            with dispatch_lock():
-                opt_state = set_injected_hyperparams(opt_state, lr, wd)
-
-    checkpoint_freq = int(config.get("checkpoint_freq", 1))
-
-    # ---- per-epoch MFU accounting (same helper as the resident path) -------
-    seq_len = int(x_np.shape[1]) if x_np.ndim == 3 else 1
-    feats = int(x_np.shape[-1])
-    perf_acct = EpochPerfAccounting(
-        config,
-        batch_size=batch_size,
-        seq_len=seq_len,
-        features=feats,
-        steps_per_epoch=steps_per_epoch,
-        eval_rows=n_val,
-        device=device,
-        trial_id=session.current_trial_id(),
+    perf_acct = epoch_perf_accounting(
+        config, x_np.shape, batch_size=batch_size,
+        steps_per_epoch=steps_per_epoch, eval_rows=n_val, device=device,
     )
     tracker = get_tracker()
 
@@ -894,33 +814,21 @@ def _train_regressor_streaming(
     ))
 
     def _stage(arr, dtype):
-        staged = np.asarray(arr, dtype=dtype)
-        if serialization_on():
-            with dispatch_lock():
-                return jax.device_put(staged, device)
-        return jax.device_put(staged, device)
+        return jax.device_put(np.asarray(arr, dtype=dtype), device)
 
     def _epoch_perm(epoch: int) -> np.ndarray:
         # EXACTLY the resident epoch program's permutation: same key
         # derivation, same split, same truncation — threefry bits are
         # identical eager vs jit, so the host replays the in-program draw.
-        if serialization_on():
-            with dispatch_lock():
-                epoch_key = jax.random.key(
-                    fold_seed(seed, "epoch", epoch), impl=rng_impl
-                )
-                perm_key, _ = jax.random.split(epoch_key)
-                perm = np.asarray(jax.random.permutation(perm_key, n_train))
-        else:
-            epoch_key = jax.random.key(
-                fold_seed(seed, "epoch", epoch), impl=rng_impl
-            )
-            perm_key, _ = jax.random.split(epoch_key)
-            perm = np.asarray(jax.random.permutation(perm_key, n_train))
+        epoch_key = jax.random.key(
+            fold_seed(s.seed, "epoch", epoch), impl=rng_impl
+        )
+        perm_key, _ = jax.random.split(epoch_key)
+        perm = np.asarray(jax.random.permutation(perm_key, n_train))
         return perm[: num_batches * batch_size]
 
     def _source():
-        for epoch in range(start_epoch, num_epochs):
+        for epoch in range(start_epoch, s.num_epochs):
             perm = _epoch_perm(epoch)
             for start, rows in plan.chunk_sizes():
                 idx = perm[start * batch_size:(start + rows) * batch_size]
@@ -967,104 +875,81 @@ def _train_regressor_streaming(
         _source(), depth=depth, deadline_s=deadline_s,
         name=f"stream-{session.get_trial_id()}",
     )
-
-    import time as _time
+    setup_span.end()
 
     # ---- epoch loop: consume donated chunk k while k+1 stages --------------
     try:
-        for epoch in range(start_epoch, num_epochs):
-            step_count = (epoch + 1) * steps_per_epoch
-            opt_steps = (epoch + 1) * max(steps_per_epoch // accum, 1)
+        for epoch in range(start_epoch, s.num_epochs):
             epoch_span = obs.span(
                 "epoch", {"epoch": epoch, "mode": "streaming"}
             )
             epoch_span.__enter__()
-            with dispatch_lock():
+            # The resident loop's two spans; here the dispatch also holds
+            # the waits on the ring and, when validation streams, its
+            # per-chunk sums.
+            with obs.span("epoch.dispatch"):
                 epoch_key = jax.random.key(
-                    fold_seed(seed, "epoch", epoch), impl=rng_impl
+                    fold_seed(s.seed, "epoch", epoch), impl=rng_impl
                 )
                 # The resident program's in-program split: perm_key (the
                 # producer replays it) and the step chain's root.
                 _, key = jax.random.split(epoch_key)
-                lr_now = lr * float(
-                    shape_schedule(min(opt_steps, total_steps))
+                lr_now = lr_after_epoch(
+                    s, bundle.shape_schedule, total_steps, steps_per_epoch,
+                    epoch,
                 )
-            wait0 = prefetcher.wait_s
-            c0 = tracker.thread_seconds()
-            t0 = _time.time()
-            loss_parts = []
-            for _start, _rows in plan.chunk_sizes():
-                # The ring get stays OUTSIDE the dispatch hold: the
-                # producer's device_put takes the same lock under
-                # serialization, and waiting while holding it would
-                # deadlock the very overlap being measured.
-                xb, yb = prefetcher.get()
-                with dispatch_lock():
+                wait0 = prefetcher.wait_s
+                c0 = tracker.thread_seconds()
+                t0 = time.time()
+                loss_parts = []
+                for _start, _rows in plan.chunk_sizes():
+                    xb, yb = prefetcher.get()
                     params, opt_state, batch_stats, key, losses = (
                         chunk_train(
                             params, opt_state, batch_stats, key, xb, yb
                         )
                     )
-                loss_parts.append(losses)
-                # A consumed chunk IS progress: a slow producer must read
-                # as slow, never as a silent (stalled) trial.
-                session.heartbeat()
-            if val_streaming:
-                sums = np.zeros(5, np.float64)
-                for _vstart, _vrows in eval_plan.chunk_sizes():
-                    xbv, ybv, mbv = prefetcher.get()
-                    with dispatch_lock():
+                    loss_parts.append(losses)
+                    # A consumed chunk IS progress: a slow producer must
+                    # read as slow, never as a silent (stalled) trial.
+                    session.heartbeat()
+                if val_streaming:
+                    sums = np.zeros(5, np.float64)
+                    for _vstart, _vrows in eval_plan.chunk_sizes():
+                        xbv, ybv, mbv = prefetcher.get()
                         part = bundle.eval_chunk(
                             params, batch_stats, xbv, ybv, mbv
                         )
                         sums += np.array([float(v) for v in part])
-                    session.heartbeat()
-                metrics = eval_metrics_from_sums(loss_name, *sums)
-                with dispatch_lock():
-                    train_loss = float(jnp.concatenate(loss_parts).mean())
-            else:
-                with dispatch_lock():
+                        session.heartbeat()
+                    metrics = eval_metrics_from_sums(s.loss_name, *sums)
+                else:
                     metrics = bundle.evaluate(
                         params, batch_stats, xv, yv, vmask
                     )
-                    # Scalar readbacks sync every queued chunk program
-                    # before the epoch clock stops (jit returns futures).
-                    train_loss = float(jnp.concatenate(loss_parts).mean())
-                    metrics = {k: float(v) for k, v in metrics.items()}
+            # Scalar readbacks sync every queued chunk program before the
+            # epoch clock stops (jit returns futures).
+            with obs.span("epoch.readback"):
+                train_loss = float(jnp.concatenate(loss_parts).mean())
+                metrics = {k: float(v) for k, v in metrics.items()}
             wait_s = prefetcher.wait_s - wait0
-            wall = _time.time() - t0
+            wall = time.time() - t0
             compile_s = tracker.thread_seconds() - c0
-            exec_s = max(wall - compile_s - wait_s, 1e-9)
             prefetcher.note_consume(max(wall - wait_s, 0.0))
-            record = {
-                "epoch": epoch,
-                "train_loss": train_loss,
-                "lr": lr_now,
-                "steps": step_count,
-                "input_mode": "streaming",
-                **metrics,
-            }
             # ``observe_s`` is wall minus compile but INCLUDING prefetch
             # wait: a starved consumer must read as slow to the anomaly
             # detector (that is the straggler signal a chaos
             # slow-producer run exists to surface), while the MFU
             # numerator keeps the wait-free exec_s.
-            perf_acct.annotate(
-                record, exec_s, device=device,
+            record = epoch_record(
+                perf_acct, device, epoch, steps_per_epoch, train_loss,
+                lr_now, metrics, max(wall - compile_s - wait_s, 1e-9),
                 observe_s=max(wall - compile_s, 1e-9),
+                input_mode="streaming",
             )
-            checkpoint = None
-            if checkpoint_freq and (epoch + 1) % checkpoint_freq == 0:
-                checkpoint = {
-                    "params": params,
-                    "opt_state": opt_state,
-                    "batch_stats": batch_stats,
-                    "epoch": epoch,
-                    "rng_impl": rng_impl or "",
-                }
-                if serialization_on():
-                    with dispatch_lock():
-                        checkpoint = jax.device_get(checkpoint)
+            checkpoint = _epoch_checkpoint(
+                s, epoch, params, opt_state, batch_stats, rng_impl
+            )
             # Close the epoch span before report (report blocks on the
             # scheduler; that wait is dispatch time, not epoch time).  An
             # exception above leaves it OPEN on purpose: a stall dump then

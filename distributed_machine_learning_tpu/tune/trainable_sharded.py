@@ -37,12 +37,12 @@ the *global* batch and must be divisible by dp.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_machine_learning_tpu import obs
@@ -55,12 +55,8 @@ from distributed_machine_learning_tpu.models import build_model
 from distributed_machine_learning_tpu.models.partition_rules import rules_for
 from distributed_machine_learning_tpu.ops.losses import get_loss
 from distributed_machine_learning_tpu.ops.optimizers import (
-    INJECTABLE_OPTIMIZERS,
-    make_injected_optimizer,
-    make_optimizer,
     set_injected_hyperparams,
 )
-from distributed_machine_learning_tpu.ops.schedules import get_schedule
 from distributed_machine_learning_tpu.parallel.mesh import make_mesh
 from distributed_machine_learning_tpu.parallel.partition import (
     mesh_axis_sizes,
@@ -69,9 +65,6 @@ from distributed_machine_learning_tpu.parallel.partition import (
 from distributed_machine_learning_tpu.parallel.sharding import (
     opt_state_shardings,
     param_shardings,
-)
-from distributed_machine_learning_tpu.perf.costmodel import (
-    EpochPerfAccounting,
 )
 from distributed_machine_learning_tpu.tune import session
 from distributed_machine_learning_tpu.tune._regression_program import (
@@ -82,11 +75,14 @@ from distributed_machine_learning_tpu.tune._regression_program import (
     per_example_losses,
 )
 from distributed_machine_learning_tpu.tune.checkpoint import restore_into
-from distributed_machine_learning_tpu.utils.compile_cache import get_tracker
-from distributed_machine_learning_tpu.utils.dispatch import (
-    dispatch_lock,
-    serialization_on,
+from distributed_machine_learning_tpu.tune.trainable import (
+    build_optimizer,
+    epoch_perf_accounting,
+    epoch_record,
+    lr_after_epoch,
+    trial_settings,
 )
+from distributed_machine_learning_tpu.utils.compile_cache import get_tracker
 from distributed_machine_learning_tpu.utils.seeding import (
     fold_seed,
     init_rngs_for,
@@ -183,9 +179,7 @@ def _train_sharded(
     rules = rules_for(config)
     rules_fp = rules_fingerprint(rules)
 
-    num_epochs = int(config.get("num_epochs", 20))
-    seed = int(config.get("seed", 0))
-    loss_name = str(config.get("loss_function", "mse"))
+    s = trial_settings(config)
     global_batch = int(config.get("batch_size", 32))
     if global_batch % dp != 0:
         raise ValueError(
@@ -240,52 +234,13 @@ def _train_sharded(
     else:
         chunk_plan = None
 
-    accum = max(int(config.get("accumulate_grad_batches", 1)), 1)
-    total_steps = int(
-        config.get(
-            "total_steps", num_epochs * max(steps_per_epoch // accum, 1)
-        )
-    )
-    lr = float(config["learning_rate"])
-    wd = float(config.get("weight_decay", 0.0))
-    opt_name = str(config.get("optimizer", "adam")).lower()
+    total_steps = s.schedule_steps(steps_per_epoch)
     # Same-architecture trials share ONE traced program when lr/wd ride in
-    # the optimizer state instead of being baked as HLO constants — see
-    # tune/trainable.py (the identical logic) and ops/optimizers.py.
-    injected = (
-        opt_name in INJECTABLE_OPTIMIZERS
-        and accum == 1
-        and bool(config.get("inject_hyperparams", True))
-    )
-    if injected:
-        shape_schedule = get_schedule(
-            str(config.get("lr_schedule", "warmup_linear_decay")),
-            learning_rate=1.0,
-            warmup_steps=int(config.get("warmup_steps", 0)),
-            total_steps=max(total_steps, 1),
-        )
-        tx = make_injected_optimizer(
-            opt_name,
-            shape_schedule,
-            momentum=float(config.get("momentum", 0.0)),
-            gradient_clipping=float(config.get("gradient_clipping", 0.0)),
-        )
-    else:
-        schedule = get_schedule(
-            str(config.get("lr_schedule", "warmup_linear_decay")),
-            learning_rate=lr,
-            warmup_steps=int(config.get("warmup_steps", 0)),
-            total_steps=max(total_steps, 1),
-        )
-        tx = make_optimizer(
-            opt_name,
-            learning_rate=schedule,
-            weight_decay=wd,
-            momentum=float(config.get("momentum", 0.0)),
-            gradient_clipping=float(config.get("gradient_clipping", 0.0)),
-            accumulate_grad_batches=accum,
-        )
-    loss_fn = get_loss(loss_name)
+    # the optimizer state instead of being baked as HLO constants
+    # (tune/trainable.py trial_settings, ops/optimizers.py).
+    injected = s.injected
+    tx, shape_schedule = build_optimizer(s, total_steps, injected)
+    loss_fn = get_loss(s.loss_name)
 
     # The model carries the mesh so the activation sharding constraints
     # (residual stream, attention q/k/v — models/layers.py) are live; the
@@ -294,51 +249,46 @@ def _train_sharded(
     sample_x = x_np[:1]
     repl = NamedSharding(mesh, P())
 
-    # Device-call section (init dispatch, shard placement, jit init):
-    # serialized across concurrent trial threads when
-    # DML_SERIALIZE_DISPATCH is on (utils/dispatch.py; same coverage
-    # as tune/trainable.py's init block).
-    with dispatch_lock():
-        # Abstract convention probe: flag kwarg + BN detection via
-        # eval_shape — nothing allocated, so the rule shardings below
-        # exist BEFORE any parameter is materialized (an over-HBM
-        # flagship must be born sharded, not placed then re-placed).
-        abstract_vars, flag_name = detect_call_convention(
-            model, sample_x, abstract=True,
-        )
-        has_bn = "batch_stats" in abstract_vars
-        forward = make_forward(model, flag_name, has_bn)
+    # Abstract convention probe: flag kwarg + BN detection via
+    # eval_shape — nothing allocated, so the rule shardings below
+    # exist BEFORE any parameter is materialized (an over-HBM
+    # flagship must be born sharded, not placed then re-placed).
+    abstract_vars, flag_name = detect_call_convention(
+        model, sample_x, abstract=True,
+    )
+    has_bn = "batch_stats" in abstract_vars
+    forward = make_forward(model, flag_name, has_bn)
 
-        p_shardings = param_shardings(
-            abstract_vars["params"], mesh, rules
-        )
-        bs_shardings = jax.tree.map(
-            lambda _: repl, abstract_vars.get("batch_stats", {})
-        )
-        v_shardings = jax.tree.map(lambda _: repl, abstract_vars)
-        v_shardings = dict(v_shardings, params=p_shardings)
-        if has_bn:
-            v_shardings["batch_stats"] = bs_shardings
-        init_kwargs = {
-            flag_name: True if flag_name == "deterministic" else False
-        }
-        # Per-trial init diversity, same as train_regressor (the rng is a
-        # traced argument — one compiled init program per architecture);
-        # out_shardings = the rule layout, so params are born sharded.
-        variables = jax.jit(
-            lambda r, x: model.init(r, x, **init_kwargs),
-            out_shardings=v_shardings,
-        )(init_rngs_for(seed), sample_x)
-        params = variables["params"]
-        o_shardings = opt_state_shardings(
-            jax.eval_shape(tx.init, params), p_shardings, mesh
-        )
-        opt_state = jax.jit(
-            tx.init, in_shardings=(p_shardings,), out_shardings=o_shardings
-        )(params)
-        if injected:
-            opt_state = set_injected_hyperparams(opt_state, lr, wd)
-        batch_stats = variables.get("batch_stats", {})
+    p_shardings = param_shardings(
+        abstract_vars["params"], mesh, rules
+    )
+    bs_shardings = jax.tree.map(
+        lambda _: repl, abstract_vars.get("batch_stats", {})
+    )
+    v_shardings = jax.tree.map(lambda _: repl, abstract_vars)
+    v_shardings = dict(v_shardings, params=p_shardings)
+    if has_bn:
+        v_shardings["batch_stats"] = bs_shardings
+    init_kwargs = {
+        flag_name: True if flag_name == "deterministic" else False
+    }
+    # Per-trial init diversity, same as train_regressor (the rng is a
+    # traced argument — one compiled init program per architecture);
+    # out_shardings = the rule layout, so params are born sharded.
+    variables = jax.jit(
+        lambda r, x: model.init(r, x, **init_kwargs),
+        out_shardings=v_shardings,
+    )(init_rngs_for(s.seed), sample_x)
+    params = variables["params"]
+    o_shardings = opt_state_shardings(
+        jax.eval_shape(tx.init, params), p_shardings, mesh
+    )
+    opt_state = jax.jit(
+        tx.init, in_shardings=(p_shardings,), out_shardings=o_shardings
+    )(params)
+    if injected:
+        opt_state = set_injected_hyperparams(opt_state, s.lr, s.wd)
+    batch_stats = variables.get("batch_stats", {})
 
     # Batched-epoch shardings: [num_batches, global_batch, ...] with the
     # in-batch dim over dp.
@@ -455,19 +405,18 @@ def _train_sharded(
                 ],
             },
         )
-        with dispatch_lock():
-            try:
-                train_chunk = _epoch_aot_cache().get_or_compile(
-                    chunk_key, chunk_fn,
-                    params, opt_state, batch_stats, jnp.int32(0),
-                    jax.ShapeDtypeStruct(chunk_shape[0], jnp.float32),
-                    jax.ShapeDtypeStruct(chunk_shape[1], jnp.float32),
-                    jax.random.key(0),
-                    donate_argnums=_CHUNK_DONATE,
-                    jit_kwargs=chunk_jit_kwargs,
-                )
-            except Exception:  # noqa: BLE001 - AOT must never fail a trial
-                train_chunk = jit_chunk()
+        try:
+            train_chunk = _epoch_aot_cache().get_or_compile(
+                chunk_key, chunk_fn,
+                params, opt_state, batch_stats, jnp.int32(0),
+                jax.ShapeDtypeStruct(chunk_shape[0], jnp.float32),
+                jax.ShapeDtypeStruct(chunk_shape[1], jnp.float32),
+                jax.random.key(0),
+                donate_argnums=_CHUNK_DONATE,
+                jit_kwargs=chunk_jit_kwargs,
+            )
+        except Exception:  # noqa: BLE001 - AOT must never fail a trial
+            train_chunk = jit_chunk()
         train_chunk_tail = jit_chunk() if chunk_plan.tail_batches else None
     elif n_procs > 1:
         # Process-spanning programs skip the AOT executable tier (a
@@ -476,7 +425,6 @@ def _train_sharded(
         # cache + artifact origin, whose keys fold the process topology.
         train_epoch = jit_epoch()
     else:
-      with dispatch_lock():
         try:
             train_epoch = _epoch_aot_cache().get_or_compile(
                 program_key, epoch_fn,
@@ -513,21 +461,16 @@ def _train_sharded(
     evaluate = jax.jit(
         eval_fn, in_shardings=(None, None, xv_sharding, xv_sharding, xv_sharding)
     )
-    # Validation staging is device traffic too — same hold discipline
-    # (utils/dispatch.py).  stage_global = device_put single-process; on a
-    # spanning mesh each process stages only its addressable slices.
-    with dispatch_lock():
-        xv = mh.stage_global(xv_np, xv_sharding)
-        yv = mh.stage_global(yv_np, xv_sharding)
-        mask = mh.stage_global(mask_np, xv_sharding)
+    # stage_global = device_put single-process; on a spanning mesh each
+    # process stages only its addressable slices.
+    xv = mh.stage_global(xv_np, xv_sharding)
+    yv = mh.stage_global(yv_np, xv_sharding)
+    mask = mh.stage_global(mask_np, xv_sharding)
 
     # ---- restore (PBT exploit / fault retry) -------------------------------
     start_epoch = 0
     ckpt = session.get_checkpoint()
     if ckpt is not None:
-      # Restore readbacks (_host) + re-sharding device_puts serialized
-      # like every other device-call section (utils/dispatch.py).
-      with dispatch_lock():
         template = {
             "params": _host_template(params),
             "opt_state": _host_template(opt_state),
@@ -545,22 +488,7 @@ def _train_sharded(
             # bodies over the new `tx` and re-jit (plain jit: the AOT key
             # describes the injected layout, not this incarnation's).
             injected = False
-            schedule = get_schedule(
-                str(config.get("lr_schedule", "warmup_linear_decay")),
-                learning_rate=lr,
-                warmup_steps=int(config.get("warmup_steps", 0)),
-                total_steps=max(total_steps, 1),
-            )
-            tx = make_optimizer(
-                opt_name,
-                learning_rate=schedule,
-                weight_decay=wd,
-                momentum=float(config.get("momentum", 0.0)),
-                gradient_clipping=float(
-                    config.get("gradient_clipping", 0.0)
-                ),
-                accumulate_grad_batches=accum,
-            )
+            tx, _ = build_optimizer(s, total_steps, False)
             o_shardings = opt_state_shardings(
                 jax.eval_shape(tx.init, params), p_shardings, mesh
             )
@@ -599,30 +527,21 @@ def _train_sharded(
         if injected:
             # This trial's config lr/wd win over restored slots (PBT
             # explore semantics — same as tune/trainable.py).
-            opt_state = set_injected_hyperparams(opt_state, lr, wd)
+            opt_state = set_injected_hyperparams(opt_state, s.lr, s.wd)
         batch_stats = jax.device_put(
             restored["batch_stats"],
             jax.tree.map(lambda _: repl, restored["batch_stats"]),
         )
         start_epoch = int(restored["epoch"]) + 1
 
-    checkpoint_freq = int(config.get("checkpoint_freq", 1))
-
     # ---- per-epoch MFU/roofline accounting (perf/costmodel.py) -------------
-    # Same helper as tune/trainable.py; the sharded paths additionally
-    # carry their AOT program key so the captured XLA cost is
-    # cross-checked against the analytic model and the records report
-    # ``roofline_bound`` (process-spanning programs skip the AOT tier —
-    # and the audit — by construction).
-    seq_len = int(x_np.shape[1]) if x_np.ndim == 3 else 1
-    feats = int(x_np.shape[-1])
-    perf_acct = EpochPerfAccounting(
-        config,
-        batch_size=global_batch,
-        seq_len=seq_len,
-        features=feats,
-        steps_per_epoch=steps_per_epoch,
-        eval_rows=n_val,
+    # The sharded paths carry their AOT program key so the captured XLA
+    # cost is cross-checked against the analytic model and the records
+    # report ``roofline_bound`` (process-spanning programs skip the AOT
+    # tier — and the audit — by construction).
+    perf_acct = epoch_perf_accounting(
+        config, x_np.shape, batch_size=global_batch,
+        steps_per_epoch=steps_per_epoch, eval_rows=n_val,
         device=budget_device,
         num_devices=len(devices),
         program_key=(
@@ -633,7 +552,6 @@ def _train_sharded(
         program_steps=(
             chunk_plan.chunk_batches if streaming else steps_per_epoch
         ),
-        trial_id=session.current_trial_id(),
     )
     tracker = get_tracker()
 
@@ -646,32 +564,35 @@ def _train_sharded(
         convention as the in-program threefry chain
         (``fold_seed(seed, "epoch", epoch)``)."""
         return np.random.default_rng(
-            fold_seed(seed, "shuffle", epoch)
+            fold_seed(s.seed, "shuffle", epoch)
         ).permutation(n_train)[: num_batches * global_batch]
 
     audit_donation = True
 
+    def count_consumed(probes):
+        # Donation audit: references to donated inputs, checked for
+        # consumption right after the first call — runtime proof the
+        # buffer aliases took effect.
+        consumed = sum(
+            1 for a in probes
+            if isinstance(a, jax.Array) and a.is_deleted()
+        )
+        if consumed:
+            get_compile_counters().add("donation_aliased_buffers", consumed)
+
     if streaming:
         # ---- streaming epoch loop: consume chunk k while k+1 stages --------
-        import time as _time
-
         depth = hostpipe.prefetch_depth(config)
         deadline_s = float(config.get(
             "streaming_producer_deadline_s",
             hostpipe.DEFAULT_PRODUCER_DEADLINE_S,
         ))
 
-        def _stage(arr, sharding):
-            if serialization_on():
-                with dispatch_lock():
-                    return jax.device_put(arr, sharding)
-            return jax.device_put(arr, sharding)
-
         def _source():
             # The resident loop's OWN per-epoch shuffle keys, consumed in
             # the same epoch order — identical batches in identical order
             # is the determinism contract.
-            for _epoch in range(start_epoch, num_epochs):
+            for _epoch in range(start_epoch, s.num_epochs):
                 perm = epoch_perm(_epoch)
                 for start, rows in chunk_plan.chunk_sizes():
                     idx = perm[
@@ -680,103 +601,79 @@ def _train_sharded(
                     xg, yg = hostpipe.gather_batches(
                         x_np, y_np, idx, rows, global_batch
                     )
-                    yield _stage(xg, xb_sharding), _stage(yg, yb_sharding)
+                    yield (
+                        jax.device_put(xg, xb_sharding),
+                        jax.device_put(yg, yb_sharding),
+                    )
 
         prefetcher = hostpipe.ChunkPrefetcher(
             _source(), depth=depth, deadline_s=deadline_s,
             name=f"stream-{session.get_trial_id()}",
         )
         try:
-            for epoch in range(start_epoch, num_epochs):
-                step_count = (epoch + 1) * steps_per_epoch
-                opt_steps = (epoch + 1) * max(steps_per_epoch // accum, 1)
+            for epoch in range(start_epoch, s.num_epochs):
                 epoch_span = obs.span(
                     "epoch", {"epoch": epoch, "mode": "streaming"}
                 )
                 epoch_span.__enter__()
-                with dispatch_lock():
-                    epoch_key = jax.random.key(
-                        fold_seed(seed, "epoch", epoch)
-                    )
-                    lr_now = (
-                        lr * float(
-                            shape_schedule(min(opt_steps, total_steps))
-                        )
-                        if injected
-                        else float(schedule(min(opt_steps, total_steps)))
-                    )
+                epoch_key = jax.random.key(
+                    fold_seed(s.seed, "epoch", epoch)
+                )
+                lr_now = lr_after_epoch(
+                    s, shape_schedule, total_steps, steps_per_epoch, epoch
+                )
                 wait0 = prefetcher.wait_s
                 c0 = tracker.thread_seconds()
-                t0 = _time.monotonic()
+                t0 = time.monotonic()
                 loss_parts = []
                 probes = None
                 for start, rows in chunk_plan.chunk_sizes():
-                    # The ring get stays OUTSIDE the dispatch hold — the
-                    # producer's device_put takes the same lock under
-                    # serialization.
                     xb, yb = prefetcher.get()
-                    with dispatch_lock():
-                        if audit_donation and probes is None:
-                            probes = [xb, yb] \
-                                + jax.tree.leaves(params)[:1] \
-                                + jax.tree.leaves(opt_state)[:1]
-                        prog = (
-                            train_chunk
-                            if rows == chunk_plan.chunk_batches
-                            else train_chunk_tail
-                        )
-                        params, opt_state, batch_stats, losses = prog(
-                            params, opt_state, batch_stats,
-                            jnp.int32(start), xb, yb, epoch_key,
-                        )
+                    if audit_donation and probes is None:
+                        probes = [xb, yb] \
+                            + jax.tree.leaves(params)[:1] \
+                            + jax.tree.leaves(opt_state)[:1]
+                    prog = (
+                        train_chunk
+                        if rows == chunk_plan.chunk_batches
+                        else train_chunk_tail
+                    )
+                    params, opt_state, batch_stats, losses = prog(
+                        params, opt_state, batch_stats,
+                        jnp.int32(start), xb, yb, epoch_key,
+                    )
                     loss_parts.append(losses)
                     # A consumed chunk IS progress for the trial watchdog.
                     session.heartbeat()
-                with dispatch_lock():
-                    metrics = evaluate(params, batch_stats, xv, yv, mask)
-                    train_loss = float(jnp.concatenate(loss_parts).mean())
-                    metrics = {k: float(v) for k, v in metrics.items()}
-                    if audit_donation and probes is not None:
-                        audit_donation = False
-                        consumed = sum(
-                            1 for a in probes
-                            if isinstance(a, jax.Array) and a.is_deleted()
-                        )
-                        if consumed:
-                            get_compile_counters().add(
-                                "donation_aliased_buffers", consumed
-                            )
+                metrics = evaluate(params, batch_stats, xv, yv, mask)
+                train_loss = float(jnp.concatenate(loss_parts).mean())
+                metrics = {k: float(v) for k, v in metrics.items()}
+                if audit_donation and probes is not None:
+                    audit_donation = False
+                    count_consumed(probes)
                 wait_s = prefetcher.wait_s - wait0
-                wall = _time.monotonic() - t0
+                wall = time.monotonic() - t0
                 compile_s = tracker.thread_seconds() - c0
-                exec_s = max(wall - compile_s - wait_s, 1e-9)
                 prefetcher.note_consume(max(wall - wait_s, 0.0))
-                record = {
-                    "epoch": epoch,
-                    "train_loss": train_loss,
-                    "lr": lr_now,
-                    "steps": step_count,
-                    "num_devices": len(devices),
-                    "mesh_shape": dict(mesh_shape),
-                    "input_mode": "streaming",
-                    **metrics,
-                }
                 # Wait rides in observe_s (a starved consumer must read
                 # as slow to the anomaly detector), never in the MFU
                 # numerator — same convention as tune/trainable.py.
-                perf_acct.annotate(
-                    record, exec_s, device=budget_device,
+                record = epoch_record(
+                    perf_acct, budget_device, epoch, steps_per_epoch,
+                    train_loss, lr_now, metrics,
+                    max(wall - compile_s - wait_s, 1e-9),
                     observe_s=max(wall - compile_s, 1e-9),
+                    num_devices=len(devices), mesh_shape=dict(mesh_shape),
+                    input_mode="streaming",
                 )
                 checkpoint = None
-                if checkpoint_freq and (epoch + 1) % checkpoint_freq == 0:
-                    with dispatch_lock():
-                        checkpoint = {
-                            "params": _host(params),
-                            "opt_state": _host(opt_state),
-                            "batch_stats": _host(batch_stats),
-                            "epoch": epoch,
-                        }
+                if s.checkpoint_due(epoch):
+                    checkpoint = {
+                        "params": _host(params),
+                        "opt_state": _host(opt_state),
+                        "batch_stats": _host(batch_stats),
+                        "epoch": epoch,
+                    }
                 # Close before report (scheduler wait is not epoch time);
                 # an exception above leaves it open — the stall dump then
                 # names the in-flight epoch as the hang site.
@@ -789,30 +686,12 @@ def _train_sharded(
         return None
 
     # ---- epoch loop: host-driven so the scheduler can interrupt ------------
-    import time as _time
-
-    for epoch in range(start_epoch, num_epochs):
+    for epoch in range(start_epoch, s.num_epochs):
         perm = epoch_perm(epoch)
-        # Serialized across concurrent trial threads when
-        # DML_SERIALIZE_DISPATCH is on (utils/dispatch.py). The epoch
-        # batches' host->device transfer — the loop's largest single
-        # transfer — rides inside the same hold, and the scalar
-        # readbacks sync BEFORE release (jit returns futures; an
-        # unsynced exit would let the next thread's traffic overlap
-        # this epoch while it still runs).
-        step_count = (epoch + 1) * steps_per_epoch
-        # Schedule is indexed by optimizer steps (micro-steps // accum).
-        opt_steps = (epoch + 1) * max(steps_per_epoch // accum, 1)
-        with obs.span("epoch", {"epoch": epoch}), dispatch_lock():
-            epoch_key = jax.random.key(fold_seed(seed, "epoch", epoch))
-            # Optax schedules are jnp-based — evaluating one is a small
-            # device dispatch, so it stays inside the hold (advisor r5:
-            # an unlocked eval per epoch is exactly the concurrent
-            # multi-thread traffic the serialization exists to prevent).
-            lr_now = (
-                lr * float(shape_schedule(min(opt_steps, total_steps)))
-                if injected
-                else float(schedule(min(opt_steps, total_steps)))
+        with obs.span("epoch", {"epoch": epoch}):
+            epoch_key = jax.random.key(fold_seed(s.seed, "epoch", epoch))
+            lr_now = lr_after_epoch(
+                s, shape_schedule, total_steps, steps_per_epoch, epoch
             )
             # One whole-epoch slab per epoch by design (streaming is the
             # over-budget path); stage_global = device_put on one process,
@@ -827,72 +706,54 @@ def _train_sharded(
                 y_np[perm].reshape(yb_shape), yb_sharding,
             )
             if audit_donation:
-                # Donation audit probes: references to donated inputs,
-                # checked for consumption right after the first call —
-                # runtime proof the buffer aliases took effect.
                 probes = [xb, yb] + jax.tree.leaves(params)[:1] \
                     + jax.tree.leaves(opt_state)[:1]
             # Stamps AFTER staging (the slab transfer is input time, not
-            # epoch execute time) and INSIDE the hold — same MFU-clock
-            # discipline as tune/trainable.py's resident loop.
+            # epoch execute time) — same MFU-clock discipline as
+            # tune/trainable.py's resident loop.
             c0 = tracker.thread_seconds()
-            t0 = _time.monotonic()
+            t0 = time.monotonic()
             params, opt_state, batch_stats, train_loss = train_epoch(
                 params, opt_state, batch_stats, xb, yb, epoch_key
             )
             metrics = evaluate(params, batch_stats, xv, yv, mask)
+            # jit returns futures: the scalar readbacks are the sync.
             train_loss = float(train_loss)
             metrics = {k: float(v) for k, v in metrics.items()}
             exec_s = max(
-                _time.monotonic() - t0
+                time.monotonic() - t0
                 - (tracker.thread_seconds() - c0),
                 1e-9,
             )
             if audit_donation:
                 audit_donation = False
-                consumed = sum(
-                    1 for a in probes
-                    if isinstance(a, jax.Array) and a.is_deleted()
-                )
-                if consumed:
-                    get_compile_counters().add(
-                        "donation_aliased_buffers", consumed
-                    )
-        record = {
-            "epoch": epoch,
-            "train_loss": train_loss,
-            "lr": lr_now,
-            "steps": step_count,
-            "num_devices": len(devices),
-            "mesh_shape": dict(mesh_shape),
-            **metrics,
-        }
-        perf_acct.annotate(record, exec_s, device=budget_device)
+                count_consumed(probes)
+        record = epoch_record(
+            perf_acct, budget_device, epoch, steps_per_epoch, train_loss,
+            lr_now, metrics, exec_s,
+            num_devices=len(devices), mesh_shape=dict(mesh_shape),
+        )
         if n_procs > 1 and bool(config.get("perf_gang_skew", True)):
             # Per-gang-member skew: allgather each member's epoch wall
             # and name a sustained straggler by PROCESS ID (counter +
             # flight dump — perf/anomaly.py).  One small collective per
-            # epoch, device traffic, so it rides the dispatch hold.
-            with dispatch_lock():
-                stragglers = mh.check_gang_skew(exec_s, label="epoch")
+            # epoch.
+            stragglers = mh.check_gang_skew(exec_s, label="epoch")
             if stragglers:
                 record["gang_stragglers"] = [
                     int(p) for p, _ in stragglers
                 ]
         checkpoint = None
-        if checkpoint_freq and (epoch + 1) % checkpoint_freq == 0:
-            # Checkpoint readback is device traffic too — same hold
-            # discipline as the epoch dispatch (utils/dispatch.py).
+        if s.checkpoint_due(epoch):
             # host_snapshot copies fully-addressable leaves and leaves
             # process-SPANNING leaves sharded: each gang member then
             # serializes exactly the shards it holds (ckpt/format.py).
-            with dispatch_lock():
-                checkpoint = {
-                    "params": mh.host_snapshot(params),
-                    "opt_state": mh.host_snapshot(opt_state),
-                    "batch_stats": mh.host_snapshot(batch_stats),
-                    "epoch": epoch,
-                }
+            checkpoint = {
+                "params": mh.host_snapshot(params),
+                "opt_state": mh.host_snapshot(opt_state),
+                "batch_stats": mh.host_snapshot(batch_stats),
+                "epoch": epoch,
+            }
         session.report(record, checkpoint=checkpoint)
 
     return None

@@ -12,7 +12,7 @@ Three layers of enforcement:
 * **lock order** — the runtime recorder (enabled suite-wide by conftest's
   ``DML_LOCK_ORDER=1``) sees a deliberately inverted acquisition as a
   cycle, and the union graph across the instrumented
-  executor/cluster/serve/ckpt/dispatch locks stays acyclic.
+  executor/cluster/serve/ckpt locks stays acyclic.
 """
 
 import ast
@@ -309,13 +309,10 @@ def test_instrumented_subsystems_record_and_stay_acyclic(tmp_path):
     mem.write_bytes("mem://fix/blob", b"bytes")
     assert mem.read_bytes("mem://fix/blob") == b"bytes"
 
-    # dispatch + cluster + executor-side liveness primitives
-    from distributed_machine_learning_tpu.utils import dispatch
+    # cluster + executor-side liveness primitives
     from distributed_machine_learning_tpu.tune import cluster
     from distributed_machine_learning_tpu import liveness
 
-    with dispatch._LOCK:
-        pass
     with cluster._SEEN_KEYS_LOCK:
         pass
     dog = liveness.DispatchWatchdog(1.0)
@@ -326,7 +323,7 @@ def test_instrumented_subsystems_record_and_stay_acyclic(tmp_path):
     seen = rec.roles_seen
     for role in (
         "ckpt.writer", "ckpt.metrics", "serve.batcher.queue",
-        "serve.batcher.stats", "serve.breaker", "chaos.plan", "dispatch",
+        "serve.batcher.stats", "serve.breaker", "chaos.plan",
         "cluster.seen_keys", "liveness.watchdog", "liveness.heartbeat",
         "tune.storage.mem",
     ):

@@ -29,37 +29,44 @@ def test_enable_persistent_cache_idempotent(tmp_path):
 def test_identical_arch_trials_hit_cache(tiny_data, tmp_path):
     """Trial #2 of an identical architecture reports ~zero backend compile.
 
-    max_concurrent=1 serializes the trials so trial 2's compile request can
+    The trials run one after the other so trial 2's compile request can
     see trial 1's cache entries (concurrent compiles of the same program
-    race and both miss).  share_programs=False pins the test to the
-    PERSISTENT-cache layer: under the default cohort cache trial 2
+    race and both miss).  Dropping the cohort's program cache between
+    them pins the test to the PERSISTENT-cache layer: with it trial 2
     compiles (and traces) nothing at all, so there would be no cache
     lookup to observe — that stronger behavior has its own test
     (test_cohort_program_cache_builds_once_per_architecture).
     """
     train, val = tiny_data
     cache = str(tmp_path / "xla")
-    analysis = tune.run(
-        tune.with_parameters(tune.train_regressor, train_data=train, val_data=val),
-        {
-            "model": "mlp",
-            "hidden_sizes": (16,),
-            "learning_rate": tune.loguniform(1e-3, 1e-2),
-            "num_epochs": 2,
-            "batch_size": 32,
-            "lr_schedule": "constant",
-            "share_programs": False,
-        },
-        metric="validation_loss",
-        num_samples=2,
-        max_concurrent=1,
-        storage_path=str(tmp_path / "results"),
-        compile_cache_dir=cache,
-        verbose=0,
-    )
+
+    def one_trial(name):
+        analysis = tune.run(
+            tune.with_parameters(
+                tune.train_regressor, train_data=train, val_data=val
+            ),
+            {
+                "model": "mlp",
+                "hidden_sizes": (16,),
+                "learning_rate": tune.loguniform(1e-3, 1e-2),
+                "num_epochs": 2,
+                "batch_size": 32,
+                "lr_schedule": "constant",
+            },
+            metric="validation_loss",
+            num_samples=1,
+            storage_path=str(tmp_path / "results"),
+            name=name,
+            compile_cache_dir=cache,
+            verbose=0,
+        )
+        return analysis.trials[0].last_result
+
+    tune.clear_cohort_program_cache()
+    r1 = one_trial("first")
     assert cc.cache_entry_count() > 0  # programs landed on disk
-    t1, t2 = analysis.trials
-    r1, r2 = t1.last_result, t2.last_result
+    tune.clear_cohort_program_cache()
+    r2 = one_trial("second")
     # compile accounting is stamped into every record
     assert "compile_time_s" in r1 and "compile_cache_hits" in r1
     assert r1["compile_time_s"] > 0

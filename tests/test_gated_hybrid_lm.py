@@ -558,6 +558,21 @@ def test_the_cell_refuses_a_program_without_its_family():
         train_lm.require_program(dict(cfg, loss_function="absent"))
 
 
+def test_token_loss_under_streaming_input_is_refused_at_build():
+    """The prefetch ring stages every array in the compute dtype and its
+    evaluator is the regression one: a language model there would train on
+    ids cast to floats, so the trial says so before it builds anything."""
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, 97, size=(8, 17)).astype(np.int32)
+    data = Dataset(tokens[:, :-1], tokens[:, 1:])
+    config = dict(TRIAL, loss_function="cross_entropy", learning_rate=1e-3,
+                  num_epochs=1, batch_size=4, input_mode="streaming")
+    with tune.standalone(), pytest.raises(
+        ValueError, match="cross_entropy.*input_mode='streaming'"
+    ):
+        tune.train_regressor(config, train_data=data, val_data=data)
+
+
 def test_snapshot_host_fallback_writes_the_same_bytes(tmp_path, monkeypatch):
     tree = {
         "params": {"w": jnp.arange(12.0).reshape(3, 4), "b": jnp.ones(4)},

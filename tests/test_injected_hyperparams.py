@@ -124,74 +124,142 @@ def test_trainable_injected_and_baked_paths_agree():
                                rtol=1e-4)
 
 
-def test_restore_overrides_hyperparams_from_config():
-    """A restored opt_state (e.g. a PBT peer's) must adopt THIS config's
-    lr/wd — set_injected_hyperparams over the restored slots."""
-    shape = get_schedule("constant", learning_rate=1.0)
-    tx = make_injected_optimizer("adam", shape)
-    st = set_injected_hyperparams(tx.init(_params()), 1e-3, 0.0)
-    st2 = set_injected_hyperparams(st, 2e-2, 3e-4)  # explore perturbed
-    assert float(st2.hyperparams["learning_rate"]) == pytest.approx(2e-2)
-    assert float(st2.hyperparams["weight_decay"]) == pytest.approx(3e-4)
+INPUT_MODES = ("resident", "streaming")
 
 
-def test_legacy_baked_checkpoint_restores_under_injected_default():
-    """A checkpoint written by the pre-injection (baked) optimizer layout
-    must still restore: the trainable detects the pytree mismatch and
-    falls back to the baked chain for that incarnation (review r5)."""
+def _run_capturing(config, train, val, checkpoint=None):
+    """``train_regressor`` under a bare session: its records and its
+    checkpoints, the latter copied to the host as they are reported (the
+    next epoch's donated buffers reuse those arrays; the real executor's
+    writer copies too)."""
     from distributed_machine_learning_tpu import tune
     from distributed_machine_learning_tpu.tune import session as sess_mod
 
-    train, val = _tiny_data()
-    base = {
-        "model": "mlp", "hidden_sizes": (8,), "learning_rate": 5e-3,
-        "num_epochs": 2, "batch_size": 16, "optimizer": "adam",
-        "seed": 3, "lr_schedule": "constant",
-    }  # noqa: E501 — jax/np imported at module top
-    # 1) Produce a BAKED-layout checkpoint (inject disabled).
-    saved = {}
+    records, checkpoints = [], []
 
-    def capture_report(metrics, checkpoint=None):
-        if checkpoint is not None and "ckpt" not in saved:
-            # Copy to host NOW: the next epoch's donated buffers reuse
-            # these arrays (the real executor's writer does the same).
-            saved["ckpt"] = jax.tree.map(
+    def report(metrics, ckpt=None):
+        records.append(dict(metrics))
+        if ckpt is not None:
+            checkpoints.append(jax.tree.map(
                 lambda a: np.asarray(a) if isinstance(a, jax.Array) else a,
-                checkpoint)
+                ckpt))
         return "continue"
 
     sess_mod.set_session(sess_mod.Session(
-        trial=None, report_fn=capture_report,
-        checkpoint_loader=lambda: None))
+        trial=sess_mod._StandaloneTrial(), report_fn=report,
+        checkpoint_loader=lambda: checkpoint))
     try:
-        tune.train_regressor(dict(base, inject_hyperparams=False),
-                             train_data=train, val_data=val)
+        tune.train_regressor(config, train_data=train, val_data=val)
     finally:
         sess_mod.set_session(None)
-    assert "ckpt" in saved
-    # Round-trip through the real serialization: production checkpoints
-    # arrive as msgpack state-dicts, not live pytrees.
+    return records, checkpoints
+
+
+def _as_stored(checkpoint):
+    """Through the real serialization: production checkpoints arrive as
+    msgpack state-dicts, not live pytrees."""
     import tempfile
 
     from distributed_machine_learning_tpu.tune import checkpoint as ckpt_lib
 
     with tempfile.TemporaryDirectory() as d:
-        path = ckpt_lib.save_checkpoint(d + "/legacy.msgpack", saved["ckpt"])
-        saved["ckpt"] = ckpt_lib.load_checkpoint(path)
+        path = ckpt_lib.save_checkpoint(d + "/ckpt.msgpack", checkpoint)
+        return ckpt_lib.load_checkpoint(path)
 
+
+@pytest.mark.parametrize("input_mode", INPUT_MODES)
+def test_restore_overrides_hyperparams_from_config(input_mode):
+    """A restored opt_state (e.g. a PBT peer's) must adopt THIS config's
+    lr/wd — set_injected_hyperparams over the restored slots."""
+    train, val = _tiny_data()
+    base = {
+        "model": "mlp", "hidden_sizes": (8,), "learning_rate": 1e-3,
+        "num_epochs": 1, "batch_size": 16, "optimizer": "adamw",
+        "seed": 3, "lr_schedule": "constant", "input_mode": input_mode,
+    }
+    _, peer = _run_capturing(base, train, val)
+    slots = peer[-1]["opt_state"].hyperparams
+    assert float(slots["learning_rate"]) == pytest.approx(1e-3)
+    explored = dict(base, learning_rate=2e-2, weight_decay=3e-4,
+                    num_epochs=2)  # explore perturbed
+    records, mine = _run_capturing(
+        explored, train, val, checkpoint=_as_stored(peer[-1])
+    )
+    assert [r["epoch"] for r in records] == [1]  # resumed after the peer's
+    slots = mine[-1]["opt_state"].hyperparams
+    assert float(slots["learning_rate"]) == pytest.approx(2e-2)
+    assert float(slots["weight_decay"]) == pytest.approx(3e-4)
+
+
+@pytest.mark.parametrize("input_mode", INPUT_MODES)
+def test_legacy_baked_checkpoint_restores_under_injected_default(input_mode):
+    """A checkpoint written by the pre-injection (baked) optimizer layout
+    must still restore: the trainable detects the pytree mismatch and
+    falls back to the baked chain for that incarnation (review r5)."""
+    train, val = _tiny_data()
+    base = {
+        "model": "mlp", "hidden_sizes": (8,), "learning_rate": 5e-3,
+        "num_epochs": 2, "batch_size": 16, "optimizer": "adam",
+        "seed": 3, "lr_schedule": "constant", "input_mode": input_mode,
+    }
+    # 1) Produce a BAKED-layout checkpoint (inject disabled).
+    _, baked = _run_capturing(
+        dict(base, inject_hyperparams=False), train, val
+    )
     # 2) Resume under the injected DEFAULT: must not raise, must continue
-    # from the stored epoch (exactly one more epoch of reports).
-    seen = []
-    sess_mod.set_session(sess_mod.Session(
-        trial=None,
-        report_fn=lambda m, c=None: (seen.append(dict(m)), "continue")[1],
-        checkpoint_loader=lambda: saved["ckpt"]))
-    try:
-        tune.train_regressor(dict(base), train_data=train, val_data=val)
-    finally:
-        sess_mod.set_session(None)
+    # from the stored epoch (exactly one more epoch of reports), and on
+    # the baked chain, whose state is what the next checkpoint carries.
+    seen, after = _run_capturing(
+        dict(base), train, val, checkpoint=_as_stored(baked[0])
+    )
     assert len(seen) == 1  # resumed at epoch 2 of 2
     assert np.isfinite(seen[0]["validation_loss"])
+    assert not hasattr(after[-1]["opt_state"], "hyperparams")
+
+
+@pytest.mark.parametrize("name,injected", [
+    ("adam", True), ("sgd", True), ("lamb", False),
+])
+def test_build_optimizer_is_the_chain_each_trainable_built(name, injected):
+    """The one builder against the registry called as the three trainables
+    used to call it: the same state tree from ``init`` and the same first
+    update."""
+    from distributed_machine_learning_tpu.tune.trainable import (
+        build_optimizer,
+        trial_settings,
+    )
+
+    config = {
+        "optimizer": name, "learning_rate": 3e-3, "weight_decay": 1e-3,
+        "momentum": 0.9, "gradient_clipping": 0.1, "warmup_steps": 2,
+    }
+    settings = trial_settings(config)
+    assert settings.injected == injected
+    tx, shape = build_optimizer(settings, 8, settings.injected)
+
+    def schedule(peak):
+        return get_schedule("warmup_linear_decay", learning_rate=peak,
+                            warmup_steps=2, total_steps=8)
+
+    if injected:
+        old = make_injected_optimizer(
+            name, schedule(1.0), momentum=0.9, gradient_clipping=0.1
+        )
+    else:
+        old = make_optimizer(
+            name, learning_rate=schedule(3e-3), weight_decay=1e-3,
+            momentum=0.9, gradient_clipping=0.1, accumulate_grad_batches=1,
+        )
+    assert float(shape(1)) == float(schedule(1.0)(1))
+    states = [t.init(_params()) for t in (tx, old)]
+    if injected:
+        states = [set_injected_hyperparams(s, 3e-3, 1e-3) for s in states]
+    assert jax.tree.structure(states[0]) == jax.tree.structure(states[1])
+    updates = [
+        t.update(_grads(), s, _params()) for t, s in zip((tx, old), states)
+    ]
+    for a, b in zip(jax.tree.leaves(updates[0]), jax.tree.leaves(updates[1])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_trial_seed_varies_init_weights():

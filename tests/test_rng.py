@@ -49,18 +49,39 @@ def test_rbg_and_threefry_key_data_shapes_differ():
         )  # wrong-width data must not silently wrap
 
 
-def test_trainable_checkpoint_records_and_restores_rng_impl(tmp_path):
-    """A trial's checkpoint records the resolved dropout-PRNG impl, and a
-    restore reuses the RECORDED impl even when the restoring config/backend
-    would resolve differently (cross-backend resume must not mix stream
-    families mid-trial)."""
+def _checkpoints_of(config, train, val, checkpoint=None):
     from distributed_machine_learning_tpu import tune
-    from distributed_machine_learning_tpu.data import dummy_regression_data
     from distributed_machine_learning_tpu.tune import session
+
+    reports = []
+    session.set_session(session.Session(
+        session._StandaloneTrial(),
+        lambda rec, ck=None: reports.append((rec, ck)),
+        lambda: checkpoint,
+    ))
+    try:
+        tune.train_regressor(config, train_data=train, val_data=val)
+    finally:
+        session.set_session(None)
+    return [c for _, c in reports if c is not None]
+
+
+def _stored(tmp_path, checkpoint, drop=()):
     from distributed_machine_learning_tpu.tune.checkpoint import (
         load_checkpoint,
         save_checkpoint,
     )
+
+    path = str(tmp_path / "ck.msgpack")
+    save_checkpoint(
+        path, {k: v for k, v in checkpoint.items() if k not in drop}
+    )
+    return load_checkpoint(path)
+
+
+@pytest.fixture(scope="module")
+def rng_trial():
+    from distributed_machine_learning_tpu.data import dummy_regression_data
 
     train, val = dummy_regression_data(
         num_samples=64, seq_len=6, num_features=3
@@ -68,30 +89,58 @@ def test_trainable_checkpoint_records_and_restores_rng_impl(tmp_path):
     config = {"model": "mlp", "learning_rate": 1e-3, "num_epochs": 1,
               "batch_size": 32, "dropout": 0.1, "rng_impl": "rbg",
               "seed": 3}
+    return config, train, val
 
-    def run(cfg, checkpoint=None):
-        reports = []
-        session.set_session(session.Session(
-            None,
-            lambda rec, ck=None: reports.append((rec, ck)),
-            lambda: checkpoint,
-        ))
-        try:
-            tune.train_regressor(cfg, train_data=train, val_data=val)
-        finally:
-            session.set_session(None)
-        return [c for _, c in reports if c is not None]
 
-    ckpts = run(config)
+@pytest.mark.parametrize("input_mode", ["resident", "streaming"])
+def test_trainable_checkpoint_records_and_restores_rng_impl(
+    tmp_path, rng_trial, input_mode
+):
+    """A trial's checkpoint records the resolved dropout-PRNG impl, and a
+    restore reuses the RECORDED impl even when the restoring config/backend
+    would resolve differently (cross-backend resume must not mix stream
+    families mid-trial)."""
+    config, train, val = rng_trial
+    config = dict(config, input_mode=input_mode)
+    ckpts = _checkpoints_of(config, train, val)
     assert ckpts and ckpts[-1]["rng_impl"] == "rbg"
 
     # Restore under a config whose own resolution differs (rng_impl absent:
     # auto -> threefry on CPU). The recorded impl must win; the new
     # checkpoint re-records the inherited impl, and training completes
     # (rbg-wide epoch keys keep working).
-    path = str(tmp_path / "ck.msgpack")
-    save_checkpoint(path, ckpts[-1])
     cfg2 = dict(config, num_epochs=2)
     del cfg2["rng_impl"]
-    ckpts2 = run(cfg2, checkpoint=load_checkpoint(path))
+    ckpts2 = _checkpoints_of(
+        cfg2, train, val, checkpoint=_stored(tmp_path, ckpts[-1])
+    )
     assert ckpts2 and ckpts2[-1]["rng_impl"] == "rbg"
+
+
+@pytest.mark.parametrize("input_mode", ["resident", "streaming"])
+def test_checkpoint_without_rng_impl_continues_under_the_raw_config_value(
+    tmp_path, rng_trial, input_mode, monkeypatch
+):
+    """A checkpoint from before the impl was recorded: its epochs were
+    drawn under the RAW config value (there was no auto-resolution then),
+    so the restore continues with that and not with what this backend
+    would resolve to."""
+    from distributed_machine_learning_tpu.tune import trainable
+
+    config, train, val = rng_trial
+    config = dict(config, input_mode=input_mode)
+    old = _stored(
+        tmp_path, _checkpoints_of(config, train, val)[-1], drop=("rng_impl",)
+    )
+    # As on a TPU: whatever the config says resolves to the hardware RNG.
+    monkeypatch.setattr(trainable, "resolve_rng_impl", lambda config: "rbg")
+    unset = dict(config, num_epochs=2)
+    del unset["rng_impl"]
+    assert _checkpoints_of(unset, train, val)[-1]["rng_impl"] == "rbg"
+    resumed = _checkpoints_of(unset, train, val, checkpoint=old)
+    assert [c["epoch"] for c in resumed] == [1]
+    assert resumed[-1]["rng_impl"] == ""  # the raw value: jax's default
+    kept = _checkpoints_of(
+        dict(config, num_epochs=2), train, val, checkpoint=old
+    )
+    assert kept[-1]["rng_impl"] == "rbg"

@@ -203,27 +203,3 @@ def test_resnet18_four_device_trial(tmp_path):
     assert t.status == TrialStatus.TERMINATED
     assert t.last_result["num_devices"] == 4
     assert np.isfinite(t.last_result["validation_loss"])
-
-
-def test_sharded_trial_under_dispatch_serialization(data, tmp_path,
-                                                    monkeypatch):
-    """The sharded trainable's locked device-call sections (init, epoch
-    with in-lock staging + readback sync, checkpoint readback) must not
-    deadlock or change results when serialization is forced on
-    (utils/dispatch.py)."""
-    from distributed_machine_learning_tpu.utils import dispatch
-
-    monkeypatch.setattr(dispatch, "_resolved", None)
-    monkeypatch.setenv("DML_SERIALIZE_DISPATCH", "1")
-    try:
-        analysis = _run(
-            data, dict(BASE_CONFIG), storage_path=str(tmp_path),
-            resources_per_trial={"devices": 4},
-        )
-        t = analysis.trials[0]
-        assert t.status == TrialStatus.TERMINATED
-        assert t.training_iteration == 4
-        losses = t.metric_history("validation_loss")
-        assert losses[-1] < losses[0]
-    finally:
-        monkeypatch.setattr(dispatch, "_resolved", None)

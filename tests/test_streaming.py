@@ -359,6 +359,46 @@ def test_streaming_resident_bit_parity_e2e(small_data, tmp_results):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_streaming_trial_emits_the_resident_trials_spans(small_data):
+    """Both loops share their set-up, so a streaming trial's spans carry
+    the resident trial's names under the same parents (PERF.md section 3
+    reads them by name)."""
+    from distributed_machine_learning_tpu import obs
+
+    train, val = small_data
+    shared = {"trial.setup", "trial.build", "trial.init_or_restore",
+              "epoch", "epoch.dispatch", "epoch.readback"}
+
+    def edges(mode):
+        tracer = obs.Tracer()
+        obs.install_tracer(tracer)
+        try:
+            _standalone_run(
+                tune.train_regressor,
+                {"model": "mlp", "hidden_sizes": (8,), "learning_rate": 1e-3,
+                 "num_epochs": 2, "batch_size": 32, "input_mode": mode},
+                train, val,
+            )
+        finally:
+            obs.install_tracer(None)
+        records = tracer.records()
+        names = {  # add_complete records (compile events) have no id
+            r["args"]["span_id"]: r["name"]
+            for r in records if "span_id" in r["args"]
+        }
+        return sorted(
+            (r["name"], names.get(r["args"].get("parent_id")))
+            for r in records if r["name"] in shared
+        )
+
+    resident = edges("resident")
+    assert ("trial.build", "trial.setup") in resident
+    assert ("trial.init_or_restore", "trial.setup") in resident
+    assert resident.count(("epoch.dispatch", "epoch")) == 2
+    assert resident.count(("epoch.readback", "epoch")) == 2
+    assert edges("streaming") == resident
+
+
 # ---------------------------------------------------------------------------
 # failure surfaces: producer crash, slow producer
 # ---------------------------------------------------------------------------

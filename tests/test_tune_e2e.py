@@ -108,6 +108,43 @@ def test_concurrent_trials_use_multiple_devices(small_data, tmp_results):
     assert overlaps > 0
 
 
+def test_eight_trial_threads_on_one_device_match_one_at_a_time(
+    small_data, tmp_results
+):
+    """Eight trial threads dispatching to ONE device with no lock of the
+    framework's own between them: every trial reports what it reports
+    when the same eight run one after the other."""
+    import jax
+
+    device = jax.devices()[0]
+
+    def sweep(name, concurrent):
+        return tune.run(
+            _trainable(small_data),
+            {**SMOKE_SPACE, "dropout": 0.1, "seed": tune.randint(0, 1000)},
+            metric="validation_loss",
+            num_samples=8,
+            seed=5,
+            devices=[device] * concurrent,
+            max_concurrent=concurrent,
+            storage_path=tmp_results,
+            name=name,
+            verbose=0,
+        )
+
+    together, alone = sweep("together", 8), sweep("alone", 1)
+    assert together.num_terminated() == alone.num_terminated() == 8
+    windows = [(t.started_at, t.finished_at) for t in together.trials]
+    assert any(
+        s1 < e2 and s2 < e1
+        for i, (s1, e1) in enumerate(windows) for (s2, e2) in windows[i + 1:]
+    )
+    for a, b in zip(together.trials, alone.trials):
+        assert a.config == b.config
+        for metric in ("train_loss", "validation_loss", "lr"):
+            assert a.metric_history(metric) == b.metric_history(metric)
+
+
 def test_grid_search_enumerates_product(small_data, tmp_results):
     space = {
         **SMOKE_SPACE,
