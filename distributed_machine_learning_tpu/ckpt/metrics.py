@@ -34,6 +34,8 @@ class CheckpointMetrics:
         "save_bytes",
         "save_wall_s",
         "save_block_s",
+        "snapshot_dispatches",
+        "snapshot_leaves",
         "chunks_written",
         "save_errors",
         "async_saves",

@@ -36,9 +36,10 @@ import queue
 import re
 import threading
 import time
-from typing import Any, Deque, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import msgpack
 import numpy as np
 from flax import serialization
@@ -540,6 +541,16 @@ def import_orbax(src_dir: str) -> Dict[str, Any]:
         return ckptr.restore(_abspath_unless_remote(src_dir))
 
 
+@jax.jit
+def _copy_on_device(leaves):
+    """A fresh buffer of every array of the list, on the devices and in
+    the layout of its original, in one program (the list shares a set of
+    devices).  No argument is donated, so no result may alias one.  At
+    module level, so that jax's own cache (shapes, dtypes, shardings)
+    serves every writer of the process; it holds no array."""
+    return [jnp.copy(x) for x in leaves]
+
+
 class AsyncCheckpointWriter:
     """Overlap checkpoint writes with training (orbax-style async save).
 
@@ -559,6 +570,24 @@ class AsyncCheckpointWriter:
       arrays out from under the serializer ("Array has been deleted" —
       donation is a no-op on CPU, so only real TPU runs hit it). Mutable
       numpy leaves are host-copied for the same reason.
+    * The device-side copy is ONE dispatch a device set, not one a leaf:
+      the jax leaves are grouped by the devices that hold them
+      (``x.sharding.device_set``) and each group goes through one compiled
+      program that returns a fresh buffer of every leaf
+      (:func:`_copy_on_device`: a single-chip trial is one group, a trial
+      sharded over a mesh one group over the mesh, a tree that spans
+      unrelated devices one dispatch each).  Nothing is donated to that
+      program, so no result can alias an argument — a copy that shared
+      its original's buffer would be deleted with it — and every copy
+      keeps its original's sharding, so the sharded format writes the
+      chunks it always did.  The copy is enqueued before ``submit``
+      returns, that is before the caller can dispatch the step that
+      donates the originals.  jax keys the program on the leaves' shapes,
+      dtypes and shardings: a sweep compiles it once a shape class, and a
+      later writer in the process finds it.
+    * A state that leaves its device no room for a second copy is read to
+      the host inside ``submit`` instead, leaf by leaf (``to_host`` on the
+      ``report.ckpt_snapshot`` span; no dispatch).
     * A reader who might race a pending write (retry restore, PBT exploit
       of a peer's checkpoint) calls ``wait(path)`` first; the threaded
       executor routes every restore through it. Cross-process restores
@@ -607,11 +636,33 @@ class AsyncCheckpointWriter:
 
     @staticmethod
     def _snapshot_leaf(x):
-        # jax.Array.copy() is a device-side copy: donation of the original
-        # cannot delete it, and the D2H read stays on the writer thread.
-        if isinstance(x, (jax.Array, np.ndarray)):
-            return x.copy()
-        return x
+        """A host leaf's own copy (the caller may write into its numpy
+        buffers); jax leaves are copied together, Python leaves pass."""
+        return x.copy() if isinstance(x, np.ndarray) else x
+
+    def _snapshot_on_device(self, leaves: List[Any]) -> Tuple[List[Any], int]:
+        """``leaves`` with every jax leaf replaced by a device-side copy
+        and every numpy leaf by a host copy, and the number of dispatches
+        that took: one a set of devices.  The D2H read of the copies stays
+        on the writer thread, and donation of the originals cannot delete
+        them."""
+        groups: Dict[Any, List[int]] = {}
+        for i, x in enumerate(leaves):
+            if isinstance(x, jax.Array):
+                groups.setdefault(
+                    frozenset(x.sharding.device_set), []
+                ).append(i)
+        snapshot = [self._snapshot_leaf(x) for x in leaves]
+        for devices, members in groups.items():
+            copies = _copy_on_device([leaves[i] for i in members])
+            for i, copy in zip(members, copies):
+                # Over a mesh jit names the layout it kept in its own
+                # words (a spec without its trailing None): the snapshot
+                # carries the original's, which re-wraps the same buffers.
+                if len(devices) > 1 and copy.sharding != leaves[i].sharding:
+                    copy = jax.device_put(copy, leaves[i].sharding)
+                snapshot[i] = copy
+        return snapshot, len(groups)
 
     @staticmethod
     def _device_has_room_for(leaves) -> bool:
@@ -649,20 +700,24 @@ class AsyncCheckpointWriter:
         leaves, treedef = jax.tree.flatten(tree)
         with obs.span("report.ckpt_snapshot", {"leaves": len(leaves)}) as sp:
             if self._device_has_room_for(leaves):
-                snapshot = [self._snapshot_leaf(x) for x in leaves]
+                snapshot, dispatches = self._snapshot_on_device(leaves)
             else:
                 # A state that fills the chip (parameters and optimizer
                 # state of a model sized to it) has no room for its copy:
                 # read it to the host here, leaf by leaf, before the
                 # caller's next step donates the buffers.
                 sp.set("to_host", True)
+                dispatches = 0
                 snapshot = [
                     np.asarray(x) if isinstance(x, jax.Array)
                     else self._snapshot_leaf(x)
                     for x in leaves
                 ]
+            sp.set("dispatches", dispatches)
             snapshot = treedef.unflatten(snapshot)
         metrics.add("save_block_s", time.time() - t0)
+        metrics.add("snapshot_dispatches", dispatches)
+        metrics.add("snapshot_leaves", len(leaves))
         done = threading.Event()
         with self._lock:
             self._pending[path] = done

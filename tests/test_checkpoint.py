@@ -320,19 +320,20 @@ def test_retried_streamed_write_hashes_the_pass_that_landed(tmp_path):
     assert load_checkpoint(path, verify=True)["w3"][0] == 3.0
 
 
-def test_save_lets_go_of_the_tree_without_the_cycle_collector(tmp_path):
+@pytest.mark.parametrize("leaves", ["host", "device"])
+def test_save_lets_go_of_the_tree_without_the_cycle_collector(
+        tmp_path, monkeypatch, leaves):
     """A snapshot is a second copy of the state on the device: once it is
     written, reference counts alone must free it (a planner made of
     recursive closures, a reference cycle, kept five of them alive on a chip, and the next
-    epoch's program no longer fitted)."""
-    from distributed_machine_learning_tpu.tune.checkpoint import (
-        AsyncCheckpointWriter,
-    )
+    epoch's program no longer fitted).  ``device``: the same of a snapshot
+    that one dispatch made, which no cache of the copy program may hold."""
+    from distributed_machine_learning_tpu.tune import checkpoint as cl
 
     tree = {"w": np.ones(70000, np.float32), "d": jnp.ones((300, 300)),
             "n": {"b": np.ones(3)}}
     alive = [weakref.ref(x) for x in jax.tree.leaves(tree)]
-    writer = AsyncCheckpointWriter(log=lambda m: None)
+    writer = cl.AsyncCheckpointWriter(log=lambda m: None)
     gc.collect()
     gc.disable()
     try:
@@ -342,23 +343,298 @@ def test_save_lets_go_of_the_tree_without_the_cycle_collector(tmp_path):
         # ... and the writer's thread does not sit on the last snapshot
         # while its queue is empty.
         snapshots = []
-        real = AsyncCheckpointWriter._snapshot_leaf
+        real = cl.save_checkpoint
 
-        def noting(x):
-            snapshots.append(weakref.ref(copy := real(x)))
-            return copy
+        def noting(path, snapshot):
+            snapshots.extend(
+                weakref.ref(x) for x in jax.tree.leaves(snapshot)
+            )
+            return real(path, snapshot)
 
-        writer._snapshot_leaf = noting
-        path = writer.submit(str(tmp_path / "async.msgpack"),
-                             {"w": np.ones(70000, np.float32)})
+        monkeypatch.setattr(cl, "save_checkpoint", noting)
+        state = ({"w": np.ones(70000, np.float32)} if leaves == "host" else
+                 {"w": jnp.ones(70000), "m": {"v": jnp.ones((30, 30))}})
+        originals = [weakref.ref(x) for x in jax.tree.leaves(state)]
+        path = writer.submit(str(tmp_path / "async.msgpack"), state)
         assert writer.wait(path, timeout=30)
         deadline = time.monotonic() + 10
-        while snapshots[0]() is not None and time.monotonic() < deadline:
+        while (any(ref() is not None for ref in snapshots)
+               and time.monotonic() < deadline):
             time.sleep(0.01)
-        assert snapshots[0]() is None
+        assert snapshots and [ref() for ref in snapshots] == (
+            [None] * len(snapshots)
+        )
+        # The snapshot was another object than the state, which is the
+        # caller's to drop.
+        assert all(ref() is not None for ref in originals)
+        del state
+        assert [ref() for ref in originals] == [None] * len(originals)
     finally:
         gc.enable()
         writer.close()
+
+
+# -- the snapshot: one dispatch a device set -----------------------------------
+
+
+def _mesh_2x4():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()).reshape(2, 4), ("dp", "tp"))
+
+
+def _snapshot_cases():
+    """name -> (a maker of the tree, the checkpoint's file name)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    def single():
+        return {"params": {"w": jnp.arange(70000, dtype=jnp.float32),
+                           "b": jnp.ones((2, 3), jnp.bfloat16)},
+                "opt": {"count": jnp.int32(3)}, "epoch": 1}
+
+    def over_mesh():
+        mesh = _mesh_2x4()
+        specs = {"rep": P(), "dp": P("dp"), "dp_none": P("dp", None),
+                 "tp": P(None, "tp"), "both": P("dp", "tp"),
+                 "joined": P(("dp", "tp"))}
+        tree = {
+            name: jax.device_put(
+                jnp.arange(64 * 8, dtype=jnp.float32).reshape(64, 8) + i,
+                NamedSharding(mesh, spec),
+            )
+            for i, (name, spec) in enumerate(specs.items())
+        }
+        tree["count"] = jax.device_put(
+            jnp.int32(7), NamedSharding(mesh, P())
+        )
+        return {"params": tree, "epoch": 2}
+
+    def mixed():
+        devices = jax.devices()
+        return {
+            "on0": jax.device_put(jnp.arange(5000.0), devices[0]),
+            "on3": {"w": jax.device_put(jnp.ones((40, 40)), devices[3]),
+                    "v": jax.device_put(jnp.zeros(7, jnp.int8), devices[3])},
+            "host": {"buf": np.arange(6, dtype=np.float32),
+                     "big": np.full(70000, 2.0, np.float32)},
+            "epoch": 4, "lr": 0.5, "rng_impl": "threefry", "none": None,
+        }
+
+    return {
+        "single-msgpack": (single, "ckpt_000001.msgpack"),
+        "mesh-msgpack": (over_mesh, "ckpt_000001.msgpack"),
+        "mesh-sharded": (over_mesh, "gen_000001"),
+        "mixed-msgpack": (mixed, "ckpt_000001.msgpack"),
+    }
+
+
+def _buffer_pointers(x):
+    return [sh.data.unsafe_buffer_pointer() for sh in x.addressable_shards]
+
+
+def _submit_noting_snapshot(tmp_path, monkeypatch, case):
+    """Submit the case's tree as a trial does (the originals deleted right
+    after, as the next step's donation deletes them; numpy leaves written
+    into), wait, and hand back what the tree held before, what the
+    writer's thread was given (per device leaf: buffer pointers and
+    sharding; nothing that keeps the snapshot alive) and what the file
+    reads back as."""
+    from distributed_machine_learning_tpu.tune import checkpoint as cl
+
+    make, name = _snapshot_cases()[case]
+    tree = make()
+    leaves = jax.tree.leaves(tree, is_leaf=lambda x: x is None)
+    before = {
+        "host": jax.tree.map(
+            lambda x: np.array(x) if hasattr(x, "shape") else x, tree
+        ),
+        "device": [(_buffer_pointers(x), x.sharding) for x in leaves
+                   if isinstance(x, jax.Array)],
+    }
+    given = []
+    real = cl.save_checkpoint
+
+    def noting(path, snapshot):
+        given.extend(
+            (_buffer_pointers(x), x.sharding)
+            for x in jax.tree.leaves(snapshot) if isinstance(x, jax.Array)
+        )
+        return real(path, snapshot)
+
+    monkeypatch.setattr(cl, "save_checkpoint", noting)
+    writer = cl.AsyncCheckpointWriter(log=lambda m: None)
+    try:
+        path = writer.submit(str(tmp_path / name), tree)
+        for x in leaves:
+            if isinstance(x, jax.Array):
+                x.delete()  # what donate_argnums does to the next step's
+            elif isinstance(x, np.ndarray):
+                x[...] = 99  # the trainable reuses its host buffers
+        assert writer.wait(path, timeout=60)
+    finally:
+        writer.close()
+    return before, given, load_checkpoint(path)
+
+
+@pytest.mark.parametrize("case", sorted(_snapshot_cases()))
+def test_snapshot_is_a_copy_that_outlives_donated_originals(
+        tmp_path, monkeypatch, case):
+    """No buffer of the snapshot is a buffer of the state, so the state's
+    deletion (donation, stood in for by ``delete()``) and the caller's
+    writes into its numpy leaves leave the file the tree as submitted."""
+    before, given, loaded = _submit_noting_snapshot(
+        tmp_path, monkeypatch, case
+    )
+    assert len(given) == len(before["device"]) > 0
+    for (was, _), (now, _) in zip(before["device"], given):
+        assert len(now) == len(was) and not set(now) & set(was)
+    from flax import serialization
+
+    _assert_trees_equal(loaded, serialization.to_state_dict(before["host"]))
+
+
+@pytest.mark.parametrize("case", sorted(_snapshot_cases()))
+def test_snapshot_leaf_keeps_its_originals_sharding(
+        tmp_path, monkeypatch, case):
+    """Exactly, a spec's trailing ``None`` included: a snapshot laid out
+    otherwise would change the chunks the sharded format writes."""
+    before, given, _ = _submit_noting_snapshot(tmp_path, monkeypatch, case)
+    assert [s for _, s in given] == [s for _, s in before["device"]]
+
+
+def test_snapshot_resharded_when_the_copy_comes_back_laid_out_otherwise(
+        tmp_path, monkeypatch):
+    """The copy program is free to choose its results' layout; what
+    ``submit`` hands the writer is laid out as the state was."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_machine_learning_tpu.tune import checkpoint as cl
+
+    mesh = _mesh_2x4()
+    x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
+                       NamedSharding(mesh, P("dp", "tp")))
+    monkeypatch.setattr(cl, "_copy_on_device", lambda leaves: [
+        jax.device_put(leaf.copy(), NamedSharding(mesh, P()))
+        for leaf in leaves
+    ])
+    given = []
+    monkeypatch.setattr(cl, "save_checkpoint",
+                        lambda path, tree: given.append(tree["x"].sharding))
+    writer = cl.AsyncCheckpointWriter(log=lambda m: None)
+    try:
+        assert writer.wait(writer.submit(str(tmp_path / "gen_000001"),
+                                         {"x": x}), timeout=30)
+    finally:
+        writer.close()
+    assert given == [x.sharding]
+
+
+@pytest.mark.parametrize("case,dispatches,to_host", [
+    ("single-msgpack", 1, False),
+    ("mesh-sharded", 1, False),
+    ("mixed-msgpack", 2, False),
+    ("host-leaves-only", 0, False),
+    ("no-room-on-the-device", 0, True),
+])
+def test_snapshot_span_and_counters_say_how_many_dispatches(
+        tmp_path, monkeypatch, case, dispatches, to_host):
+    """``report.ckpt_snapshot`` carries ``leaves`` (every leaf of the tree)
+    and ``dispatches`` (one a set of devices; none on the host road), and
+    the checkpoint counters sum both."""
+    from distributed_machine_learning_tpu.ckpt.metrics import get_metrics
+    from distributed_machine_learning_tpu.tune import checkpoint as cl
+
+    if case == "host-leaves-only":
+        tree, name = {"w": np.ones(5), "epoch": 3, "tag": "x"}, "c.msgpack"
+    elif case == "no-room-on-the-device":
+        tree, name = _snapshot_cases()["single-msgpack"][0](), "c.msgpack"
+        monkeypatch.setattr(cl.AsyncCheckpointWriter, "_device_has_room_for",
+                            staticmethod(lambda leaves: False))
+    else:
+        make, name = _snapshot_cases()[case]
+        tree = make()
+    n_leaves = len(jax.tree.leaves(tree))
+    writer = cl.AsyncCheckpointWriter(log=lambda m: None)
+    base = get_metrics().snapshot()
+
+    def submit_twice():
+        for i in (1, 2):
+            path = writer.submit(str(tmp_path / str(i) / name), tree)
+            assert writer.wait(path, timeout=60)
+
+    try:
+        spans = _spans_of(submit_twice)
+    finally:
+        writer.close()
+    snaps = [sp for sp in spans if sp["name"] == "report.ckpt_snapshot"]
+    assert [sp["args"]["leaves"] for sp in snaps] == [n_leaves] * 2
+    assert [sp["args"]["dispatches"] for sp in snaps] == [dispatches] * 2
+    assert [sp["args"].get("to_host", False) for sp in snaps] == [to_host] * 2
+    delta = get_metrics().delta_since(base)
+    assert delta["snapshot_dispatches"] == 2 * dispatches
+    assert delta["snapshot_leaves"] == 2 * n_leaves
+
+
+def test_snapshot_counters_reach_experiment_state_through_tune_run(tmp_path):
+    from distributed_machine_learning_tpu import tune
+
+    def trainable(config):
+        w = jnp.zeros((4, 3))
+        for epoch in range(3):
+            w = w + 1
+            tune.report(
+                {"loss": 1.0 / (epoch + 1)},
+                checkpoint={"params": {"w": w, "b": jnp.ones(3)},
+                            "host": np.arange(4), "epoch": epoch},
+            )
+
+    tune.run(trainable, {"x": 1}, metric="loss", num_samples=1,
+             storage_path=str(tmp_path), name="snap", verbose=0)
+    with open(tmp_path / "snap" / "experiment_state.json") as f:
+        counters = json.load(f)["checkpoint"]
+    assert counters["snapshot_dispatches"] == 3
+    assert counters["snapshot_leaves"] == 3 * 4
+    assert counters["saves"] == 3
+    tree = load_checkpoint(str(
+        tmp_path / "snap" / "trial_00000" / "checkpoints"
+        / "ckpt_000003.msgpack"
+    ))
+    np.testing.assert_array_equal(tree["params"]["w"], np.full((4, 3), 3.0))
+
+
+def test_second_writer_finds_the_copy_program_compiled(tmp_path):
+    """The copy program is the module's and jax keys it on the tree's
+    shapes, dtypes and shardings: a new writer (each ``tune.run`` makes
+    one) on a new state of the same structure traces and compiles nothing,
+    which is what keeps a benchmark's window at no compiles after its
+    set-up trial."""
+    from distributed_machine_learning_tpu.compilecache import get_tracker
+    from distributed_machine_learning_tpu.tune import checkpoint as cl
+
+    def state(fill):
+        return {"params": {"w": jnp.full((33, 17), fill),
+                           "b": jnp.full((17,), fill, jnp.bfloat16)},
+                "count": jnp.int32(fill), "epoch": fill}
+
+    tracker = get_tracker()
+
+    def save_through_a_new_writer(i):
+        writer = cl.AsyncCheckpointWriter(log=lambda m: None)
+        try:
+            path = writer.submit(str(tmp_path / f"ckpt_{i:06d}.msgpack"),
+                                 state(float(i)))
+            assert writer.wait(path, timeout=30)
+        finally:
+            writer.close()
+        return path
+
+    save_through_a_new_writer(1)
+    before = tracker.snapshot()
+    path = save_through_a_new_writer(2)
+    after = tracker.snapshot()
+    assert after["traces"] == before["traces"]
+    assert after["backend_compiles"] == before["backend_compiles"]
+    assert load_checkpoint(path)["params"]["w"][0, 0] == 2.0
 
 
 def test_streamed_save_holds_one_leaf_not_the_payload(tmp_path):
