@@ -3,6 +3,17 @@
 ``build_model(config)`` constructs a model from a trial config dict, deriving
 architecture fields from the config keys the reference's search spaces use
 (`/root/reference/ray-tune-hpo-regression.py:379-400`).
+
+Families (``config["model"]``): ``transformer``, ``simple_transformer``,
+``mlp``, ``cnn1d``, ``resnet18``, ``rnn`` over float windows, and two
+decoders over int32 token ids whose trial keys are the fields of their
+sizes dataclass with ``vocab_size``, ``num_layers`` and ``compute_dtype``:
+``gated_hybrid_lm`` (``HybridSizes``: Gated DeltaNet 3 : 1 gated attention
+over a top-k mixture with a shared expert) and ``cca_moe_lm`` (``CCASizes``:
+``d_model, num_heads, num_kv_heads, head_dim, rotary_dim, rope_theta,
+conv_time0, conv_time1, num_experts, top_k, expert_width, router_hidden,
+held_experts, expert_tile``; compressed convolutional attention over a
+top-1 mixture behind an MLP router, the embedding tied to the head).
 """
 
 from __future__ import annotations
@@ -12,6 +23,7 @@ from typing import Any, Dict
 
 import jax.numpy as jnp
 
+from distributed_machine_learning_tpu.models.cca_lm import CCAMoELM, CCASizes
 from distributed_machine_learning_tpu.models.cnn import CNN1DRegressor
 from distributed_machine_learning_tpu.models.hybrid_lm import (
     GatedHybridLM,
@@ -149,21 +161,43 @@ def _build_rnn(config: Dict[str, Any]):
     )
 
 
+def _sizes_from(config: Dict[str, Any], sizes_class) -> Dict[str, Any]:
+    """The config's keys that are fields of ``sizes_class``, a language
+    model's block widths; ``held_experts`` as the tuple a module takes."""
+    sizes = {
+        f.name: config[f.name]
+        for f in dataclasses.fields(sizes_class) if f.name in config
+    }
+    if sizes.get("held_experts") is not None:
+        sizes["held_experts"] = tuple(int(v) for v in sizes["held_experts"])
+    return sizes
+
+
 @models.register("gated_hybrid_lm")
 def _build_gated_hybrid_lm(config: Dict[str, Any]):
     """A decoder LM over int32 token ids (models/hybrid_lm.py).  Every size
     is a config key of ``HybridSizes``'s name; ``held_experts`` is
     ``[first id, count]`` of the experts this chip holds."""
-    sizes = {
-        f.name: config[f.name]
-        for f in dataclasses.fields(HybridSizes) if f.name in config
-    }
-    if sizes.get("held_experts") is not None:
-        sizes["held_experts"] = tuple(int(v) for v in sizes["held_experts"])
     return GatedHybridLM(
         vocab_size=int(config["vocab_size"]),
         num_layers=int(config.get("num_layers", 4)),
-        sizes=HybridSizes(**sizes),
+        sizes=HybridSizes(**_sizes_from(config, HybridSizes)),
+        dtype=compute_dtype_of(config),
+    )
+
+
+@models.register("cca_moe_lm")
+def _build_cca_moe_lm(config: Dict[str, Any]):
+    """A decoder LM over int32 token ids whose mixer is compressed
+    convolutional attention and whose feed-forward is a top-k mixture
+    behind an MLP router, the embedding tied to the head
+    (models/cca_lm.py).  Every size is a config key of ``CCASizes``'s name;
+    ``held_experts`` is ``[first id, count]`` of the experts this chip
+    holds."""
+    return CCAMoELM(
+        vocab_size=int(config["vocab_size"]),
+        num_layers=int(config.get("num_layers", 4)),
+        sizes=CCASizes(**_sizes_from(config, CCASizes)),
         dtype=compute_dtype_of(config),
     )
 
@@ -178,6 +212,8 @@ __all__ = [
     "build_model",
     "compute_dtype_of",
     "MLPRegressor",
+    "CCAMoELM",
+    "CCASizes",
     "GatedHybridLM",
     "HybridSizes",
     "MoEFF",

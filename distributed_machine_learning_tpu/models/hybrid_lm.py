@@ -167,6 +167,21 @@ def causal_attention(q, k, v, scale: float):
     return out.reshape(B, S, H, D).astype(q.dtype)
 
 
+def causal_attention_on_device(q, k, v, scale: float):
+    """Causal attention under the scope ``causal_attention``: the flash
+    kernels on a TPU, ``causal_attention`` elsewhere."""
+    with jax.named_scope("causal_attention"):
+        if not layers._on_tpu():
+            return causal_attention(q, k, v, scale)
+        # Asked for by name: the automatic route stops at head size 64
+        # (models/layers.py).
+        from distributed_machine_learning_tpu.ops.pallas_attention import (
+            flash_attention,
+        )
+
+        return flash_attention(q, k, v, scale, True)
+
+
 class GatedAttentionMixer(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -196,18 +211,7 @@ class GatedAttentionMixer(nn.Module):
             )
             for a in (q, k)
         )
-        scale = hd ** -0.5
-        with jax.named_scope("causal_attention"):
-            if layers._on_tpu():
-                # Asked for by name: the automatic route stops at head
-                # size 64 (models/layers.py).
-                from distributed_machine_learning_tpu.ops.pallas_attention import (
-                    flash_attention,
-                )
-
-                out = flash_attention(q, k, v, scale, True)
-            else:
-                out = causal_attention(q, k, v, scale)
+        out = causal_attention_on_device(q, k, v, hd ** -0.5)
         out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dtype)
         return _dense(D, "o_proj", dtype)(out.reshape(B, S, H * hd))
 
@@ -229,7 +233,16 @@ class GatedMLP(nn.Module):
 class DroplessMoE(nn.Module):
     """Top-k routing over ``num_experts`` experts of which this layer holds
     ``held_experts`` (first id, count): its own experts' part of the result
-    plus the shared expert, which every holder computes whole."""
+    plus the shared expert, which every holder computes whole.
+
+    ``router`` is the part that turns float32 tokens ``[T, d]`` into logits
+    ``[T, num_experts]``: any module, adopted under the name ``router``;
+    left out, one bias-free matrix.  The weighting rule: a chosen expert's
+    weight is its softmax probability over all experts, divided by the sum
+    of the token's ``top_k`` probabilities where ``renormalise`` (at
+    ``top_k`` 1 that quotient is the constant 1 and the router gets no
+    gradient), as it is where not.  ``shared_width`` 0 builds no shared
+    expert and no gate for it."""
 
     num_experts: int
     top_k: int
@@ -238,6 +251,8 @@ class DroplessMoE(nn.Module):
     held_experts: Optional[tuple] = None
     tile: int = DEFAULT_TILE
     dtype: Any = None
+    router: Optional[nn.Module] = None
+    renormalise: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -254,15 +269,17 @@ class DroplessMoE(nn.Module):
         w_gate = self.param("w_gate", _normal_init, (held, D, F), jnp.float32)
         w_up = self.param("w_up", _normal_init, (held, D, F), jnp.float32)
         w_down = self.param("w_down", _normal_init, (held, F, D), jnp.float32)
+        router = self.router if self.router is not None else nn.Dense(
+            E, use_bias=False, name="router", dtype=jnp.float32,
+            param_dtype=jnp.float32, kernel_init=_normal_init,
+        )
 
         with jax.named_scope("routed_experts"):
-            logits = nn.Dense(
-                E, use_bias=False, name="router", dtype=jnp.float32,
-                param_dtype=jnp.float32, kernel_init=_normal_init,
-            )(tokens.astype(jnp.float32))
+            logits = router(tokens.astype(jnp.float32))
             probs = jax.nn.softmax(logits, axis=-1)
             top_p, top_e = jax.lax.top_k(probs, K)
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if self.renormalise:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
             plan = make_plan(top_e, first, held, self.tile)
             routed = routed_experts(
                 tokens.astype(dtype), top_p, w_gate, w_up, w_down, plan,
@@ -278,6 +295,8 @@ class DroplessMoE(nn.Module):
             self.sow(STATS_COLLECTION, "local_pairs", sizes.sum())
             self.sow(STATS_COLLECTION, "load_max_over_mean",
                      sizes.max() / jnp.maximum(sizes.mean(), 1e-9))
+        if not self.shared_width:
+            return routed.astype(dtype).reshape(B, S, D)
 
         with jax.named_scope("shared_expert"):
             shared = GatedMLP(self.shared_width, dtype, name="shared_expert")(
