@@ -37,6 +37,7 @@ from distributed_machine_learning_tpu.ops.grouped_experts import (
     DEFAULT_TILE,
     make_plan,
     routed_experts,
+    stride_row_share,
 )
 
 NORM_EPS = 1e-6
@@ -304,6 +305,8 @@ class DroplessMoE(nn.Module):
             self.sow(STATS_COLLECTION, "local_pairs", sizes.sum())
             self.sow(STATS_COLLECTION, "load_max_over_mean",
                      sizes.max() / jnp.maximum(sizes.mean(), 1e-9))
+            self.sow(STATS_COLLECTION, "stride_row_share",
+                     stride_row_share(plan))
         if not self.shared_width:
             return routed.astype(dtype).reshape(B, S, D)
 

@@ -3,18 +3,24 @@ grouped matrix product over the experts held here.
 
 A top-k router sends every token to ``k`` of ``num_experts`` experts; this
 chip holds ``held`` of them (ids ``first .. first + held - 1``).  The pairs
-that land on a held expert are ordered by expert, each expert's run is
-padded to whole tiles of ``tile`` rows, and a loop over the tiles *in use*
-runs the gated feed-forward ``W_down(act(W_gate x) * W_up x)`` of the
-tile's expert on the tile's tokens (``act`` SiLU or ReLU: ``ACTIVATIONS``).  No expert has a capacity and no pair
-is dropped: the buffers are sized for the worst routing (every token's
+that land on a held expert are ordered by expert, and each expert's run is
+padded to whole tiles of ``tile`` rows.  The product walks the held experts
+in turn, each expert's weights sliced once, and runs the gated feed-forward
+``W_down(act(W_gate x) * W_up x)`` (``act`` SiLU or ReLU: ``ACTIVATIONS``)
+over its run in strides of ``STRIDE_TILES`` tiles while that many are
+left, then a tile at a time.  No expert has a capacity and no pair is
+dropped: the buffers are sized for the worst routing (every token's
 ``min(k, held)`` choices land here), the work is what the routing asks.
 What the absent experts would add is left out.
 
-Shapes are static and the trip count is not, so the loop is a ``while`` on
-the device and its backward is written by hand (``jax.custom_vjp``): the
-same loop again, recomputing a tile's activations and accumulating the
-experts' weight gradients in float32.  Everything outside the loop is
+Shapes are static and the trip counts are not, so the walk is ``while``
+loops on the device and its backward is written by hand
+(``jax.custom_vjp``): the same walk again, recomputing a stride's
+activations.  An expert's three weight gradients accumulate in float32 in
+the walk's carry and leave it once an expert.  That carry is read and
+written once a stride, so a stride of two tiles moves half of the
+accumulator's bytes a row that a tile would, and its products contract
+over 512 rows at the 256-row tile.  Everything outside the walk is
 cumulative sums and gathers, and one scatter of the pairs' indices into
 their rows (``make_plan``).
 """
@@ -28,6 +34,11 @@ import jax
 import jax.numpy as jnp
 
 DEFAULT_TILE = 256
+# Tiles a stride takes while a run has that many left (512 rows at the
+# default tile); the run's last ``< STRIDE_TILES`` tiles go one at a time.
+# Two timed faster than four or eight on a v5e at all three LM cells'
+# shapes, the routed experts alone (PERF.md, section 6).
+STRIDE_TILES = 2
 
 
 def _silu(g):
@@ -48,12 +59,13 @@ ACTIVATIONS = {
 
 
 class Plan(NamedTuple):
-    """Where each pair's row lies, and what each row and tile holds."""
+    """Where each pair's row lies, what each row holds, and each held
+    expert's run of rows."""
 
     row_token: jnp.ndarray    # [R] token of each padded row; T = the zero row
     row_pair: jnp.ndarray     # [R] flat pair (t * K + j); T * K = no pair
-    tile_expert: jnp.ndarray  # [R // tile] held expert of each tile
-    n_tiles: jnp.ndarray      # [] tiles in use
+    run_start: jnp.ndarray    # [held] first padded row of each expert's run
+    run_tiles: jnp.ndarray    # [held] tiles in each expert's run
     pair_row: jnp.ndarray     # [T, K] padded row of each pair; R = the zero row
     sizes: jnp.ndarray        # [held] pairs on each held expert
 
@@ -83,15 +95,9 @@ def make_plan(expert_idx, first: int, held: int, tile: int) -> Plan:
     count = jnp.cumsum(here, axis=0, dtype=jnp.int32)
     sizes = count[-1]
     earlier = jnp.sum(jnp.where(here, count - 1, 0), axis=1)   # same expert
-    padded = -(-sizes // tile) * tile
-    ends = jnp.cumsum(padded)
-    pstarts = ends - padded
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(
-            ends, jnp.arange(0, R, tile, dtype=jnp.int32), side="right"
-        ),
-        held - 1,
-    ).astype(jnp.int32)
+    tiles = -(-sizes // tile)
+    padded = tiles * tile
+    pstarts = jnp.cumsum(padded) - padded
     pairs = jnp.arange(P, dtype=jnp.int32)
     row = jnp.append(pstarts, 0)[key] + earlier
     pair_row = jnp.where(key < held, row, R)
@@ -103,11 +109,18 @@ def make_plan(expert_idx, first: int, held: int, tile: int) -> Plan:
     return Plan(
         row_token=jnp.where(row_pair < P, row_pair // K, T),
         row_pair=row_pair,
-        tile_expert=tile_expert,
-        n_tiles=ends[-1] // tile,
+        run_start=pstarts,
+        run_tiles=tiles,
         pair_row=pair_row.reshape(T, K),
         sizes=sizes,
     )
+
+
+def stride_row_share(plan: Plan):
+    """The share of the rows in use that the walk computes in whole
+    strides (0 where no run has ``STRIDE_TILES`` tiles)."""
+    strided = plan.run_tiles // STRIDE_TILES * STRIDE_TILES
+    return jnp.sum(strided) / jnp.maximum(jnp.sum(plan.run_tiles), 1)
 
 
 def _dot(a, b, contract):
@@ -117,10 +130,40 @@ def _dot(a, b, contract):
     )
 
 
-def _tile_inputs(i, tile, plan: Plan, *row_arrays):
-    """Tile ``i``'s expert and its rows of each of ``row_arrays``."""
-    tok = jax.lax.dynamic_slice(plan.row_token, (i * tile,), (tile,))
-    return plan.tile_expert[i], [a[tok] for a in row_arrays]
+def _walk(plan: Plan, tile: int, stacks, step, carry, acc=()):
+    """Each held expert's run in turn, its slices of the weight ``stacks``
+    cut once: ``step(w, row0, rows, carry, acc) -> (carry, acc)`` on rows
+    ``[row0, row0 + rows)``, strides of ``STRIDE_TILES`` tiles while that
+    many are left, then single tiles (``rows`` is static, so ``step`` is
+    traced twice).  An expert with no pairs takes no step.  ``carry`` runs
+    through the walk; ``acc`` starts from the value given at every expert
+    and leaves stacked, ``[held, ...]``.  Returns ``(carry, acc)``."""
+    stride = STRIDE_TILES * tile
+
+    def expert(carry, run):
+        w, start, n = run
+        strides = n // STRIDE_TILES
+
+        def loop(count, first, rows, state):
+            return jax.lax.fori_loop(
+                0, count, lambda i, s: step(w, first + i * rows, rows, *s),
+                state,
+            )
+
+        state = loop(strides, start, stride, (carry, acc))
+        return loop(n - strides * STRIDE_TILES, start + strides * stride,
+                    tile, state)
+
+    return jax.lax.scan(
+        expert, carry, (stacks, plan.run_start, plan.run_tiles)
+    )
+
+
+def _rows(plan: Plan, row0, rows: int, *row_arrays):
+    """Rows ``[row0, row0 + rows)`` of the plan, taken from each of
+    ``row_arrays`` by their tokens."""
+    tok = jax.lax.dynamic_slice(plan.row_token, (row0,), (rows,))
+    return [a[tok] for a in row_arrays]
 
 
 def _hidden(xt, wg_e, wu_e):
@@ -138,15 +181,16 @@ def _forward(x, weights, w_gate, w_up, w_down, plan: Plan, tile: int,
     x_pad = jnp.concatenate([x, jnp.zeros((1, d), dtype)])
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
-    def body(i, out_rows):
-        e, (xt,) = _tile_inputs(i, tile, plan, x_pad)
-        g, u = _hidden(xt, wg[e], wu[e])
+    def step(w, row0, rows, out_rows, acc):
+        wg_e, wu_e, wd_e = w
+        (xt,) = _rows(plan, row0, rows, x_pad)
+        g, u = _hidden(xt, wg_e, wu_e)
         h = (act(g) * u).astype(dtype)
-        o = _dot(h, wd[e], ((1,), (0,))).astype(dtype)
-        return jax.lax.dynamic_update_slice(out_rows, o, (i * tile, 0))
+        o = _dot(h, wd_e, ((1,), (0,))).astype(dtype)
+        return jax.lax.dynamic_update_slice(out_rows, o, (row0, 0)), acc
 
-    out_rows = jax.lax.fori_loop(
-        0, plan.n_tiles, body, jnp.zeros((R + 1, d), dtype)
+    out_rows, _ = _walk(
+        plan, tile, (wg, wu, wd), step, jnp.zeros((R + 1, d), dtype)
     )
     # One choice at a time: all K at once would be a [T, K, d] float32.
     weights = weights.astype(jnp.float32)
@@ -189,42 +233,41 @@ def _routed_bwd(tile, activation, res, dy):
     )[plan.row_pair]
     wg, wu, wd = (w.astype(dtype) for w in (w_gate, w_up, w_down))
 
-    def add_at(acc, e, value):
-        return jax.lax.dynamic_update_index_in_dim(acc, acc[e] + value, e, 0)
-
-    def body(i, carry):
-        dx_rows, dw_rows, d_gate, d_up, d_down = carry
-        e, (xt, dyt) = _tile_inputs(i, tile, plan, x_pad, dy_pad)
-        wr = jax.lax.dynamic_slice(row_weight, (i * tile,), (tile,))
-        g, u = _hidden(xt, wg[e], wu[e])
+    def step(w, row0, rows, carry, acc):
+        wg_e, wu_e, wd_e = w
+        dx_rows, dw_rows = carry
+        a_gate, a_up, a_down = acc
+        xt, dyt = _rows(plan, row0, rows, x_pad, dy_pad)
+        wr = jax.lax.dynamic_slice(row_weight, (row0,), (rows,))
+        g, u = _hidden(xt, wg_e, wu_e)
         s, slope = ACTIVATIONS[activation][1](g)
         h = (s * u).astype(dtype)
-        o = _dot(h, wd[e], ((1,), (0,)))
+        o = _dot(h, wd_e, ((1,), (0,)))
         dw = jnp.sum(o * dyt.astype(jnp.float32), axis=-1)
         do = (dyt.astype(jnp.float32) * wr[:, None]).astype(dtype)
-        dh = _dot(do, wd[e], ((1,), (1,)))
+        dh = _dot(do, wd_e, ((1,), (1,)))
         du = (dh * s).astype(dtype)
         dg = (dh * u * slope()).astype(dtype)
-        dxt = _dot(dg, wg[e], ((1,), (1,))) + _dot(du, wu[e], ((1,), (1,)))
+        dxt = _dot(dg, wg_e, ((1,), (1,))) + _dot(du, wu_e, ((1,), (1,)))
         return (
-            jax.lax.dynamic_update_slice(
-                dx_rows, dxt.astype(dtype), (i * tile, 0)
+            (
+                jax.lax.dynamic_update_slice(
+                    dx_rows, dxt.astype(dtype), (row0, 0)
+                ),
+                jax.lax.dynamic_update_slice(dw_rows, dw, (row0,)),
             ),
-            jax.lax.dynamic_update_slice(dw_rows, dw, (i * tile,)),
-            add_at(d_gate, e, _dot(xt, dg, ((0,), (0,)))),
-            add_at(d_up, e, _dot(xt, du, ((0,), (0,)))),
-            add_at(d_down, e, _dot(h, do, ((0,), (0,)))),
+            (
+                a_gate + _dot(xt, dg, ((0,), (0,))),
+                a_up + _dot(xt, du, ((0,), (0,))),
+                a_down + _dot(h, do, ((0,), (0,))),
+            ),
         )
 
-    dx_rows, dw_rows, d_gate, d_up, d_down = jax.lax.fori_loop(
-        0, plan.n_tiles, body,
-        (
-            jnp.zeros((R + 1, d), dtype),
-            jnp.zeros((R + 1,), jnp.float32),
-            jnp.zeros(w_gate.shape, jnp.float32),
-            jnp.zeros(w_up.shape, jnp.float32),
-            jnp.zeros(w_down.shape, jnp.float32),
-        ),
+    (dx_rows, dw_rows), (d_gate, d_up, d_down) = _walk(
+        plan, tile, (wg, wu, wd), step,
+        (jnp.zeros((R + 1, d), dtype), jnp.zeros((R + 1,), jnp.float32)),
+        tuple(jnp.zeros(w.shape[1:], jnp.float32)
+              for w in (w_gate, w_up, w_down)),
     )
     dx = jnp.zeros((T, d), jnp.float32)
     for j in range(K):

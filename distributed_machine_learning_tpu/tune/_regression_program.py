@@ -353,9 +353,10 @@ def make_token_eval_fn(
     over the positions of the unmasked rows.  Where the model's expert
     layers sow their routing counts (``models/hybrid_lm.py``), the
     evaluation batches' counts ride along: ``moe_local_pairs`` (token-expert
-    pairs computed here a batch, summed over the layers) and
+    pairs computed here a batch, summed over the layers),
     ``moe_load_max_over_mean`` (the fullest held expert over the mean one,
-    the worst layer's)."""
+    the worst layer's) and ``moe_stride_row_share`` (the share of the rows
+    in use computed in whole strides, the mean over the layers)."""
     from distributed_machine_learning_tpu.models.moe import STATS_COLLECTION
     from distributed_machine_learning_tpu.ops.losses import token_cross_entropy
 
@@ -383,7 +384,11 @@ def make_token_eval_fn(
 
             pairs = [jnp.sum(v) for v in layers("local_pairs")]
             loads = [jnp.max(v) for v in layers("load_max_over_mean")]
-            routing = (sum(pairs), jnp.max(jnp.stack(loads))) if pairs else ()
+            shares = [jnp.ravel(v) for v in layers("stride_row_share")]
+            routing = (
+                sum(pairs), jnp.max(jnp.stack(loads)),
+                jnp.mean(jnp.concatenate(shares)),
+            ) if pairs else ()
             return None, ((nll * m).sum(), routing)
 
         _, (nll, routing) = jax.lax.scan(step, None, (xb, yb, mb))
@@ -392,6 +397,7 @@ def make_token_eval_fn(
         if routing:
             out["moe_local_pairs"] = routing[0].mean()
             out["moe_load_max_over_mean"] = routing[1].max()
+            out["moe_stride_row_share"] = routing[2].mean()
         return out
 
     return evaluate

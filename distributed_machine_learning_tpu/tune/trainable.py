@@ -235,12 +235,14 @@ def epoch_record(
     }
     perf_acct.annotate(record, exec_s, device=device, observe_s=observe_s)
     if "moe_local_pairs" in metrics:
-        # An expert layer's routing counts (make_token_eval_fn): the
-        # mean ratio is load_max_over_mean_sum over reports.
+        # An expert layer's routing counts (make_token_eval_fn): a
+        # ``_sum`` over ``moe.reports`` is the mean a report.
         registry = obs.get_registry()
         registry.add("moe.local_pairs", metrics["moe_local_pairs"])
         registry.add("moe.load_max_over_mean_sum",
                      metrics["moe_load_max_over_mean"])
+        registry.add("moe.stride_row_share_sum",
+                     metrics["moe_stride_row_share"])
         registry.add("moe.reports")
     return record
 

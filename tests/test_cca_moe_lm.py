@@ -368,18 +368,19 @@ def test_top1_weighting_reaches_the_router(renormalise):
     assert float(jnp.max(jnp.abs(grads["w_down"]))) > 0.0
 
 
-# The hybrid family's expert layer and whole loss, lowered from the parent
-# commit's tree on the sandbox's CPU (jax 0.9.0; `as_text()` of the jitted
-# gradient, shapes only): the router part, the weighting rule and the
-# shared expert's absence are arguments whose defaults leave its program
-# as it was.
+# The hybrid family's expert layer and whole loss, lowered on the
+# CPU (jax 0.9.0; `as_text()` of the jitted gradient, shapes
+# only) from the tree whose routed experts walk each held expert's run in
+# strides (`ops/grouped_experts.py _walk`): the router part, the weighting
+# rule and the shared expert's absence are arguments whose defaults leave
+# its program as it was.
 PARENT_HLO_SHA256 = {
-    "moe": "af8da055fe8d7e717a19a2339a7441ac6aa9967d44752f8e5523e30d18397536",
-    "stack": "a15ee594bd0ddfa11be9c1aecf0c431be542646148fa3e98eb0f6e0a5f150bc2",
+    "moe": "ae995ac50b10552d022ee88ef496f61725edc168f5493a1d07d495b19d35a606",
+    "stack": "0608a0948b2618bc3ad0c4b8b07059d3a7e399d55b498fd084ee7aae213d43cb",
 }
 
 
-# ``tests/test_gated_hybrid_lm.py``'s tiny trial, which the parent's hash is of.
+# ``tests/test_gated_hybrid_lm.py``'s tiny trial, which the pinned hash is of.
 HYBRID_TRIAL = {
     "model": "gated_hybrid_lm", "vocab_size": 97, "num_layers": 4,
     "d_model": 64, "full_attention_interval": 4, "num_heads": 4,
@@ -453,6 +454,7 @@ def test_two_epochs_through_tune_run_with_a_checkpoint_and_a_restore(tmp_path):
     first, second = straight.results
     assert second["train_loss"] < first["train_loss"] < np.log(97) * 1.05
     assert first["moe_local_pairs"] > 0    # the expert layers sow their counts
+    assert 0.0 <= first["moe_stride_row_share"] <= 1.0
 
     cut = _tune_run(tmp_path, "cut")
     root, trial_id = cut.root, cut.trials[0].trial_id

@@ -351,6 +351,10 @@ def test_dropless_under_imbalance():
     assert float(sown["load_max_over_mean"][0]) > 2.0
     # The plan's rows hold every local pair exactly once.
     plan = grouped_experts.make_plan(top_e, 4, 4, 8)
+    # Expert 5's run of 10 tiles is walked mostly in strides.
+    share = float(grouped_experts.stride_row_share(plan))
+    assert share > 0.5
+    assert float(sown["stride_row_share"][0]) == share
     pairs = np.asarray(plan.row_pair)
     pairs = pairs[pairs < 80 * 3]
     local = np.flatnonzero(np.asarray((top_e >= 4) & (top_e < 8)).reshape(-1))
@@ -374,20 +378,21 @@ def test_the_plan_is_a_stable_sort_by_expert(
     flat = idx.reshape(-1) - first
     row_pair = np.full(R, P)
     pair_row = np.full(P, R)
-    tile_expert, row = [], 0
+    run_start, run_tiles, row = [], [], 0
     for e in range(held):
         pairs = np.flatnonzero(flat == e)          # in order: stable
         row_pair[row:row + len(pairs)] = pairs
         pair_row[pairs] = row + np.arange(len(pairs))
         tiles = -(-len(pairs) // tile)
-        tile_expert += [e] * tiles
+        run_start.append(row)
+        run_tiles.append(tiles)
         row += tiles * tile
     assert np.array_equal(plan.row_pair, row_pair)
     assert np.array_equal(plan.pair_row.reshape(-1), pair_row)
     assert np.array_equal(plan.row_token,
                           np.where(row_pair < P, row_pair // top_k, tokens))
-    assert int(plan.n_tiles) == len(tile_expert)
-    assert np.array_equal(plan.tile_expert[:len(tile_expert)], tile_expert)
+    assert np.array_equal(plan.run_start, run_start)
+    assert np.array_equal(plan.run_tiles, run_tiles)
     assert np.array_equal(plan.sizes, [(flat == e).sum() for e in range(held)])
 
 

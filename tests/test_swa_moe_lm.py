@@ -377,15 +377,16 @@ def test_the_router_reads_the_normed_input_not_the_experts_input():
 # -- the other families' programs ---------------------------------------------
 
 # The epoch programs of the two other language-model families at a tiny
-# size, lowered from the parent commit's tree on the sandbox's CPU (jax
-# 0.9.0; ``as_text()`` of ``make_epoch_fn`` under jit, shapes only): the
-# window, the activation and the router's input are arguments whose
-# defaults leave them as they were.
+# size, lowered on a CPU (jax 0.9.0; ``as_text()`` of
+# ``make_epoch_fn`` under jit, shapes only) from the tree whose routed
+# experts walk each held expert's run in strides (``ops/grouped_experts.py
+# _walk``): the window, the activation and the router's input are
+# arguments whose defaults leave them as they were.
 PARENT_EPOCH_SHA256 = {
     "gated_hybrid_lm":
-        "b9d6238111a6497baa6506534d3770c30e179c011f26778da0cfe06884b3100b",
+        "a6ebdd9c69723030651983c8c34fd3808f07fe3aed471192fec489a395bdfec1",
     "cca_moe_lm":
-        "d7e6359e98a30c1b0b61cff883f314b7528e5929dd31c730bfabc1592c5ed645",
+        "615794e2a5668db090cceabf0b6bb3376966777b0d8016856b75f9e597e2c4f1",
 }
 
 
