@@ -100,7 +100,7 @@ def _build_programs(config, sample_x, batch_size, n_train, lr):
     def _eval(params, batch_stats, x, y):
         import jax.numpy as jnp
 
-        preds, _, _ = forward(params, batch_stats, x, None, train=False)
+        preds, *_ = forward(params, batch_stats, x, None, train=False)
         preds = preds.astype(jnp.float32)
         return jnp.mean(
             jnp.abs(y - preds) / (jnp.abs(y) + 1e-8)

@@ -53,9 +53,11 @@ def expert_capacity(capacity_factor: float, top_k: int, group: int,
 
 
 # The collection an expert layer sows its routing counts into (never added
-# to the objective, unlike ``"moe"``): ``local_pairs`` and
-# ``load_max_over_mean`` a layer (models/hybrid_lm.py DroplessMoE); the
-# token evaluation program reads it (tune/_regression_program.py).
+# to the objective, unlike ``"moe"``): ``local_pairs``,
+# ``load_max_over_mean`` and ``stride_row_share`` a layer
+# (models/hybrid_lm.py DroplessMoE); the training epoch and the token
+# evaluation program read it (tune/_regression_program.py
+# routing_summaries).
 STATS_COLLECTION = "moe_stats"
 
 

@@ -22,6 +22,31 @@ import numpy as np
 import optax
 
 
+def routing_summaries(mutated) -> tuple:
+    """What a model's expert layers sowed into ``moe_stats`` in one
+    ``model.apply`` (``models/hybrid_lm.py DroplessMoE``): ``(pairs,
+    load, share)`` -- the token-expert pairs computed on the experts held
+    here, summed over the layers; the fullest held expert over the mean
+    one, the worst layer's; the share of the rows in use computed in whole
+    strides, the mean over the layers.  ``()`` where nothing was sown."""
+    from distributed_machine_learning_tpu.models.moe import STATS_COLLECTION
+
+    sown = jax.tree_util.tree_leaves_with_path(
+        mutated.get(STATS_COLLECTION, {})
+    )
+
+    def layers(name):  # what every expert layer sowed under ``name``
+        return [v for path, v in sown if name in jax.tree_util.keystr(path)]
+
+    pairs = [jnp.sum(v) for v in layers("local_pairs")]
+    if not pairs:
+        return ()
+    loads = [jnp.max(v) for v in layers("load_max_over_mean")]
+    shares = [jnp.ravel(v) for v in layers("stride_row_share")]
+    return (sum(pairs), jnp.max(jnp.stack(loads)),
+            jnp.mean(jnp.concatenate(shares)))
+
+
 def make_forward(model, flag_name: str, has_bn: bool) -> Callable:
     """Unified apply() over the zoo's two call conventions.
 
@@ -29,10 +54,15 @@ def make_forward(model, flag_name: str, has_bn: bool) -> Callable:
     (preds, new_batch_stats, aux_loss)``.  ``aux_loss`` collects everything
     the model sowed into the ``"moe"`` collection (the MoE load-balance
     terms, already scaled by their coefficient — models/moe.py); it is 0.0
-    for dense models and is added to the training objective only.
+    for dense models and is added to the training objective only.  Where
+    the model's expert layers sow their routing counts, a fourth value
+    follows: their :func:`routing_summaries`, which reach no objective.
     """
 
-    from distributed_machine_learning_tpu.models.moe import collect_aux
+    from distributed_machine_learning_tpu.models.moe import (
+        STATS_COLLECTION,
+        collect_aux,
+    )
 
     def forward(params, batch_stats, x, dropout_key, train: bool):
         vs = {"params": params}
@@ -40,10 +70,13 @@ def make_forward(model, flag_name: str, has_bn: bool) -> Callable:
             vs["batch_stats"] = batch_stats
         kwargs = {flag_name: (not train) if flag_name == "deterministic" else train}
         rngs = {"dropout": dropout_key} if train else None
-        mutable = ["moe"] + (["batch_stats"] if has_bn and train else [])
+        mutable = ["moe", STATS_COLLECTION] + (
+            ["batch_stats"] if has_bn and train else []
+        )
         out, mut = model.apply(vs, x, rngs=rngs, mutable=mutable, **kwargs)
         new_bs = mut["batch_stats"] if (has_bn and train) else batch_stats
-        return out, new_bs, collect_aux(mut)
+        routing = routing_summaries(mut)
+        return (out, new_bs, collect_aux(mut)) + ((routing,) if routing else ())
 
     return forward
 
@@ -76,8 +109,11 @@ def make_epoch_fn(
     """One training epoch as a pure function: shuffle + scan over batches.
 
     ``epoch(params, opt_state, batch_stats, x_all, y_all, epoch_key) ->
-    (params, opt_state, batch_stats, mean_loss)``.  Jit/vmap at the call
-    site.
+    (params, opt_state, batch_stats, mean_loss)``.  Where ``forward``
+    yields routing summaries (:func:`make_forward`), the epoch's follow as
+    a fifth value, a dict: ``moe_pairs`` summed over the steps (int32),
+    ``moe_load_max_over_mean`` and ``moe_stride_row_share`` the means over
+    the steps.  Jit/vmap at the call site.
     """
 
     def epoch(params, opt_state, batch_stats, x_all, y_all, epoch_key):
@@ -91,20 +127,31 @@ def make_epoch_fn(
             xb, yb = x_all[idx], y_all[idx]
 
             def loss_of(p):
-                preds, new_bs, aux = forward(p, batch_stats, xb, dkey, train=True)
-                return loss_fn(_widened(loss_fn, preds), yb) + aux, new_bs
+                preds, new_bs, aux, *routing = forward(
+                    p, batch_stats, xb, dkey, train=True
+                )
+                loss = loss_fn(_widened(loss_fn, preds), yb) + aux
+                return loss, (new_bs, routing)
 
-            (loss, new_bs), grads = jax.value_and_grad(loss_of, has_aux=True)(
-                params
-            )
+            (loss, (new_bs, routing)), grads = jax.value_and_grad(
+                loss_of, has_aux=True
+            )(params)
             updates, new_opt = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-            return (params, new_opt, new_bs, key), loss
+            return (params, new_opt, new_bs, key), (loss, routing)
 
-        (params, opt_state, batch_stats, _), losses = jax.lax.scan(
+        (params, opt_state, batch_stats, _), (losses, routing) = jax.lax.scan(
             step, (params, opt_state, batch_stats, drop0), perm
         )
-        return params, opt_state, batch_stats, losses.mean()
+        out = (params, opt_state, batch_stats, losses.mean())
+        if not routing:
+            return out
+        pairs, loads, shares = routing[0]
+        return out + ({
+            "moe_pairs": pairs.astype(jnp.int32).sum(),
+            "moe_load_max_over_mean": loads.mean(),
+            "moe_stride_row_share": shares.mean(),
+        },)
 
     return epoch
 
@@ -139,8 +186,8 @@ def make_chunk_epoch_fn(
             xb_, yb_ = batch
 
             def loss_of(p):
-                preds, new_bs, aux = forward(p, batch_stats, xb_, dkey,
-                                             train=True)
+                preds, new_bs, aux, *_ = forward(p, batch_stats, xb_, dkey,
+                                                 train=True)
                 return loss_fn(_widened(loss_fn, preds), yb_) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(loss_of, has_aux=True)(
@@ -183,7 +230,7 @@ def make_indexed_epoch_fn(
             key = jax.random.fold_in(epoch_key, i)
 
             def loss_of(p):
-                preds, new_bs, aux = forward(p, batch_stats, x, key, True)
+                preds, new_bs, aux, *_ = forward(p, batch_stats, x, key, True)
                 return loss_fn(_widened(loss_fn, preds), y) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(
@@ -222,7 +269,7 @@ def make_indexed_chunk_fn(
             key = jax.random.fold_in(epoch_key, i)
 
             def loss_of(p):
-                preds, new_bs, aux = forward(p, batch_stats, x, key, True)
+                preds, new_bs, aux, *_ = forward(p, batch_stats, x, key, True)
                 return loss_fn(_widened(loss_fn, preds), y) + aux, new_bs
 
             (loss, new_bs), grads = jax.value_and_grad(
@@ -250,7 +297,7 @@ def make_chunk_eval_fn(forward: Callable) -> Callable:
     def evaluate_chunk(params, batch_stats, xb, yb, mb):
         def step(_, batch):
             x, y, m = batch
-            preds, _, _ = forward(
+            preds, *_ = forward(
                 params, batch_stats, x, jax.random.key(0), train=False
             )
             preds = preds.astype(jnp.float32)
@@ -313,7 +360,7 @@ def make_eval_fn(
 
         def step(_, batch):
             x, y, m = batch
-            preds, _, _ = forward(
+            preds, *_ = forward(
                 params, batch_stats, x, jax.random.key(0), train=False
             )
             preds = preds.astype(jnp.float32)
@@ -351,12 +398,10 @@ def make_token_eval_fn(
     ``ops.losses.TOKEN_LOSSES``): ``(params, batch_stats, x, y, mask) ->
     {validation_loss, validation_perplexity}``, the mean cross-entropy
     over the positions of the unmasked rows.  Where the model's expert
-    layers sow their routing counts (``models/hybrid_lm.py``), the
-    evaluation batches' counts ride along: ``moe_local_pairs`` (token-expert
-    pairs computed here a batch, summed over the layers),
-    ``moe_load_max_over_mean`` (the fullest held expert over the mean one,
-    the worst layer's) and ``moe_stride_row_share`` (the share of the rows
-    in use computed in whole strides, the mean over the layers)."""
+    layers sow their routing counts, the evaluation batches'
+    :func:`routing_summaries` ride along: ``moe_local_pairs`` (the mean a
+    batch), ``moe_load_max_over_mean`` (the worst batch's) and
+    ``moe_stride_row_share`` (the mean a batch)."""
     from distributed_machine_learning_tpu.models.moe import STATS_COLLECTION
     from distributed_machine_learning_tpu.ops.losses import token_cross_entropy
 
@@ -374,22 +419,7 @@ def make_token_eval_fn(
                 {"params": params}, x, mutable=[STATS_COLLECTION], **kwargs
             )
             nll = jnp.mean(token_cross_entropy(logits, y), axis=-1)
-            sown = jax.tree_util.tree_leaves_with_path(
-                mut.get(STATS_COLLECTION, {})
-            )
-
-            def layers(name):  # what every expert layer sowed under ``name``
-                return [v for path, v in sown
-                        if name in jax.tree_util.keystr(path)]
-
-            pairs = [jnp.sum(v) for v in layers("local_pairs")]
-            loads = [jnp.max(v) for v in layers("load_max_over_mean")]
-            shares = [jnp.ravel(v) for v in layers("stride_row_share")]
-            routing = (
-                sum(pairs), jnp.max(jnp.stack(loads)),
-                jnp.mean(jnp.concatenate(shares)),
-            ) if pairs else ()
-            return None, ((nll * m).sum(), routing)
+            return None, ((nll * m).sum(), routing_summaries(mut))
 
         _, (nll, routing) = jax.lax.scan(step, None, (xb, yb, mb))
         loss = nll.sum() / mask.sum()

@@ -234,17 +234,26 @@ def epoch_record(
         **metrics,
     }
     perf_acct.annotate(record, exec_s, device=device, observe_s=observe_s)
-    if "moe_local_pairs" in metrics:
-        # An expert layer's routing counts (make_token_eval_fn): a
-        # ``_sum`` over ``moe.reports`` is the mean a report.
-        registry = obs.get_registry()
-        registry.add("moe.local_pairs", metrics["moe_local_pairs"])
-        registry.add("moe.load_max_over_mean_sum",
-                     metrics["moe_load_max_over_mean"])
-        registry.add("moe.stride_row_share_sum",
-                     metrics["moe_stride_row_share"])
-        registry.add("moe.reports")
     return record
+
+
+def note_step_routing(span, routing: dict, steps: int) -> None:
+    """An epoch's routing counts from its own training steps
+    (``make_epoch_fn``'s fifth value, read back): attrs of the open
+    ``epoch`` span, and registry counters, where a ``_sum`` over
+    ``moe.steps`` is the mean a step."""
+    pairs = int(routing["moe_pairs"])
+    load = float(routing["moe_load_max_over_mean"])
+    share = float(routing["moe_stride_row_share"])
+    span.set("moe_pairs", pairs)
+    span.set("moe_steps", steps)
+    span.set("moe_load_max_over_mean", load)
+    span.set("moe_stride_row_share", share)
+    registry = obs.get_registry()
+    registry.add("moe.step_pairs", pairs)
+    registry.add("moe.steps", steps)
+    registry.add("moe.step_load_max_over_mean_sum", load * steps)
+    registry.add("moe.step_stride_row_share_sum", share * steps)
 
 
 def _epoch_checkpoint(s, epoch, params, opt_state, batch_stats, rng_impl):
@@ -577,7 +586,7 @@ def train_regressor(
 
     # ---- epoch loop: host-driven so the scheduler can interrupt ------------
     for epoch in range(start_epoch, s.num_epochs):
-        with obs.span("epoch", {"epoch": epoch}):
+        with obs.span("epoch", {"epoch": epoch}) as epoch_span:
             with obs.span("epoch.dispatch"):
                 epoch_key = jax.random.key(
                     fold_seed(s.seed, "epoch", epoch), impl=rng_impl
@@ -590,9 +599,11 @@ def train_regressor(
                 )
                 c0 = tracker.thread_seconds()
                 t0 = time.time()
-                params, opt_state, batch_stats, train_loss = train_epoch(
-                    params, opt_state, batch_stats, data.x_train,
-                    data.y_train, epoch_key
+                params, opt_state, batch_stats, train_loss, *routing = (
+                    train_epoch(
+                        params, opt_state, batch_stats, data.x_train,
+                        data.y_train, epoch_key
+                    )
                 )
                 metrics = bundle.evaluate(
                     params, batch_stats, data.x_val, data.y_val,
@@ -600,8 +611,11 @@ def train_regressor(
                 )
             # jit returns futures: the scalar readbacks are the sync.
             with obs.span("epoch.readback"):
+                train_loss, routing = jax.device_get((train_loss, routing))
                 train_loss = float(train_loss)
                 metrics = {k: float(v) for k, v in metrics.items()}
+                if routing:
+                    note_step_routing(epoch_span, routing[0], steps_per_epoch)
         # The readbacks above synced both programs; wall minus this
         # thread's compile seconds is device-execute time.
         exec_s = max(

@@ -449,7 +449,7 @@ def _train_sharded(
     mask_np = (np.arange(len(xv_np)) < n_val).astype(np.float32)
 
     def eval_fn(params, batch_stats, xv, yv, mask):
-        preds, _, _ = forward(params, batch_stats, xv, jax.random.key(0), False)
+        preds, *_ = forward(params, batch_stats, xv, jax.random.key(0), False)
         se, ae, ape = per_example_losses(preds.astype(jnp.float32), yv)
         denom = mask.sum()
         return {
