@@ -138,8 +138,8 @@ class GatedDeltaNetMixer(nn.Module):
         k = k.reshape(B, S, hk, dk)
         v = v.reshape(B, S, hv, dv)
         # Each key head serves hv // hk value heads in a row.
-        q = jnp.repeat(_l2(q.astype(jnp.float32)) * dk ** -0.5, hv // hk, axis=2)
-        k = jnp.repeat(_l2(k.astype(jnp.float32)), hv // hk, axis=2)
+        q = _l2(q.astype(jnp.float32)) * dk ** -0.5
+        k = _l2(k.astype(jnp.float32))
         b, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
         beta = jax.nn.sigmoid(b)
         g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)

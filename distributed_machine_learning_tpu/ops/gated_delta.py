@@ -15,9 +15,11 @@ strength.  A scan over positions would run 8,192 tiny steps a sequence;
 the chunked form does the same sums with matrix products: inside a chunk
 of ``C`` positions the deltas solve a unit-lower-triangular system built
 from the decayed ``K K^T``, the outputs read the decayed ``Q K^T``, and
-``S`` is carried from chunk to chunk.  The backward pass is autodiff's over
-that program with the chunk step rematerialised, so it keeps one ``S`` a
-chunk and never one a position.
+``S`` is carried from chunk to chunk.  ``ops/pallas_gated_delta.py``
+computes it in two Pallas kernels where the shapes fit them; ``_chunked``
+is XLA's program for every other shape (its backward is autodiff's with
+the chunk step rematerialised, so it keeps one ``S`` a chunk and never one
+a position).
 
 The decay's cumulative sums, the solve and ``S`` are float32; the matrix
 products take ``matmul_dtype`` operands (the model's compute dtype) and
@@ -29,12 +31,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributed_machine_learning_tpu.ops import pallas_gated_delta
+
 DEFAULT_CHUNK = 64
-# Heads worked at a time.  The backward pass keeps a head's chunk-local
-# matrices ([S / C, C, C] and [S / C, C, Dk + Dv] float32, a dozen of them)
-# until it has used them; a block of heads at a time, each rematerialised,
-# holds that to the block's share.
-HEAD_BLOCK = 8
 
 
 def _mm(spec: str, a, b, dtype):
@@ -46,28 +45,62 @@ def _mm(spec: str, a, b, dtype):
 
 def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
                            matmul_dtype=jnp.float32):
-    """``o`` [B, S, H, Dv] in float32 for q, k [B, S, H, Dk] (k of unit
-    norm, q already scaled), v [B, S, H, Dv], g and beta [B, S, H], the
-    state starting from nought.  Any S: a last partial chunk is padded
-    with positions that write nothing.  Heads are independent and are
-    worked ``HEAD_BLOCK`` at a time where that divides them."""
-    H = q.shape[2]
-    if not (HEAD_BLOCK < H and H % HEAD_BLOCK == 0):
-        return _chunked(q, k, v, g, beta, chunk, matmul_dtype)
-    n = H // HEAD_BLOCK
+    """``o`` [B, S, Hv, Dv] in float32 for q, k [B, S, Hk, Dk] (k of unit
+    norm, q already scaled), v [B, S, Hv, Dv], g and beta [B, S, Hv], the
+    state starting from nought; each key head serves the ``Hv // Hk``
+    value heads that follow it.  Any S: a last partial chunk is padded
+    with positions that write nothing.
 
-    def blocks(a):  # [B, S, H, ...] -> [n, B, S, head_block, ...]
-        a = a.reshape(*a.shape[:2], n, HEAD_BLOCK, *a.shape[3:])
-        return jnp.moveaxis(a, 2, 0)
+    Where the shapes fit its tiles (``pallas_gated_delta.fits``: chunk
+    64, head sizes a multiple of 128) the Pallas kernels compute it, in
+    the interpreter off a TPU; elsewhere ``_chunked``, XLA's program.  The
+    registry counts each call as it is traced: ``gated_delta.kernel_calls``
+    or ``gated_delta.xla_calls``."""
+    from distributed_machine_learning_tpu import obs
 
-    o = jax.lax.map(
-        jax.checkpoint(
-            lambda xs: _chunked(*xs, chunk, matmul_dtype)
-        ),
-        tuple(blocks(a) for a in (q, k, v, g, beta)),
-    )                                                 # [n, B, S, hb, Dv]
-    o = jnp.moveaxis(o, 0, 2)
-    return o.reshape(*o.shape[:2], H, o.shape[-1])
+    Hk, Dk = q.shape[2:]
+    Hv, Dv = v.shape[2:]
+    if pallas_gated_delta.fits(chunk, Dk, Dv, Hk, Hv):
+        obs.get_registry().add("gated_delta.kernel_calls")
+        return _kernel(q, k, v, g, beta, matmul_dtype)
+    obs.get_registry().add("gated_delta.xla_calls")
+    if Hv != Hk:
+        q, k = (jnp.repeat(a, Hv // Hk, axis=2) for a in (q, k))
+    return _chunked(q, k, v, g, beta, chunk, matmul_dtype)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _kernel(q, k, v, g, beta, matmul_dtype):
+    """The kernels' call: q, k and v as the projections' [B, S, heads*D],
+    the chunk-local cumulative g and beta as [B, Hv, S / C, C] rows."""
+    B, S, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2:]
+    C = pallas_gated_delta.CHUNK
+    pad = -S % C
+    if pad:
+        # beta 0 writes nothing, g 0 decays nothing (as in _chunked).
+        widths = ((0, 0), (0, pad), (0, 0))
+        q, k, v = (jnp.pad(a, widths + ((0, 0),)) for a in (q, k, v))
+        g, beta = jnp.pad(g, widths), jnp.pad(beta, widths)
+    N = (S + pad) // C
+
+    def rows(a):  # [B, S, Hv] -> [B, Hv, N, C]
+        return jnp.moveaxis(a.astype(jnp.float32).reshape(B, N, C, Hv), 3, 1)
+
+    o = pallas_gated_delta.gated_delta_chunks(
+        q.astype(jnp.float32).reshape(B, N * C, Hk * Dk),
+        k.astype(jnp.float32).reshape(B, N * C, Hk * Dk),
+        v.reshape(B, N * C, Hv * Dv),
+        jnp.cumsum(rows(g), axis=-1),
+        rows(beta),
+        Dk,
+        jnp.dtype(matmul_dtype),
+        not _on_tpu(),
+    )
+    return o.reshape(B, N * C, Hv, Dv)[:, :S]
 
 
 def _chunked(q, k, v, g, beta, chunk: int, matmul_dtype):

@@ -7,8 +7,8 @@ import os
 
 import pytest
 
-from benchmark import flops, flops_lm
-from benchmark.readers import kernel_roofline_from, scope_share
+from benchmark import flops, flops_gdn, flops_lm
+from benchmark.readers import kernel_roofline_cfg, kernel_roofline_from, scope_share
 from benchmark.run import Cell, Run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -217,3 +217,69 @@ def test_the_norms_taken_on_every_core_are_the_train_drivers(monkeypatch):
     assert train_lm.init_gap(far, want["mu"]) == pytest.approx(
         0.5 / float(np.abs(want["mu"]["a"]).max()), rel=1e-6)
     assert train_lm.init_gap(want["mu"], want["mu"]) == 0.0
+
+
+def test_the_gated_delta_costs_are_flops_lms_chunk_rule_term(cell):
+    """With no model width (``hidden_size`` 0: no projection, expert or
+    head) and no attention layer, ``flops_lm`` counts the chunked rule
+    alone, layer by layer: the kernels' forward call is that term over the
+    batch, the backward twice it; the bytes are the call's inputs read
+    and its outputs written once, at the configuration's dtypes."""
+    cfg = dict(cell.config, hidden_size=0, full_attention_interval=5)
+    rule_only = flops_lm.forward_flops_per_sequence(cfg, 8192, 0.0)
+    traffic = cell.traffic
+    ops, nbytes = flops_gdn.gdn_forward(cell.config, traffic)
+    assert ops == pytest.approx(traffic["batch_size"] * rule_only / 4)
+    bwd_ops, bwd_bytes = flops_gdn.gdn_backward(cell.config, traffic)
+    assert bwd_ops == 2 * ops
+    qk = 2 * 2 * 8192 * 16 * 128 * 4          # float32 at the key heads
+    v, o = 2 * 8192 * 32 * 128 * 2, 2 * 8192 * 32 * 128 * 4
+    g_beta = 2 * 2 * 8192 * 32 * 4
+    assert nbytes == qk + v + g_beta + o
+    assert bwd_bytes == 2 * (qk + v + g_beta) + o
+
+
+def test_the_gated_delta_rooflines_tell_forward_from_backward(cell):
+    """Each metric's match takes its own kernel's events alone (the
+    backward is the one that yields a bfloat16), costs the call from the
+    cell's files, and reads nothing from a trace without the kernels."""
+    from benchmark import trace as T
+
+    fwd = ('%gated_delta_fwd.1 = (f32[2,8192,4096]{2,1,0}, '
+           'f32[2,32,16,128,128]{4,3,2,1,0}) custom-call(f32[2,8192,2048] %q), '
+           'custom_call_target="tpu_custom_call"')
+    bwd = ('%gated_delta_bwd.1 = (f32[2,8192,2048]{2,1,0}, '
+           'f32[2,8192,2048]{2,1,0}, bf16[2,8192,4096]{2,1,0}, '
+           'f32[2,32,128,64]{3,2,1,0}, f32[2,32,128,64]{3,2,1,0}) '
+           'custom-call(f32[2,8192,2048] %q), '
+           'custom_call_target="tpu_custom_call"')
+    flash = ('%causal_attention.7 = (bf16[2,8192,4096]{2,1,0}, '
+             'f32[2,16,1,8192]{3,2,1,0}) custom-call(bf16[2,8192,4096] %q), '
+             'custom_call_target="tpu_custom_call"')
+    peaks = {"bf16_flops_per_s": 1e14, "hbm_bytes_per_s": 1e12}
+    run = Run(cell=cell, seed=0, seconds=0, traced=True, devices=[None],
+              work_dir="", peaks=peaks)
+    run.trace = T.Trace(devices={"d": T.DeviceTrace(ops=[
+        (fwd, 0.0, 0.01), (fwd, 0.02, 0.01), (bwd, 0.04, 0.03),
+        (flash, 0.08, 0.01)])})
+    run.trace_window = (0.0, 1.0)
+
+    def spec(name):
+        with open(os.path.join(ROOT, "benchmark", "metrics",
+                               name + ".json")) as f:
+            return json.load(f)
+
+    for name, cost, calls, seconds in (
+            ("gdn_fwd_roofline", flops_gdn.gdn_forward, 2, 0.02),
+            ("gdn_bwd_roofline", flops_gdn.gdn_backward, 1, 0.03)):
+        floor, _ = flops.roofline_floor_s(*cost(cell.config, cell.traffic),
+                                          peaks)
+        assert kernel_roofline_cfg.read(spec(name), run) == pytest.approx(
+            100.0 * floor * calls / seconds)
+    # The causal flash forward's match does not take the rule's kernels.
+    flash_spec = spec("flash_causal_fwd_roofline")["match"]
+    assert T.kernel_seconds(run.trace.devices["d"], (0.0, 1.0),
+                            **flash_spec) == (pytest.approx(0.01), 1)
+    run.trace = T.Trace(devices={"d": T.DeviceTrace(ops=[(flash, 0.0, 0.1)])})
+    assert kernel_roofline_cfg.read(spec("gdn_fwd_roofline"), run) is None
+    assert kernel_roofline_cfg.read(spec("gdn_bwd_roofline"), run) is None

@@ -373,10 +373,13 @@ def test_top1_weighting_reaches_the_router(renormalise):
 # only) from the tree whose routed experts walk each held expert's run in
 # strides (`ops/grouped_experts.py _walk`): the router part, the weighting
 # rule and the shared expert's absence are arguments whose defaults leave
-# its program as it was.
+# its program as it was.  The whole loss is pinned again since the delta
+# rule takes q and k at the key heads and repeats them itself on XLA's
+# path (``ops/gated_delta.py``): the same operations in another order,
+# whose loss and every gradient equal the earlier program's to the bit.
 PARENT_HLO_SHA256 = {
     "moe": "ae995ac50b10552d022ee88ef496f61725edc168f5493a1d07d495b19d35a606",
-    "stack": "0608a0948b2618bc3ad0c4b8b07059d3a7e399d55b498fd084ee7aae213d43cb",
+    "stack": "3c26d229f5011c350e53ae0c281d561879e8c60e8d7ea5c97dd6d6e63865bc06",
 }
 
 

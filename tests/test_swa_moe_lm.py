@@ -383,8 +383,11 @@ def test_the_router_reads_the_normed_input_not_the_experts_input():
 # _walk``): the window, the activation and the router's input are
 # arguments whose defaults leave them as they were.
 PARENT_EPOCH_SHA256 = {
+    # Pinned again since the delta rule repeats q and k to the value heads
+    # itself on XLA's path: the operations reordered, the numbers the same
+    # to the bit.
     "gated_hybrid_lm":
-        "a6ebdd9c69723030651983c8c34fd3808f07fe3aed471192fec489a395bdfec1",
+        "e1e84faf7a1e3be2e239649eb45503954bf4c15241b9c33d0561b89b401b979d",
     "cca_moe_lm":
         "615794e2a5668db090cceabf0b6bb3376966777b0d8016856b75f9e597e2c4f1",
 }

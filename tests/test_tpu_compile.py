@@ -12,6 +12,7 @@ Everything compiles in this process, with the persistent cache off around
 it (an entry compiled for a described chip cannot be read back without one).
 """
 
+import functools
 import re
 
 import jax
@@ -368,3 +369,78 @@ def test_the_kernels_without_a_window_lower_as_the_parents(
                    ).lower(q, k, v).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PARENT_KERNEL_SHA256[call]
+
+
+# -- the gated delta rule's kernels ------------------------------------------
+
+def test_gated_delta_kernels_compile_at_the_q3next_cell(one_chip):
+    """Forward (with the states the backward reads) and backward at
+    train_q3next_s8192's call: [2, 8192], 16 key heads serving 32 value
+    heads of 128, bfloat16 products.  The backward is the one kernel that
+    yields a bfloat16 (dv), which the roofline metrics' matches rely on."""
+    from distributed_machine_learning_tpu.ops import pallas_gated_delta as pg
+
+    B, S, hk, hv, D = 2, 8192, 16, 32, 128
+    f32 = jnp.float32
+    q = jax.ShapeDtypeStruct((B, S, hk * D), f32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, S, hv * D), jnp.bfloat16, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((B, hv, S // 64, 64), f32, sharding=one_chip)
+
+    def loss(q, k, v, gc, beta):
+        return pg.gated_delta_chunks(
+            q, k, v, gc, beta, D, jnp.dtype(jnp.bfloat16), False).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        q, q, v, rows, rows)
+    yields = _kernel_yields(compiled)
+    assert len(yields) == 2
+    assert ["bf16[" in y for y in yields].count(True) == 1
+
+
+def test_the_epoch_program_runs_the_rule_in_kernels_under_its_scope(
+        one_chip, monkeypatch):
+    """A one-layer gated_hybrid_lm whose linear layer has heads of 128:
+    its epoch program (two steps) holds the rule's forward, its
+    rematerialisation and its backward as the kernels ``gated_delta_fwd``
+    and ``gated_delta_bwd``, every one under a source path that holds the
+    scope ``gated_delta``, so ``readers/scope_share.py`` counts them in
+    ``gdn_share_pct.lm``."""
+    import optax
+
+    from benchmark.drivers import train_lm
+    from distributed_machine_learning_tpu.models import build_model
+    from distributed_machine_learning_tpu.ops import gated_delta
+    from distributed_machine_learning_tpu.ops.losses import get_loss
+    from distributed_machine_learning_tpu.tune._regression_program import (
+        make_epoch_fn,
+        make_forward,
+    )
+
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    model = build_model({
+        "model": "gated_hybrid_lm", "vocab_size": 97, "num_layers": 1,
+        "d_model": 128, "full_attention_interval": 2,
+        "linear_key_heads": 1, "linear_value_heads": 2,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "num_experts": 4, "top_k": 2, "expert_width": 64,
+        "shared_width": 64, "expert_tile": 16, "compute_dtype": "bfloat16",
+    })
+    tokens = jax.ShapeDtypeStruct((4, 256), jnp.int32, sharding=one_chip)
+    params = jax.eval_shape(
+        lambda t: model.init({"params": jax.random.key(0)}, t[:1])["params"],
+        tokens)
+    tx = optax.adam(1e-3)
+    on_chip = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    epoch = make_epoch_fn(make_forward(model, "deterministic", False), tx,
+                          get_loss("cross_entropy"), 4, 2, 2)
+    text = _compile(epoch, on_chip(params),
+                    on_chip(jax.eval_shape(tx.init, params)), {}, tokens,
+                    tokens, jax.random.key(0)).as_text()
+    sources = dict(train_lm._INSTRUCTION_SOURCE.findall(text))
+    kernels = {name: path for name, path in sources.items()
+               if name.startswith("gated_delta_")}
+    assert sorted(n.rsplit(".", 1)[0] for n in kernels) == [
+        "gated_delta_bwd", "gated_delta_fwd", "gated_delta_fwd"]
+    inside = re.compile(r"(^|[/(])gated_delta(/|\)|$)")
+    assert all(inside.search(path) for path in kernels.values()), kernels
